@@ -159,15 +159,11 @@ def mixed_workload_bench(ray_tpu, serve):
     # engine-side TTFT/TPOT are real per-request measurements from the
     # serve trace plane (first token host-visible at the prefill/decode
     # boundary)
-    ttft = tpot = {}
-    try:
-        from ray_tpu.experimental.state import summarize_workloads
+    from ray_tpu.experimental.state import summarize_workloads
 
-        s = summarize_workloads("serve")
-        ttft = s.get("ttft", {}).get("llm_engine_mixed") or {}
-        tpot = s.get("tpot", {}).get("llm_engine_mixed") or {}
-    except Exception as e:  # noqa: BLE001 — bench must still emit a row
-        print(f"mixed serve-trace summary unavailable: {e}")
+    s = summarize_workloads("serve")
+    ttft = s.get("ttft", {}).get("llm_engine_mixed") or {}
+    tpot = s.get("tpot", {}).get("llm_engine_mixed") or {}
     serve.delete("llm_engine_mixed")
 
     sp99 = static_row.get("short", {}).get("p99_ms") or 0
@@ -356,18 +352,15 @@ def fleet_survival_bench(ray_tpu, serve):
         ray_tpu, handle, sched, "llm_fleet", kill_at=FLEET_N // 3
     )
     failovers = 0
-    try:
-        from ray_tpu.experimental.state import summarize_workloads
+    from ray_tpu.experimental.state import summarize_workloads
 
-        deadline = time.time() + 30
-        while time.time() < deadline:
-            fleet = (summarize_workloads("serve") or {}).get("fleet") or {}
-            failovers = int(fleet.get("llm_fleet", {}).get("failovers_total", 0))
-            if failovers:
-                break
-            time.sleep(0.5)
-    except Exception as e:  # noqa: BLE001 — bench must still emit a row
-        print(f"fleet summary unavailable: {e}")
+    deadline = time.time() + 30
+    while time.time() < deadline:
+        fleet = (summarize_workloads("serve") or {}).get("fleet") or {}
+        failovers = int(fleet.get("llm_fleet", {}).get("failovers_total", 0))
+        if failovers:
+            break
+        time.sleep(0.5)
     serve.delete("llm_fleet")
     return {
         "requests_per_phase": FLEET_N,
@@ -385,12 +378,15 @@ def fleet_survival_bench(ray_tpu, serve):
 def main():
     import jax
 
-    jax.config.update("jax_platforms", "cpu")  # driver never claims the chip
+    jax.config.update("jax_platforms", "cpu")  # the chip is the replica's
     import ray_tpu
     from ray_tpu import serve
+    from ray_tpu._private import tpu
     from ray_tpu.serve.llm import llm_deployment
 
-    ray_tpu.init(num_cpus=6, num_tpus=1)
+    if not tpu.detect_chips():
+        raise SystemExit("bench_serve.py: this host exposes no TPU chip")
+    ray_tpu.init(num_cpus=6)
 
     dep = llm_deployment(
         MODEL,
@@ -452,15 +448,11 @@ def main():
     # the head joins them next to the task flight records, and the summary
     # reports the percentiles — the baseline the continuous-batching
     # engine (ROADMAP item 1) has to beat.
-    ttft = tpot = {}
-    try:
-        from ray_tpu.experimental.state import summarize_workloads
+    from ray_tpu.experimental.state import summarize_workloads
 
-        serve_summary = summarize_workloads("serve")
-        ttft = serve_summary.get("ttft", {}).get("llm") or {}
-        tpot = serve_summary.get("tpot", {}).get("llm") or {}
-    except Exception as e:  # noqa: BLE001 — bench must still emit a row
-        print(f"serve-trace summary unavailable: {e}")
+    serve_summary = summarize_workloads("serve")
+    ttft = serve_summary.get("ttft", {}).get("llm") or {}
+    tpot = serve_summary.get("tpot", {}).get("llm") or {}
 
     result = {
         "metric": "serve_llama_decode_tokens_per_sec_per_chip",
@@ -484,25 +476,12 @@ def main():
     }
     if MIXED:
         # side-by-side static vs continuous-batching engine on one seeded
-        # mixed-length Poisson trace (old sweep above kept untouched for
-        # r01..r05 trajectory comparability)
-        try:
-            result["mixed_workload"] = mixed_workload_bench(ray_tpu, serve)
-        except Exception as e:  # noqa: BLE001 — the legacy sweep's row must still land
-            import traceback
-
-            traceback.print_exc()
-            result["mixed_workload"] = {"error": f"{type(e).__name__}: {e}"}
+        # mixed-length Poisson trace
+        result["mixed_workload"] = mixed_workload_bench(ray_tpu, serve)
     if FLEET:
         # fleet survival: SLO-driven scale-out reaction, failover count
         # and TTFT p99 under a mid-stream replica kill (serve/FLEET.md)
-        try:
-            result["fleet"] = fleet_survival_bench(ray_tpu, serve)
-        except Exception as e:  # noqa: BLE001 — prior sections' rows must still land
-            import traceback
-
-            traceback.print_exc()
-            result["fleet"] = {"error": f"{type(e).__name__}: {e}"}
+        result["fleet"] = fleet_survival_bench(ray_tpu, serve)
     with open("SERVE_BENCH_r05.json", "w") as f:
         json.dump(result, f, indent=1)
     print(json.dumps(result))

@@ -75,7 +75,10 @@ def _maybe_install_jax_reducer():
     """Register a reducer for jax.Array the first time jax shows up.
 
     Device arrays are pulled to host as numpy (which pickles out-of-band,
-    zero-copy) and re-materialized with jnp.asarray on load.  Importing jax
+    zero-copy) and re-materialized with jnp.asarray on load — which
+    initialises a backend in the process that loads them: a driver that
+    must stay off the chip has its TPU workers report plain numbers
+    (chip_smoke.py) or pins its own jax to the CPU.  Importing jax
     eagerly in every worker would add seconds of startup, so this only
     fires once jax is already in sys.modules.
     """
@@ -92,26 +95,14 @@ def _maybe_install_jax_reducer():
         return (_rebuild, (np.asarray(arr),))
 
     try:
+        from jax._src.array import ArrayImpl
+
         copyreg.pickle(jax.Array, _reduce_jax_array)
         # concrete ArrayImpl class is what instances actually carry.
         # Imported, NOT discovered via type(jnp.zeros(())): creating an
-        # array initializes a backend, and in a process whose TPU-claim
-        # env was stripped AFTER interpreter start that init can hang on
-        # the half-registered device plugin.
-        try:
-            from jax._src.array import ArrayImpl
-        except ImportError:
-            # private path moved (jax upgrade): arrays fall back to
-            # jax's in-band pickling — functional but not zero-copy;
-            # say so instead of degrading silently
-            import warnings
-
-            warnings.warn(
-                "jax._src.array.ArrayImpl not importable; jax arrays will "
-                "serialize in-band (no zero-copy out-of-band buffers)"
-            )
-        else:
-            copyreg.pickle(ArrayImpl, _reduce_jax_array)
+        # array initializes a backend, and this runs in drivers, heads'
+        # clients and pool workers that must never take the chip.
+        copyreg.pickle(ArrayImpl, _reduce_jax_array)
     except Exception as e:  # noqa: BLE001
         import warnings
 

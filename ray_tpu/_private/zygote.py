@@ -8,15 +8,18 @@ a 1-core host turns ~1s/worker into ~30ms/worker — the difference
 between ~1/s and tens/s actor creation).
 
 The zygote is a single-threaded child of the raylet/head started with
-the POOL env (TPU claim stripped): it preimports the worker dependency
+the POOL env (JAX_PLATFORMS=cpu): it preimports the worker dependency
 closure once, then serves length-prefixed JSON spawn requests on stdin:
 
     {"env": {...}, "log": "<path>"}  ->  fork()
 
 The forked child applies the env, redirects stdio to the worker log,
 setsids, and runs worker_main.main(); the parent replies {"pid": n}.
-TPU workers never come from the zygote — their claim env must be present
-at interpreter start (sitecustomize), so they keep the exec path.
+A node's one TPU worker is exec'd, not forked here.  Nothing needs that any
+more — its platform is decided by the spawn env alone
+(_private/tpu.py worker_spawn_env), and jax is imported after the fork
+either way — but it is one process per node, so forking it would save a
+second per node, not per task.
 """
 
 from __future__ import annotations
@@ -69,8 +72,8 @@ def zygote_main():
                 pass
             # clear-and-set, not update-over-base: the request carries the
             # COMPLETE intended env, and keys deliberately absent from a
-            # later spawn's dict (e.g. TPU-claim vars stripped for pool
-            # workers) must not be silently inherited from whatever env
+            # later spawn's dict (e.g. the TPU-worker marker dropped for
+            # pool workers) must not be silently inherited from whatever env
             # the zygote itself was started with
             os.environ.clear()
             os.environ.update(req.get("env") or {})

@@ -51,6 +51,7 @@ from ray_tpu.exceptions import (
     RaySystemError,
     RayTaskError,
     TaskCancelledError,
+    TpuWorkerStuckError,
     WorkerCrashedError,
 )
 from ray_tpu.util.lockwitness import named_condition, named_lock
@@ -1212,11 +1213,19 @@ class CoreWorker:
         request would deadlock the loop)."""
         for oid in oids:
             oid = bytes(oid)
-            if oid in self._direct_pending:
-                self._defer_promotion(oid)
-                continue
+            # pending is read BEFORE the value: a reply stores the value and
+            # then pops _direct_pending, so "pending, and no value" means the
+            # call really is in flight.  Deferring on "pending" alone, inside
+            # that store-then-pop window, makes on_object_done (which sees
+            # the value) fire the callback at once, which defers again,
+            # until the stack overflows
+            pending = oid in self._direct_pending
             sobj = self._memory_store.get(oid)
             if sobj is None:
+                if pending and not (
+                    self.store is not None and self.store.contains(oid)
+                ):
+                    self._defer_promotion(oid)
                 continue
             self._promote_memory_objects(sobj.contained, _async=_async)
             if self.store is None:
@@ -2802,7 +2811,11 @@ class CoreWorker:
 
     def kill_actor(self, actor_id: bytes, no_restart: bool = True):
         self._owned_actors.discard(bytes(actor_id))
-        self.request(MsgType.KILL_ACTOR, {"actor_id": actor_id, "no_restart": no_restart})
+        reply = self.request(
+            MsgType.KILL_ACTOR, {"actor_id": actor_id, "no_restart": no_restart}
+        )
+        if reply.get("error"):
+            raise TpuWorkerStuckError(reply["error"])
 
     def cancel_task(self, task_id: bytes, force: bool = False):
         self.request(MsgType.CANCEL_TASK, {"task_id": task_id, "force": force})

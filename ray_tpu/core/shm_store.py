@@ -109,24 +109,14 @@ class _Lib:
         return cls._instance
 
 
-# memoryview() only delegates to a Python-level __buffer__ from 3.12 on
-# (PEP 688); before that, readers must fall back to copying under the pin
-_MEMORYVIEW_DELEGATES = sys.version_info >= (3, 12)
-
-
 class _PinnedRegion:
     """Buffer-protocol exporter that releases the store pin when collected.
 
     numpy arrays built over slices of ``memoryview(region)`` keep the region
     alive, so the pin (store refcount) outlives every zero-copy consumer —
     the moral equivalent of plasma's client-side release tracking
-    (reference: plasma/client.cc Release).
-
-    On Python < 3.12 ``memoryview(region)`` raises TypeError (PEP 688 is
-    3.12+), so callers there read through ``region._view`` and COPY the
-    bytes out while the region object — and therefore the pin — is still
-    alive: correct on every version, zero-copy where the interpreter
-    allows it.
+    (reference: plasma/client.cc Release).  ``memoryview(region)`` reaches
+    ``__buffer__`` through PEP 688, which is why the package needs 3.12.
     """
 
     def __init__(self, store: "ShmObjectStore", oid: bytes, view: memoryview):
@@ -257,12 +247,7 @@ class ShmObjectStore:
         if rc != 0:
             return None
         region = _PinnedRegion(self, object_id, self._mv[off.value : off.value + size.value])
-        if _MEMORYVIEW_DELEGATES:
-            view = memoryview(region)  # slices keep `region` (the pin) alive
-            copy_out = False
-        else:
-            view = region._view  # `region` local holds the pin while we read
-            copy_out = True
+        view = memoryview(region)  # slices keep `region` (the pin) alive
         (hlen,) = _U32.unpack(view[: _U32.size])
         pos = _U32.size
         metadata, inband_len, buf_lens = msgpack.unpackb(
@@ -273,16 +258,9 @@ class ShmObjectStore:
         pos = _pad(pos + inband_len)
         buffers: List[memoryview] = []
         for blen in buf_lens:
-            chunk = view[pos : pos + blen]
-            buffers.append(memoryview(bytes(chunk)) if copy_out else chunk)
+            buffers.append(view[pos : pos + blen])
             pos = _pad(pos + blen)
-        sobj = SerializedObject(bytes(metadata), inband, buffers)
-        if copy_out:
-            # pre-3.12 buffers are copies, but the pin contract must not be
-            # version-dependent: a live get_serialized() result keeps the
-            # object evict-exempt either way (test_pinned_not_evicted)
-            sobj._pin = region
-        return sobj
+        return SerializedObject(bytes(metadata), inband, buffers)
 
     def metadata_of(self, object_id: bytes) -> Optional[bytes]:
         """Metadata tag of a sealed object without materializing inband or
@@ -319,11 +297,7 @@ class ShmObjectStore:
         if rc != 0:
             return None
         region = _PinnedRegion(self, object_id, self._mv[off.value : off.value + size.value])
-        if _MEMORYVIEW_DELEGATES:
-            return memoryview(region)
-        # pre-3.12: copy the wire image out under the pin (`region` lives
-        # until after bytes() completes), then let the pin drop
-        return memoryview(bytes(region._view))
+        return memoryview(region)
 
     def raw_create(self, object_id: bytes, size: int) -> Optional[memoryview]:
         """Allocate an unsealed object of `size` bytes and return a writable

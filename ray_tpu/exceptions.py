@@ -193,6 +193,13 @@ class RaySystemError(RayError):
     pass
 
 
+class TpuWorkerStuckError(RaySystemError):
+    """A killed TPU worker's process outlived SIGTERM and SIGKILL
+    (_private/tpu.py reap_tpu_worker): its host's chips stay taken, so the
+    teardown that asked for the kill must not report success.  The message
+    names the pid."""
+
+
 class HeadUnreachableError(RaySystemError, ConnectionError):
     """The head (GCS) could not be reached within the bounded dial /
     reconnect window.  Typed so callers can tell a briefly-unreachable

@@ -57,7 +57,7 @@ class GPT2Config:
     # buffers) — the least-recompute policy that still fits a v5e chip at
     # batch 16 with the splash attention kernel
     remat_policy: str = "dots"
-    # "auto": pallas flash kernel on TPU, xla einsum elsewhere
+    # "auto": pallas splash kernel on TPU, xla einsum elsewhere
     attention_impl: str = "auto"
     # what the QK^T matmul writes: f32 (safe) or bf16 (half the [S,S] HBM
     # traffic; softmax still accumulates f32)
@@ -308,7 +308,7 @@ class GPT2Model:
                 out_specs=spec,
             )(q, k_, v_)
         else:
-            attn = self._causal_attention(q, k_, v_)
+            attn = self._causal_attention(q, k_, v_, mesh)
         attn = attn.reshape(B, S, E)
         x = x + (attn @ layer_params["proj_w"].astype(cd) + layer_params["proj_b"].astype(cd))
 
@@ -378,7 +378,7 @@ class GPT2Model:
         )(flat, router, ein, eout)
         return out.reshape(B, S, E)
 
-    def _causal_attention(self, q, k, v):
+    def _causal_attention(self, q, k, v, mesh=None):
         from ray_tpu.ops.attention import causal_attention
 
         return causal_attention(
@@ -387,6 +387,7 @@ class GPT2Model:
             v,
             impl=self.config.attention_impl,
             scores_dtype=self.config.attn_scores_dtype,
+            mesh=mesh,
         )
 
     def backbone(

@@ -154,7 +154,7 @@ class LlamaModel:
 
     # ------------------------------------------------------------- forward
 
-    def _layer(self, x, lp, positions, kv_cache=None, cache_index=None):
+    def _layer(self, x, lp, positions, kv_cache=None, cache_index=None, mesh=None):
         cfg = self.config
         cd = cfg.compute_dtype
         B, S, E = x.shape
@@ -204,7 +204,7 @@ class LlamaModel:
             # block kernel)
             from ray_tpu.ops.attention import causal_attention
 
-            attn = causal_attention(q, k, v).reshape(B, S, E)
+            attn = causal_attention(q, k, v, mesh=mesh).reshape(B, S, E)
         else:
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * (D**-0.5)
             scores = jnp.where(mask[None, None], scores, -1e30)
@@ -229,10 +229,10 @@ class LlamaModel:
         def body(x, lp):
             if cfg.remat:
                 y, _ = jax.checkpoint(
-                    lambda x_, lp_: self._layer(x_, lp_, positions)
+                    lambda x_, lp_: self._layer(x_, lp_, positions, mesh=mesh)
                 )(x, lp)
             else:
-                y, _ = self._layer(x, lp, positions)
+                y, _ = self._layer(x, lp, positions, mesh=mesh)
             return y, None
 
         x, _ = jax.lax.scan(body, x, params["layers"])

@@ -84,28 +84,21 @@ def make_mesh(config: MeshConfig, devices: Optional[Sequence] = None):
 
 
 def shard_map_compat(fn, mesh, in_specs, out_specs, manual_axes=None):
-    """shard_map across jax versions (jax.shard_map vs experimental;
-    check_vma vs check_rep) — the single shared wrapper for every SPMD
-    helper in this package.
+    """``jax.shard_map`` with this package's conventions (no replication
+    check) — the single shared wrapper for every SPMD helper here.
 
     manual_axes: restrict manual collectives to this subset of mesh axes —
     the REST stay compiler-managed ("auto") inside the body, so e.g. a
     GPipe schedule manual over pp can keep tp-sharded in-stage matmuls
     with XLA-inserted collectives (pp×tp composition)."""
-    try:
-        from jax import shard_map as _sm
+    import jax
 
-        kw = {"check_vma": False}
-        if manual_axes is not None and set(manual_axes) != set(mesh.axis_names):
-            kw["axis_names"] = frozenset(manual_axes)
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _sm
-
-        kw = {"check_rep": False}
-        if manual_axes is not None and set(manual_axes) != set(mesh.axis_names):
-            kw["auto"] = frozenset(set(mesh.axis_names) - set(manual_axes))
-        return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw)
+    kw = {}
+    if manual_axes is not None and set(manual_axes) != set(mesh.axis_names):
+        kw["axis_names"] = frozenset(manual_axes)
+    return jax.shard_map(
+        fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False, **kw
+    )
 
 
 def data_pspec(mesh) -> "object":

@@ -1005,11 +1005,17 @@ class ServeController:
     def delete_deployment(self, name: str):
         import ray_tpu
 
+        from ray_tpu.exceptions import TpuWorkerStuckError
+
         dep = self.deployments.pop(name, None)
         if dep:
             for r in dep["replicas"]:
                 try:
+                    # returns once a TPU replica's process is gone: the
+                    # caller may put the next model on the same chips
                     ray_tpu.kill(r)
+                except TpuWorkerStuckError:
+                    raise
                 except Exception:
                     pass
         self.version += 1

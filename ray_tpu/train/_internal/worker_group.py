@@ -10,6 +10,7 @@ from __future__ import annotations
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu.exceptions import TpuWorkerStuckError
 
 
 class TrainWorker:
@@ -118,9 +119,14 @@ class WorkerGroup:
         return ray_tpu.get(self.workers[rank].execute.remote(fn, *args, **kwargs), timeout=timeout)
 
     def shutdown(self):
+        """Kill the gang.  Returns once every TPU worker among them has
+        exited (ray_tpu.kill waits), so the chips are free for whatever the
+        caller starts next."""
         for w in self.workers:
             try:
                 ray_tpu.kill(w)
+            except TpuWorkerStuckError:
+                raise
             except Exception:
                 pass
         self.workers = []

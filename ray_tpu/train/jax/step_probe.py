@@ -46,9 +46,13 @@ from typing import Any, Dict, List, Optional
 
 from ray_tpu._private import task_events
 
-# bf16 peak FLOPs per chip for MFU when the caller doesn't supply one
-# (matched by substring against jax's device_kind string)
+# bf16 peak FLOP/s of one chip, by substring of jax's device_kind ("TPU v5
+# lite" is the v5e) — the one peaks table: the probe's MFU and the bench
+# scripts both read it.  Source: Google Cloud TPU documentation, the system
+# architecture page of each generation.
 _PEAK_FLOPS_BY_KIND = (
+    ("v6 lite", 918e12),
+    ("v6e", 918e12),
     ("v5p", 459e12),
     ("v5 lite", 197e12),
     ("v5e", 197e12),
@@ -56,6 +60,17 @@ _PEAK_FLOPS_BY_KIND = (
     ("v3", 123e12),
     ("v2", 46e12),
 )
+
+
+def peak_flops_per_device(device_kind: str) -> Optional[float]:
+    """Peak bf16 FLOP/s of one device of this kind, or None for a kind the
+    table does not hold — never another generation's number."""
+    kind = (device_kind or "").lower()
+    for key, flops in _PEAK_FLOPS_BY_KIND:
+        if key in kind:
+            return flops
+    return None
+
 
 _PHASE_NAMES = ("data_wait", "h2d", "compute", "metrics_fold")
 
@@ -186,24 +201,15 @@ class StepProbe:
         if not self.flops_per_step or mean_step_s <= 0:
             return None
         if self._peak_total is None:
-            per = self._peak_per_device
-            n_dev = 1
-            try:
-                import jax
+            import jax
 
-                devices = jax.devices()
-                n_dev = max(1, len(devices))
-                if per is None:
-                    kind = getattr(devices[0], "device_kind", "") or ""
-                    for key, flops in _PEAK_FLOPS_BY_KIND:
-                        if key in kind.lower():
-                            per = flops
-                            break
-            except Exception:  # graftlint: disable=silent-except -- no jax backend: MFU simply unavailable
-                pass
+            devices = jax.devices()
+            per = self._peak_per_device or peak_flops_per_device(
+                devices[0].device_kind
+            )
             if per is None:
-                return None
-            self._peak_total = per * n_dev
+                return None  # MFU not available on a device the table lacks
+            self._peak_total = per * len(devices)
         return self.flops_per_step / (mean_step_s * self._peak_total)
 
     # ----------------------------------------------------------- shipping

@@ -16,8 +16,8 @@ boxes exceeds any useful threshold (r03→r04 swung −31% on an idle-loop
 change of zero relevance), so gating them would only teach people to
 ignore the gate.  Comparability guards keep the gate honest: BENCH/SERVE
 rows only enter their series when the run executed on the TPU backend
-(``platform == "tpu"``) and exited rc=0 — a CPU-fallback run (r05's backend
-outage) is annotated in the table, not treated as a 100x regression.
+(``platform == "tpu"``) and exited rc=0 — a run that died, or did not run
+on the chip, is annotated in the table, not treated as a 100x regression.
 
 Usage::
 
@@ -26,7 +26,9 @@ Usage::
     python scripts/perf_trends.py --out trends.txt  # also write the table
     python scripts/perf_trends.py --no-gate       # table only, exit 0
 
-Wired into CI next to perf_smoke; the table uploads as a build artifact.
+The repo root no longer holds a BENCH series (PERF_LEDGER.jsonl is the
+record from this round on), so CI does not run this over the root; the
+parsers are exercised on tests/fixtures/perf_trends.
 """
 
 from __future__ import annotations

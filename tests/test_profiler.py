@@ -415,17 +415,22 @@ def _load_perf_trends():
 
 
 def test_perf_trends_real_trajectory_passes(capsys):
-    """The gate must pass on the repo's actual r01–r05 artifacts —
-    including the r05 BENCH backend-fallback run, which the
-    comparability guard excludes instead of scoring as a regression."""
+    """The gate must pass on a trajectory in the artifacts' real shapes
+    (tests/fixtures/perf_trends: the driver's BENCH capture, both PERF
+    layouts, a SERVE_BENCH row) — including a BENCH run that died before
+    printing a metric, which the comparability guard excludes instead of
+    scoring as a regression."""
     pt = _load_perf_trends()
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    rc = pt.main(["--dir", repo])
+    fixtures = os.path.join(
+        os.path.dirname(os.path.abspath(__file__)), "fixtures", "perf_trends"
+    )
+    rc = pt.main(["--dir", fixtures])
     out = capsys.readouterr().out
     assert rc == 0
     assert "bench.gpt2_tok_per_s_per_chip" in out
     assert "perf.queued_drain_per_sec" in out
-    assert "not comparable" in out  # the r05 fallback note surfaced
+    assert "serve.decode_tok_per_s_per_chip" in out
+    assert "not comparable" in out  # the dead run's note surfaced
 
 
 def test_perf_trends_synthetic_regression_fails(tmp_path, capsys):
