@@ -98,6 +98,26 @@ PHASES = (
     "train_step_end",
 )
 
+# Engine-thread span vocabulary (ray_tpu/serve/tracing.py ``span``): the
+# phases of ONE engine iteration, written as profiler TraceAnnotations on
+# the ``engine-<deployment>`` thread, so they sit on the device trace's
+# clock.  They complement the ``serve_*`` stamps above (a request's life
+# across threads, wall clock); graftlint GL008 checks literal span() sites
+# against this tuple, the benchmark's readers match the same names.
+ENGINE_SPANS = (
+    "engine/iteration",  # one whole _iteration(); parent of all but idle
+    "engine/admit",  # pending weights, defrags, reap, sched.admit()
+    "engine/prefill",  # one prefill chunk
+    "engine/decode",  # one decode step over the fleet
+    "engine/build",  # host arrays for the program call (in prefill/decode)
+    "engine/dispatch",  # the jitted call (holds the PjitFunction event)
+    "engine/sync",  # the blocking device->host read of the sampled tokens
+    "engine/deliver",  # note_token + deliver/retire of the step's tokens
+    "engine/flush",  # re-flush of streams whose ring was full
+    "engine/gauges",  # occupancy gauges, only when they publish
+    "engine/idle",  # the wake wait of a loop with no work
+)
+
 # Derived per-phase durations: name -> (start stamp, end stamp).
 # queue_wait/arg_fetch/exec/put pair stamps from ONE process and are immune
 # to cross-node clock skew; deliver (head→worker) and e2e (driver→head)
@@ -127,6 +147,9 @@ DURATIONS = {
     # the direct head-of-line-blocking signal (both stamps from the
     # replica process, clock-skew-immune)
     "serve_engine_queue": ("serve_engine_submit", "serve_engine_admit"),
+    # admitted but not yet prefilling: prefill is first-come-first-served,
+    # one chunk an iteration, so under a burst this is where TTFT goes
+    "serve_prefill_wait": ("serve_engine_admit", "serve_prefill_start"),
     "serve_queue_wait": ("serve_queue_enter", "serve_queue_exit"),
     "serve_batch_assemble": ("serve_queue_exit", "serve_batch_assembled"),
     "serve_prefill": ("serve_prefill_start", "serve_first_token"),

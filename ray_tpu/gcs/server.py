@@ -4899,6 +4899,7 @@ class HeadServer:
                 "ttft_s": req.get("ttft_s"),
                 "tpot_s": req.get("tpot_s"),
                 "tokens": int(req.get("tokens") or 0),
+                "rid": req.get("rid"),  # the engine's request id, if one served it
             }
             self.task_records.append(rec)
             for stage, dur in durs.items():

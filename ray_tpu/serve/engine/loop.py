@@ -16,6 +16,18 @@ Iteration shape (scheduler.py decides, this module executes):
     admit  →  [one prefill chunk]  →  [one decode step over the fleet]
            →  deliver frames  →  retire / recycle slots
 
+Inside a profiler capture the same shape reads as spans on this thread
+(``serve/tracing.py span``, vocabulary ``ENGINE_SPANS``), on the device
+trace's clock:
+
+    engine/iteration
+      engine/admit
+      engine/prefill   engine/build → engine/dispatch → [engine/sync → engine/deliver]
+      engine/decode    engine/build → engine/dispatch → engine/sync → engine/deliver
+      engine/flush     (only with laggard streams)
+      engine/gauges    (only when the gauges publish)
+    engine/idle        (the wake wait of a turn with no work)
+
 Nothing here talks to the head: token frames leave through delivery
 sinks (buffered result, or dag-channel streams via engine/transport.py)
 and observability leaves through the serve tracer's batched SERVE_TRACE
@@ -33,6 +45,8 @@ from typing import Any, Dict, List, Optional
 import numpy as np
 
 from ray_tpu.exceptions import EngineStreamError
+from ray_tpu.serve import tracing as serve_tracing
+from ray_tpu.serve.tracing import span
 from ray_tpu.serve.engine.kv_cache import PagedKVCache
 from ray_tpu.serve.engine.scheduler import (
     DECODE,
@@ -177,8 +191,6 @@ class InferenceEngine:
         """Enqueue one request.  Raises EngineOverloadedError on a full
         queue (the bounded failure mode), ValueError on capacity misuse,
         EngineStreamError after a fatal engine stop."""
-        from ray_tpu.serve import tracing as serve_tracing
-
         serve_tracing.stamp(trace, "serve_engine_submit")
         with self._lock:
             # stop checked UNDER the lock: a submit racing the loop's
@@ -194,6 +206,8 @@ class InferenceEngine:
                 trace=trace,
                 sink=sink if sink is not None else BufferSink(),
             )
+            if trace is not None:
+                trace["rid"] = req.rid  # joins the record to the engine's request
         # only an ACCEPTED request defers sealing to the engine — a
         # rejected one (overload/capacity) must still be sealed by the
         # submitting handler's finally, or its record would never ship
@@ -231,7 +245,8 @@ class InferenceEngine:
                         getattr(s, "flushable", lambda: False)()
                         for s in self._laggards
                     )
-                    self._wake.wait(0.002 if fast else 0.05)
+                    with span("engine/idle"):
+                        self._wake.wait(0.002 if fast else 0.05)
                     self._wake.clear()
                     continue
                 self._iteration()
@@ -294,37 +309,37 @@ class InferenceEngine:
         self.weight_updates += 1
 
     def _iteration(self) -> None:
-        from ray_tpu.serve import tracing as serve_tracing
+        with span("engine/iteration"):
+            self.iterations += 1
+            with span("engine/admit"):
+                self._apply_pending_params()
+                self._run_defrags()
+                with self._lock:
+                    self._reap_cancelled()
+                    admitted = self.sched.admit()
+                for req in admitted:
+                    serve_tracing.stamp(req.trace, "serve_engine_admit")
 
-        self.iterations += 1
-        self._apply_pending_params()
-        self._run_defrags()
-        with self._lock:
-            self._reap_cancelled()
-            admitted = self.sched.admit()
-        for req in admitted:
-            serve_tracing.stamp(req.trace, "serve_engine_admit")
+            # -- one prefill chunk (chunked: decode never waits on a whole prompt)
+            with self._lock:
+                pf = self.sched.next_prefill()
+            if pf is not None:
+                with span("engine/prefill"):
+                    self._prefill_chunk(*pf)
 
-        # -- one prefill chunk (chunked: decode never waits on a whole prompt)
-        with self._lock:
-            pf = self.sched.next_prefill()
-        if pf is not None:
-            self._prefill_chunk(*pf)
-
-        # -- one decode step over the whole fleet: ONE program, any mix of
-        # sequence lengths, inactive slots masked
-        fleet = self.sched.decode_fleet()
-        if fleet:
-            self._decode_step(fleet)
-        self._flush_laggards()
-        self._maybe_gauges()
+            # -- one decode step over the whole fleet: ONE program, any mix of
+            # sequence lengths, inactive slots masked
+            fleet = self.sched.decode_fleet()
+            if fleet:
+                with span("engine/decode"):
+                    self._decode_step(fleet)
+            self._flush_laggards()
+            self._maybe_gauges()
 
     def _reap_cancelled(self) -> None:
         """Lock held.  Retire cancelled running requests at the iteration
         boundary — and seal their (deferred) trace records: a cancelled
         request still happened."""
-        from ray_tpu.serve import tracing as serve_tracing
-
         victims = [r for r in self.running_snapshot() if r.cancelled]
         for req in victims:
             self.sched.retire(req, error=None)
@@ -339,69 +354,75 @@ class InferenceEngine:
         return list(self.sched.running.values())
 
     def _prefill_chunk(self, req: EngineRequest, start: int, toks: List[int]) -> None:
-        from ray_tpu.serve import tracing as serve_tracing
-
-        if start == 0:
-            serve_tracing.stamp(req.trace, "serve_prefill_start")
-        C = self.cfg.prefill_chunk
-        n_valid = len(toks)
-        chunk = np.zeros(C, np.int32)
-        chunk[:n_valid] = toks
-        first, self._pages = self._programs["prefill"](
-            self.llm.params,
-            self._pages,
-            np.ascontiguousarray(self.cache.tables[req.slot]),
-            chunk,
-            np.int32(start),
-            np.int32(n_valid),
-        )
+        with span("engine/build"):
+            if start == 0:
+                serve_tracing.stamp(req.trace, "serve_prefill_start")
+            C = self.cfg.prefill_chunk
+            n_valid = len(toks)
+            chunk = np.zeros(C, np.int32)
+            chunk[:n_valid] = toks
+            table = np.ascontiguousarray(self.cache.tables[req.slot])
+        with span("engine/dispatch"):
+            first, self._pages = self._programs["prefill"](
+                self.llm.params,
+                self._pages,
+                table,
+                chunk,
+                np.int32(start),
+                np.int32(n_valid),
+            )
         if not self.sched.note_prefill(req, n_valid):
             return
         # prompt fully resident: the chunk's sampled token IS the first
         # generated token, host-visible right here — the TTFT endpoint
-        tok0 = int(first)
-        serve_tracing.stamp(req.trace, "serve_first_token")
-        req.state = DECODE
-        with self._lock:
-            finished = self.sched.note_token(req, tok0)
-        if finished:
-            self._retire(req, last_tokens=[tok0])
-        else:
-            self._deliver(req, [tok0])
+        with span("engine/sync"):
+            tok0 = int(first)
+        with span("engine/deliver"):
+            serve_tracing.stamp(req.trace, "serve_first_token")
+            req.state = DECODE
+            with self._lock:
+                finished = self.sched.note_token(req, tok0)
+            if finished:
+                self._retire(req, last_tokens=[tok0])
+            else:
+                self._deliver(req, [tok0])
 
     def _decode_step(self, fleet: List[EngineRequest]) -> None:
-        S = self.cfg.num_slots
-        tokens = np.zeros(S, np.int32)
-        positions = np.zeros(S, np.int32)
-        active = np.zeros(S, bool)
-        for req in fleet:
-            s = req.slot
-            tokens[s] = req.out[-1]
-            positions[s] = req.prompt_len + len(req.out) - 1
-            active[s] = True
-        nxt, self._pages = self._programs["decode"](
-            self.llm.params,
-            self._pages,
-            np.ascontiguousarray(self.cache.tables),
-            tokens,
-            positions,
-            active,
-        )
-        nxt = np.asarray(nxt)  # the per-step host sync: the token frontier
-        for req in fleet:
-            tok = int(nxt[req.slot])
-            with self._lock:
-                finished = self.sched.note_token(req, tok)
-            if finished:
-                self._retire(req, last_tokens=[tok])
-            else:
-                self._deliver(req, [tok])
+        with span("engine/build"):
+            S = self.cfg.num_slots
+            tokens = np.zeros(S, np.int32)
+            positions = np.zeros(S, np.int32)
+            active = np.zeros(S, bool)
+            for req in fleet:
+                s = req.slot
+                tokens[s] = req.out[-1]
+                positions[s] = req.prompt_len + len(req.out) - 1
+                active[s] = True
+            tables = np.ascontiguousarray(self.cache.tables)
+        with span("engine/dispatch"):
+            nxt, self._pages = self._programs["decode"](
+                self.llm.params,
+                self._pages,
+                tables,
+                tokens,
+                positions,
+                active,
+            )
+        with span("engine/sync"):
+            nxt = np.asarray(nxt)  # the per-step host sync: the token frontier
+        with span("engine/deliver"):
+            for req in fleet:
+                tok = int(nxt[req.slot])
+                with self._lock:
+                    finished = self.sched.note_token(req, tok)
+                if finished:
+                    self._retire(req, last_tokens=[tok])
+                else:
+                    self._deliver(req, [tok])
 
     # ----------------------------------------------------------- delivery
 
     def _retire(self, req: EngineRequest, last_tokens: Optional[List[int]] = None) -> None:
-        from ray_tpu.serve import tracing as serve_tracing
-
         serve_tracing.stamp(req.trace, "serve_decode_end")
         if req.trace is not None:
             req.trace["tokens"] = len(req.out)
@@ -417,8 +438,6 @@ class InferenceEngine:
         done: bool = False,
         error: Optional[str] = None,
     ) -> None:
-        from ray_tpu.serve import tracing as serve_tracing
-
         if error is not None:
             serve_tracing.stamp(req.trace, "serve_decode_end")
             serve_tracing.finish_request(req.trace, error=True, final=True)
@@ -436,13 +455,16 @@ class InferenceEngine:
         """Re-flush streams whose channel ring was full at emit time —
         the consumer drains slots at its own pace, so delivery of a
         sequence longer than the ring depth completes here."""
-        for sink in list(self._laggards):
-            try:
-                sink.flush()
-                if not sink.needs_flush():
+        if not self._laggards:
+            return
+        with span("engine/flush"):
+            for sink in list(self._laggards):
+                try:
+                    sink.flush()
+                    if not sink.needs_flush():
+                        self._laggards.discard(sink)
+                except Exception:  # noqa: BLE001 -- broken stream: its consumer sees the typed error
                     self._laggards.discard(sink)
-            except Exception:  # noqa: BLE001 -- broken stream: its consumer sees the typed error
-                self._laggards.discard(sink)
 
     # -------------------------------------------------------------- defrag
 
@@ -520,6 +542,10 @@ class InferenceEngine:
         if not force and now - self._last_gauges < self.cfg.gauge_period_s:
             return
         self._last_gauges = now
+        with span("engine/gauges"):
+            self._publish_gauges()
+
+    def _publish_gauges(self) -> None:
         try:
             from ray_tpu._private import worker as worker_mod
 

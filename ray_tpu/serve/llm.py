@@ -314,18 +314,28 @@ class ShardedLLM:
         # — one silent recompile per program, exactly what the engine's
         # no-recompilation contract forbids
         step_out = (repl, (page_sharding, page_sharding))
+
+        def program(fn, *args, **kwargs):
+            # a bare partial has no __name__: the compiler would call the
+            # module jit__unknown.  Named after the model's method, the
+            # device trace's XLA Modules line and the host's PjitFunction
+            # dispatch event carry the same stable name
+            bound = functools.partial(fn, *args, **kwargs)
+            bound.__name__ = fn.__name__
+            return bound
+
         return {
             "init": jax.jit(
-                functools.partial(self.model.init_pages, num_pages, page_size),
+                program(self.model.init_pages, num_pages, page_size),
                 out_shardings=(page_sharding, page_sharding),
             ),
             "prefill": jax.jit(
-                functools.partial(self.model.prefill_chunk_paged, page_size=page_size),
+                program(self.model.prefill_chunk_paged, page_size=page_size),
                 donate_argnums=(1,),
                 out_shardings=step_out,
             ),
             "decode": jax.jit(
-                functools.partial(self.model.decode_step_paged, page_size=page_size),
+                program(self.model.decode_step_paged, page_size=page_size),
                 donate_argnums=(1,),
                 out_shardings=step_out,
             ),
@@ -686,8 +696,18 @@ def engine_llm_deployment(
                 self.engine.reconfigure(max_queue=int(user_config["max_queue"]))
 
         def info(self):
+            import jax
+
+            local = jax.local_devices()
             return {
                 "platform": self.platform,
+                "device_kind": local[0].device_kind,
+                "device_count": len(local),
+                # weights + page pool; a program's temporaries are not in it
+                "peak_bytes_in_use": max(
+                    int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+                    for d in local
+                ),
                 "params_b": round(self.llm.cfg.num_params() / 1e9, 2),
                 "tp": self.llm.tp,
                 "engine": self.engine.stats(),

@@ -17,6 +17,17 @@ first-class TTFT/TPOT distributions for the LLM path.
 Stage stamps come from the canonical ``task_events.PHASES`` vocabulary
 (the ``serve_*`` block — graftlint GL008 checks literal stamp sites).
 
+Two tools, one module.  STAMPS (``stamp``, above) are for a request's
+life: it crosses threads and processes and lasts tens of milliseconds, so
+they read the wall clock and ship to the head.  SPANS (``span``) are for
+one thread's phases against the device — the engine thread's turn, whose
+sub-millisecond parts mean something only by their position relative to
+device activity.  A span is a ``jax.profiler.TraceAnnotation``: it lies
+on the profiler's clock beside the device trace, exists only inside a
+profiler capture (``ray-tpu profile --deep``, or a benchmark's traced
+run) and is never shipped anywhere.  Span names are the closed vocabulary
+``ENGINE_SPANS`` (GL008 checks literal ``span()`` sites too).
+
 Overhead contract: when recording is off (``RAY_TPU_TASK_EVENTS=0``)
 ``new_request()`` returns None after one flag check, and every
 downstream site gates on that None — no dict, no clock read, no extra
@@ -34,6 +45,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
@@ -50,8 +62,25 @@ _current_batch: contextvars.ContextVar[Optional[List[dict]]] = contextvars.Conte
 )
 
 
+ENGINE_SPANS = task_events.ENGINE_SPANS
+_NO_SPAN = contextlib.nullcontext()
+
+
 def enabled() -> bool:
     return task_events.enabled
+
+
+def span(name: str):
+    """Context manager for one phase of the calling thread's work, as a
+    profiler ``TraceAnnotation`` named ``name`` (from ``ENGINE_SPANS``).
+    With no profiler capture running, entering one is a single atomic
+    check in C++, so there is nothing to switch on or off.  This module
+    never imports jax (the discipline of ``_private/profiler.py``): a
+    process that has not imported it gets the shared no-op."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return _NO_SPAN
+    return jax.profiler.TraceAnnotation(name)
 
 
 def new_request(deployment: str = "") -> Optional[dict]:

@@ -20,6 +20,9 @@ stamps.  This rule pins that schema at the call sites:
   come from the canonical ``task_events.PHASES`` vocabulary — a typo'd
   phase silently vanishes from every duration, histogram, and timeline
   sub-span that joins on the canonical names.
+- engine-thread profiler spans (serve/tracing.py): a literal
+  ``span("...")`` name must come from ``task_events.ENGINE_SPANS`` — the
+  trace readers match the same names.
 
 Non-literal arguments are skipped (runtime sanitization in
 h_record_event covers them).
@@ -96,6 +99,8 @@ class EventRecordSchemaChecker(FileChecker):
                 yield from self._check_wire(ctx, node)
             elif name == "stamp" and len(node.args) >= 2:
                 yield from self._check_phase_name(ctx, node, _const_str(node.args[1]))
+            elif name == "span" and node.args:
+                yield from self._check_span_name(ctx, node, _const_str(node.args[0]))
 
     @staticmethod
     def _is_phase_dict(base: ast.expr) -> bool:
@@ -122,6 +127,18 @@ class EventRecordSchemaChecker(FileChecker):
                 f"phase stamp {phase!r} is not in the canonical "
                 f"task_events.PHASES vocabulary {sorted(vocab)}: a drifted "
                 "name drops out of every duration/histogram/timeline join",
+            )
+
+    def _check_span_name(self, ctx: FileContext, node, name) -> Iterator[Finding]:
+        from ray_tpu._private.task_events import ENGINE_SPANS
+
+        if name is not None and name not in ENGINE_SPANS:
+            yield ctx.finding(
+                self.rule,
+                node,
+                f"span {name!r} is not in task_events.ENGINE_SPANS "
+                f"{sorted(ENGINE_SPANS)}: a reader of the profiler trace "
+                "matches spans by these names",
             )
 
     def _check_direct(self, ctx: FileContext, node: ast.Call) -> Iterator[Finding]:

@@ -632,6 +632,28 @@ def test_event_schema_flags_bad_stamp_call_and_accepts_canonical(tmp_path):
     assert len(got) == 1 and "not_a_phase" in got[0].message
 
 
+def test_event_schema_flags_span_outside_the_engine_vocabulary(tmp_path):
+    """Engine-thread profiler spans are matched by name in trace readers:
+    a literal span() name must come from task_events.ENGINE_SPANS."""
+    findings = lint_file(
+        tmp_path,
+        "serve/engine/spans_use.py",
+        """
+        from ray_tpu.serve.tracing import span
+
+        def turn(name):
+            with span("engine/decode"):
+                pass
+            with span("engine/decod"):   # typo'd span
+                pass
+            with span(name):             # non-literal: skipped
+                pass
+        """,
+    )
+    got = [f for f in findings if f.rule_name == "event-record-schema"]
+    assert len(got) == 1 and "engine/decod" in got[0].message
+
+
 # --------------------------------------------------------------------- GL009
 
 

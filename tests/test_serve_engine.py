@@ -370,7 +370,13 @@ def test_engine_tracing_stamps_and_single_seal(tiny_llm):
             "serve_first_token", "serve_decode_end",
         ):
             assert stage in ph, stage
-        assert ph["serve_engine_submit"] <= ph["serve_engine_admit"] <= ph["serve_first_token"]
+        assert (
+            ph["serve_engine_submit"] <= ph["serve_engine_admit"]
+            <= ph["serve_prefill_start"] <= ph["serve_first_token"]
+        )
+        # admitted, waiting for its first prefill chunk: a stage of its own
+        assert task_events.durations(ph)["serve_prefill_wait"] >= 0.0
+        assert rec["rid"] == req.rid  # joins the record to the engine's request
         assert rec["ttft_s"] is not None and rec["tpot_s"] is not None
         assert rec["tokens"] == 4
         assert not any(k.startswith("_") for k in rec)
@@ -421,6 +427,40 @@ def test_engine_deployment_buffered_and_mixed(engine_cluster):
         timeout=60,
     )
     assert stats["compile_prefill"] == 1.0 and stats["compile_decode"] == 1.0
+
+
+def test_engine_deployment_info_and_fetched_request_records(engine_cluster):
+    """info() names the replica's devices, and a request's sealed record
+    comes back whole from the head: the engine's rid, every stamp, and
+    the admitted-to-prefill wait as a stage of its own."""
+    from ray_tpu.experimental.state.api import summarize_workloads
+
+    _, handle = engine_cluster
+    info = ray_tpu.get(
+        serve.get_deployment_handle("llm").method("info").remote(), timeout=60
+    )
+    assert info["platform"] == "cpu" and info["device_kind"]
+    assert info["device_count"] >= 1 and info["peak_bytes_in_use"] >= 0
+    ray_tpu.get(
+        [handle.remote({"prompt": [7] * n, "max_new_tokens": 3}) for n in (2, 6, 9)],
+        timeout=120,
+    )
+    records = []
+    deadline = time.monotonic() + 20.0
+    while time.monotonic() < deadline:
+        reply = summarize_workloads("serve", limit=50)
+        records = [r for r in reply.get("records", []) if r["name"] == "serve:llm"]
+        if len([r for r in records if r.get("rid") is not None]) >= 3:
+            break
+        time.sleep(0.2)
+    engine_records = [r for r in records if r.get("rid") is not None]
+    assert len(engine_records) >= 3, reply
+    assert len({r["rid"] for r in engine_records}) == len(engine_records)
+    for rec in engine_records:
+        assert rec["durations"]["serve_prefill_wait"] >= 0.0
+        assert rec["phases"]["serve_engine_admit"] <= rec["phases"]["serve_prefill_start"]
+    stages = {row["stage"] for row in reply["summary"] if row["deployment"] == "llm"}
+    assert "serve_prefill_wait" in stages
 
 
 def test_stream_tokens_incremental_and_ordered(engine_cluster):
