@@ -20,15 +20,29 @@ Everything that enforces the rule reads it from here:
 from __future__ import annotations
 
 import glob
+import json
+import logging
 import os
 import signal
 import time
 from typing import Dict, Mapping, Optional
 
+logger = logging.getLogger(__name__)
+
 _CACHE_ENV = "JAX_COMPILATION_CACHE_DIR"
-# how long a signalled TPU worker gets to exit before SIGKILL, and then to die
+# How long a signalled TPU worker gets to exit before SIGKILL, and then to
+# die.  From the ``tpu_worker_reaped`` lines of 15 teardowns on a v5e (7 on
+# one chip, 8 on the four-chip host, PERF.md section 7, PR 28): libtpu's
+# SIGTERM handler lets go in 2.1-2.5 s, once in 7.1 s, so 10 s is over what
+# was seen.  The wait after SIGKILL is NOT measured: none of those teardowns
+# needed the SIGKILL.  All that is known is one line of PR 26's log, a
+# four-chip worker still alive 5 s after it (in the kernel, releasing its
+# chips' mappings), which failed a finished run.  40 s is a guess with room
+# for that one case, kept under the 60 s of the generic RPC timeout that
+# callers of kill wait with; set it from ``tpu_worker_reaped`` lines with
+# ``sigkill`` true once there are some.
 _EXIT_WAIT_S = 10.0
-_KILL_WAIT_S = 5.0
+_KILL_WAIT_S = 40.0
 REAP_WAIT_S = _EXIT_WAIT_S + _KILL_WAIT_S
 
 
@@ -97,14 +111,33 @@ def wait_pid_exit(pid: int, timeout: float) -> bool:
 def reap_tpu_worker(pid: int) -> Optional[str]:
     """Wait for an already signalled TPU worker on this host to exit,
     escalating to SIGKILL.  Returns None once it is gone, or the error to
-    show the caller: its chips stay taken for as long as it lives."""
-    if wait_pid_exit(pid, _EXIT_WAIT_S):
-        return None
-    try:
-        os.kill(pid, signal.SIGKILL)
-    except ProcessLookupError:
-        return None
-    if wait_pid_exit(pid, _KILL_WAIT_S):
+    show the caller: its chips stay taken for as long as it lives.  Logs one
+    line saying how long the worker took to go and whether it needed the
+    SIGKILL: the waits above are set from those lines."""
+    t0 = time.monotonic()
+    gone = wait_pid_exit(pid, _EXIT_WAIT_S)
+    sigkill = not gone
+    if sigkill:
+        try:
+            os.kill(pid, signal.SIGKILL)
+            gone = wait_pid_exit(pid, _KILL_WAIT_S)
+        except ProcessLookupError:
+            gone = True
+    logger.log(
+        logging.WARNING if sigkill else logging.INFO,  # a process without a logging set-up still shows the SIGKILL case
+        "%s",
+        json.dumps(
+            {
+                "event": "tpu_worker_reaped",
+                "pid": pid,
+                "chips": detect_chips(),
+                "seconds": round(time.monotonic() - t0, 3),
+                "sigkill": sigkill,
+                "gone": gone,
+            }
+        ),
+    )
+    if gone:
         return None
     return (
         f"TPU worker pid {pid} is still alive {REAP_WAIT_S:.0f}s "

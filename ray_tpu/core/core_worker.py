@@ -17,6 +17,7 @@ Wait:1268, CreateActor:1680, SubmitActorTask:1913) plus its Cython binding
 from __future__ import annotations
 
 import asyncio
+import collections
 import concurrent.futures
 import hashlib
 import logging
@@ -26,6 +27,7 @@ import time
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from ray_tpu._private import serialization
+from ray_tpu._private import tpu as tpu_env
 from ray_tpu._private.config import RayConfig
 from ray_tpu._private.ids import JobID, ObjectID, TaskID, WorkerID
 from ray_tpu._private.log_plane import LOG_TAIL_MARKER
@@ -253,6 +255,12 @@ class CoreWorker:
         self._put_lock = named_lock("CoreWorker._put_lock")
         self._local_refs: Dict[bytes, int] = {}
         self._refs_lock = named_lock("CoreWorker._refs_lock")
+        # oids whose ObjectRef died, not yet counted down.  The collector
+        # runs ObjectRef.__del__ on whatever thread it interrupts, also one
+        # inside a section that holds _refs_lock (a tier-1 run hung there,
+        # waiting for itself), so __del__ takes no lock: it appends here and
+        # the flush loop counts down under the lock
+        self._released: "collections.deque[bytes]" = collections.deque()
         self._pending_removals: List[bytes] = []
         self._pending_adds: List[bytes] = []
         self._submit_buffer: List[dict] = []
@@ -735,6 +743,7 @@ class CoreWorker:
         # head dedupes via its recent-done ring / sealed returns); snapshot
         # under the lock — executor/user threads mutate both rings
         with self._refs_lock:
+            self._count_down_released()  # a submit whose refs all died is not replayed
             adds, self._pending_adds = self._pending_adds, []
             dones = list(self._done_ring)
             unacked = list(self._unacked_submits.values())
@@ -927,6 +936,8 @@ class CoreWorker:
     async def _gc_flush_loop(self):
         while True:
             await asyncio.sleep(0.2)
+            with self._refs_lock:
+                self._count_down_released()
             if not self._head_up.is_set():
                 continue  # head mid-restart: keep batching, flush after
             # adds flush BEFORE removals so this process's +/- pairs can
@@ -974,27 +985,35 @@ class CoreWorker:
                 self._pending_adds.append(oid)
 
     def _remove_local_ref(self, oid: bytes):
-        with self._refs_lock:
+        """``ObjectRef.__del__``: lock-free (see ``_released``).  A count
+        that lags is safe: it only keeps an object a flush tick longer."""
+        self._released.append(oid)
+
+    def _count_down_released(self):
+        """Caller holds ``_refs_lock``."""
+        released = self._released
+        while released:
+            oid = released.popleft()
             n = self._local_refs.get(oid, 0) - 1
-            if n <= 0:
-                self._local_refs.pop(oid, None)
-                self._pending_removals.append(oid)
-                # direct-call results live only in this process: last local
-                # ref gone = value unreachable
-                self._memory_store.pop(oid, None)
-                # head-FT: a fire-and-forget submit retires once NO return
-                # ref survives — nobody awaits it, so replaying it after a
-                # reattach could only double-run its side effects
-                # (ObjectID = task_id(24) + return index)
-                tid = oid[:24]
-                wire = self._unacked_submits.get(tid)
-                if wire is not None and not any(
-                    tid + i.to_bytes(4, "little") in self._local_refs
-                    for i in range(int(wire.get("num_returns", 1)))
-                ):
-                    self._unacked_submits.pop(tid, None)
-            else:
+            if n > 0:
                 self._local_refs[oid] = n
+                continue
+            self._local_refs.pop(oid, None)
+            self._pending_removals.append(oid)
+            # direct-call results live only in this process: last local
+            # ref gone = value unreachable
+            self._memory_store.pop(oid, None)
+            # head-FT: a fire-and-forget submit retires once NO return
+            # ref survives — nobody awaits it, so replaying it after a
+            # reattach could only double-run its side effects
+            # (ObjectID = task_id(24) + return index)
+            tid = oid[:24]
+            wire = self._unacked_submits.get(tid)
+            if wire is not None and not any(
+                tid + i.to_bytes(4, "little") in self._local_refs
+                for i in range(int(wire.get("num_returns", 1)))
+            ):
+                self._unacked_submits.pop(tid, None)
 
     # ------------------------------------------------------------ functions
 
@@ -2811,8 +2830,13 @@ class CoreWorker:
 
     def kill_actor(self, actor_id: bytes, no_restart: bool = True):
         self._owned_actors.discard(bytes(actor_id))
+        # the head answers once a TPU worker has let go of its chips, which
+        # can take the whole reap wait: the generic RPC timeout must not cut
+        # a teardown that is still inside it
         reply = self.request(
-            MsgType.KILL_ACTOR, {"actor_id": actor_id, "no_restart": no_restart}
+            MsgType.KILL_ACTOR,
+            {"actor_id": actor_id, "no_restart": no_restart},
+            timeout=max(RayConfig.rpc_timeout_s, tpu_env.REAP_WAIT_S + 10),
         )
         if reply.get("error"):
             raise TpuWorkerStuckError(reply["error"])

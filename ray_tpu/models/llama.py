@@ -1,4 +1,10 @@
-"""Llama family, TPU-first: RMSNorm + RoPE + SwiGLU + grouped-query attn.
+"""Llama family, TPU-first: RMSNorm + RoPE + grouped-query attention, with a
+dense SwiGLU FFN (Llama, Mistral) or -- ``LlamaConfig.n_experts`` -- a routed
+layer of SwiGLU experts, dropless top-k with a float32 router
+(``parallel/moe.py dropless_moe_ffn``), and optionally RMSNorm on the query
+and key projections (``qk_norm``): together the OLMoE block.  One FFN function
+(``_ffn``) serves the training forward, the contiguous-cache decode and the
+engine's two paged programs.
 
 The serving-side flagship (BASELINE config #5: Serve Llama-2-7B replica).
 Same functional conventions as gpt2.py — pytree params with stacked
@@ -40,6 +46,20 @@ class LlamaConfig:
     compute_dtype: Any = jnp.bfloat16
     param_dtype: Any = jnp.float32
     remat: bool = True
+    # sparse experts (OLMoE): n_experts > 0 replaces the dense FFN by
+    # n_experts SwiGLU experts of width hidden_dim, n_experts_per_tok of
+    # them a token, weighted by the router's softmax over ALL experts
+    # (not renormalised over the chosen ones); qk_norm puts an RMSNorm
+    # over the whole query and key projections, before the heads
+    n_experts: int = 0
+    n_experts_per_tok: int = 0
+    qk_norm: bool = False
+
+    def __post_init__(self):
+        if self.n_experts and not 0 < self.n_experts_per_tok <= self.n_experts:
+            raise ValueError(
+                f"n_experts_per_tok={self.n_experts_per_tok} must lie in 1..n_experts={self.n_experts}"
+            )
 
     @property
     def head_dim(self) -> int:
@@ -72,10 +92,18 @@ class LlamaConfig:
         return cls(dim=64, n_layers=2, n_heads=4, n_kv_heads=2, hidden_dim=128, **kw)
 
     def num_params(self) -> int:
-        E, L, H = self.dim, self.n_layers, self.hidden_dim
+        E, L, H, X = self.dim, self.n_layers, self.hidden_dim, self.n_experts
         kv_dim = self.n_kv_heads * self.head_dim
-        per_layer = 2 * E * E + 2 * E * kv_dim + 3 * E * H + 2 * E
+        ffn = X * (E + 3 * E * H) if X else 3 * E * H  # router + experts, or one SwiGLU
+        qk = E + kv_dim if self.qk_norm else 0
+        per_layer = 2 * E * E + 2 * E * kv_dim + ffn + 2 * E + qk
         return int(self.padded_vocab * E * 2 + L * per_layer + E)
+
+    def active_params_per_token(self) -> int:
+        """Parameters one token's forward pass multiplies by: all of them
+        but the experts it was not routed to."""
+        idle = max(0, self.n_experts - self.n_experts_per_tok)
+        return int(self.num_params() - self.n_layers * idle * 3 * self.dim * self.hidden_dim)
 
 
 def _rms_norm(x, scale, eps):
@@ -110,49 +138,107 @@ class LlamaModel:
         pd = cfg.param_dtype
         k = iter(jax.random.split(rng, 10))
         std = 0.02
+        X = cfg.n_experts
+        ffn_in, ffn_out = ((L, X, E, H), (L, X, H, E)) if X else ((L, E, H), (L, H, E))
 
         def norm(key, shape, s=std):
             return (jax.random.normal(key, shape) * s).astype(pd)
 
-        return {
-            "tok_emb": norm(next(k), (V, E)),
-            "out_head": norm(next(k), (E, V)),
-            "final_norm": jnp.ones((E,), pd),
-            "layers": {
-                "attn_norm": jnp.ones((L, E), pd),
-                "ffn_norm": jnp.ones((L, E), pd),
-                "wq": norm(next(k), (L, E, E)),
-                "wk": norm(next(k), (L, E, kv_dim)),
-                "wv": norm(next(k), (L, E, kv_dim)),
-                "wo": norm(next(k), (L, E, E), std / math.sqrt(2 * L)),
-                "w_gate": norm(next(k), (L, E, H)),
-                "w_up": norm(next(k), (L, E, H)),
-                "w_down": norm(next(k), (L, H, E), std / math.sqrt(2 * L)),
-            },
+        # keys are drawn in the order the dense tree always drew them, so a
+        # dense config's weights are what they were before experts existed
+        tok_emb, out_head = norm(next(k), (V, E)), norm(next(k), (E, V))
+        layers = {
+            "attn_norm": jnp.ones((L, E), pd),
+            "ffn_norm": jnp.ones((L, E), pd),
+            "wq": norm(next(k), (L, E, E)),
+            "wk": norm(next(k), (L, E, kv_dim)),
+            "wv": norm(next(k), (L, E, kv_dim)),
+            "wo": norm(next(k), (L, E, E), std / math.sqrt(2 * L)),
+            "w_gate": norm(next(k), ffn_in),
+            "w_up": norm(next(k), ffn_in),
+            "w_down": norm(next(k), ffn_out, std / math.sqrt(2 * L)),
         }
+        if X:
+            layers["router"] = norm(next(k), (L, E, X))
+        if cfg.qk_norm:
+            layers["q_norm"] = jnp.ones((L, E), pd)
+            layers["k_norm"] = jnp.ones((L, kv_dim), pd)
+        return {"tok_emb": tok_emb, "out_head": out_head, "final_norm": jnp.ones((E,), pd), "layers": layers}
 
     def param_pspecs(self, mesh=None) -> Dict[str, Any]:
         # mesh accepted for interface parity with GPT2Model (whose pp path
         # re-layers the specs); llama pp integration rides the same pipeline
         # primitive when needed
+        cfg = self.config
+        layers = {
+            "attn_norm": P("fsdp", None),
+            "ffn_norm": P("fsdp", None),
+            "wq": P("fsdp", None, "tp"),
+            "wk": P("fsdp", None, "tp"),
+            "wv": P("fsdp", None, "tp"),
+            "wo": P("fsdp", "tp", None),
+            "w_gate": P("fsdp", None, "tp"),
+            "w_up": P("fsdp", None, "tp"),
+            "w_down": P("fsdp", "tp", None),
+        }
+        if cfg.n_experts:
+            # whole experts over tp (their expert dimension); the router is
+            # small and every device needs all of it
+            for name in ("w_gate", "w_up", "w_down"):
+                layers[name] = P("fsdp", "tp", None, None)
+            layers["router"] = P("fsdp", None, None)
+        if cfg.qk_norm:
+            # the norm is over the whole projection: its scale stays whole
+            layers["q_norm"] = P("fsdp", None)
+            layers["k_norm"] = P("fsdp", None)
         return {
             "tok_emb": P("tp", None),
             "out_head": P(None, "tp"),
             "final_norm": P(None),
-            "layers": {
-                "attn_norm": P("fsdp", None),
-                "ffn_norm": P("fsdp", None),
-                "wq": P("fsdp", None, "tp"),
-                "wk": P("fsdp", None, "tp"),
-                "wv": P("fsdp", None, "tp"),
-                "wo": P("fsdp", "tp", None),
-                "w_gate": P("fsdp", None, "tp"),
-                "w_up": P("fsdp", None, "tp"),
-                "w_down": P("fsdp", "tp", None),
-            },
+            "layers": layers,
         }
 
     # ------------------------------------------------------------- forward
+
+    def _qkv(self, x, lp, positions):
+        """Normed input -> rotated queries and keys, values, split into
+        heads.  With ``qk_norm`` the query and key projections pass through
+        an RMSNorm over their whole width before the split (OLMoE)."""
+        cfg = self.config
+        cd = cfg.compute_dtype
+        B, S, _ = x.shape
+        H, KV, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        h = _rms_norm(x, lp["attn_norm"].astype(jnp.float32), cfg.norm_eps).astype(cd)
+        q = h @ lp["wq"].astype(cd)
+        k = h @ lp["wk"].astype(cd)
+        if cfg.qk_norm:
+            q = _rms_norm(q, lp["q_norm"].astype(jnp.float32), cfg.norm_eps).astype(cd)
+            k = _rms_norm(k, lp["k_norm"].astype(jnp.float32), cfg.norm_eps).astype(cd)
+        v = (h @ lp["wv"].astype(cd)).reshape(B, S, KV, D)
+        q = _rope(q.reshape(B, S, H, D), positions, cfg.rope_theta)
+        k = _rope(k.reshape(B, S, KV, D), positions, cfg.rope_theta)
+        return q, k, v
+
+    def _ffn(self, x, lp):
+        """The block's second half, x [B, S, E] -> (x + FFN(norm(x)),
+        chosen): a dense SwiGLU (chosen None) or, with ``n_experts``, the
+        dropless routed experts and each row's chosen experts [B, S, K]."""
+        cfg = self.config
+        cd = cfg.compute_dtype
+        h = _rms_norm(x, lp["ffn_norm"].astype(jnp.float32), cfg.norm_eps).astype(cd)
+        if not cfg.n_experts:
+            gate = jax.nn.silu(h @ lp["w_gate"].astype(cd))
+            up = h @ lp["w_up"].astype(cd)
+            return x + (gate * up) @ lp["w_down"].astype(cd), None
+        from ray_tpu.parallel.moe import dropless_moe_ffn
+
+        B, S, E = x.shape
+        with jax.named_scope("moe_ffn"):
+            y, chosen = dropless_moe_ffn(
+                h.reshape(B * S, E), lp["router"], lp["w_gate"], lp["w_up"], lp["w_down"],
+                top_k=cfg.n_experts_per_tok,
+            )
+        return x + y.reshape(B, S, E), chosen.reshape(B, S, -1)
 
     def _layer(self, x, lp, positions, kv_cache=None, cache_index=None, mesh=None):
         cfg = self.config
@@ -160,12 +246,7 @@ class LlamaModel:
         B, S, E = x.shape
         H, KV, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
 
-        h = _rms_norm(x, lp["attn_norm"].astype(jnp.float32), cfg.norm_eps).astype(cd)
-        q = (h @ lp["wq"].astype(cd)).reshape(B, S, H, D)
-        k = (h @ lp["wk"].astype(cd)).reshape(B, S, KV, D)
-        v = (h @ lp["wv"].astype(cd)).reshape(B, S, KV, D)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        q, k, v = self._qkv(x, lp, positions)
 
         new_cache = None
         if kv_cache is not None:
@@ -210,12 +291,7 @@ class LlamaModel:
             scores = jnp.where(mask[None, None], scores, -1e30)
             probs = jax.nn.softmax(scores, axis=-1).astype(cd)
             attn = jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(B, S, E)
-        x = x + attn @ lp["wo"].astype(cd)
-
-        h = _rms_norm(x, lp["ffn_norm"].astype(jnp.float32), cfg.norm_eps).astype(cd)
-        gate = jax.nn.silu(h @ lp["w_gate"].astype(cd))
-        up = h @ lp["w_up"].astype(cd)
-        x = x + (gate * up) @ lp["w_down"].astype(cd)
+        x, _ = self._ffn(x + attn @ lp["wo"].astype(cd), lp)
         return x, new_cache
 
     def apply(self, params, tokens, mesh=None):
@@ -304,22 +380,20 @@ class LlamaModel:
         probs = jax.nn.softmax(scores, axis=-1).astype(cfg.compute_dtype)
         return jnp.einsum("bhqk,bkhd->bqhd", probs, vals)
 
-    def _paged_layer(self, x, lp, li, positions, pages, wpage, woff, gpage, goff, valid_ctx):
+    def _paged_layer(self, x, lp, li, positions, pages, wpage, woff, gpage, goff, valid_ctx, row_valid):
         """One transformer layer over paged KV: write this step's K/V into
         the pool, gather each slot's logical context, attend.  x [B, S, E]
-        (decode: B=slots,S=1; prefill chunk: B=1,S=chunk)."""
+        (decode: B=slots,S=1; prefill chunk: B=1,S=chunk).  ``pages`` is
+        (k_pages, v_pages) and, for an expert model, the per-expert count
+        of routed assignments [X] int32, to which this layer adds the
+        choices of its ``row_valid`` [B, S] rows.  Returns (x, pages)."""
         cfg = self.config
         cd = cfg.compute_dtype
         B, S, E = x.shape
-        H, KV, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        kp, vp = pages
+        KV, D = cfg.n_kv_heads, cfg.head_dim
+        kp, vp, *load = pages
 
-        h = _rms_norm(x, lp["attn_norm"].astype(jnp.float32), cfg.norm_eps).astype(cd)
-        q = (h @ lp["wq"].astype(cd)).reshape(B, S, H, D)
-        k = (h @ lp["wk"].astype(cd)).reshape(B, S, KV, D)
-        v = (h @ lp["wv"].astype(cd)).reshape(B, S, KV, D)
-        q = _rope(q, positions, cfg.rope_theta)
-        k = _rope(k, positions, cfg.rope_theta)
+        q, k, v = self._qkv(x, lp, positions)
 
         kp = self._paged_write(kp, li, wpage, woff, k.reshape(-1, KV, D))
         vp = self._paged_write(vp, li, wpage, woff, v.reshape(-1, KV, D))
@@ -328,13 +402,11 @@ class LlamaModel:
         if keys.ndim == 3:  # single-slot prefill: add the batch dim
             keys, vals = keys[None], vals[None]
         attn = self._paged_attend(q, keys, vals, valid_ctx).reshape(B, S, E)
-        x = x + attn @ lp["wo"].astype(cd)
-
-        h = _rms_norm(x, lp["ffn_norm"].astype(jnp.float32), cfg.norm_eps).astype(cd)
-        gate = jax.nn.silu(h @ lp["w_gate"].astype(cd))
-        up = h @ lp["w_up"].astype(cd)
-        x = x + (gate * up) @ lp["w_down"].astype(cd)
-        return x, (kp, vp)
+        x, chosen = self._ffn(x + attn @ lp["wo"].astype(cd), lp)
+        if chosen is not None:
+            hits = jax.nn.one_hot(chosen, cfg.n_experts, dtype=jnp.int32) * row_valid[..., None, None]
+            load = [load[0] + hits.sum((0, 1, 2))]
+        return x, (kp, vp, *load)
 
     def _sample_greedy(self, logits):
         """argmax with the vocab padding masked (a padded id must never
@@ -347,20 +419,25 @@ class LlamaModel:
 
     def init_pages(self, num_pages: int, page_size: int) -> Tuple:
         """Physical KV page pool shared by every engine slot:
-        [L, num_pages, page_size, KV, D] pair."""
+        [L, num_pages, page_size, KV, D] pair.  An expert model's pool
+        carries a third member, the routing counter [n_experts] int32
+        (assignments per expert, summed over layers and calls, wrapping):
+        it rides through both programs with the pool, so the engine reads
+        no further array per turn."""
         cfg = self.config
         shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
-        return (
-            jnp.zeros(shape, cfg.compute_dtype),
-            jnp.zeros(shape, cfg.compute_dtype),
-        )
+        pool = (jnp.zeros(shape, cfg.compute_dtype), jnp.zeros(shape, cfg.compute_dtype))
+        if cfg.n_experts:
+            pool += (jnp.zeros((cfg.n_experts,), jnp.int32),)
+        return pool
 
     def decode_step_paged(
         self, params, pages, tables, tokens, positions, active, page_size: int
     ):
         """One engine iteration: decode one token for every active slot.
 
-        pages: (k_pages, v_pages) [L, NP, PS, KV, D]; tables [S, MP] int32
+        pages: (k_pages, v_pages) [L, NP, PS, KV, D] (and an expert
+        model's routing counter, ``init_pages``); tables [S, MP] int32
         (physical page per logical page, -1 unallocated); tokens [S] int32
         (the token each slot feeds); positions [S] int32 (cache index the
         fed token is written at); active [S] bool.  Returns
@@ -391,7 +468,7 @@ class LlamaModel:
         for li in range(cfg.n_layers):
             lp = jax.tree.map(lambda p: p[li], params["layers"])
             x, pages = self._paged_layer(
-                x, lp, li, pos2, pages, wpage, woff, gpage, goff, valid_ctx
+                x, lp, li, pos2, pages, wpage, woff, gpage, goff, valid_ctx, active[:, None]
             )
         x = _rms_norm(x, params["final_norm"].astype(jnp.float32), cfg.norm_eps).astype(cd)
         logits = (x @ params["out_head"].astype(cd))[:, 0, :]
@@ -437,7 +514,7 @@ class LlamaModel:
         for li in range(cfg.n_layers):
             lp = jax.tree.map(lambda p: p[li], params["layers"])
             x, pages = self._paged_layer(
-                x, lp, li, pos[None, :], pages, wpage, woff, gpage, goff, valid_ctx
+                x, lp, li, pos[None, :], pages, wpage, woff, gpage, goff, valid_ctx, valid_q[None, :]
             )
         x = _rms_norm(x, params["final_norm"].astype(jnp.float32), cfg.norm_eps).astype(cd)
         logits = (x[0] @ params["out_head"].astype(cd))  # [C, V]

@@ -1,6 +1,9 @@
-"""Expert parallelism: MoE FFN with all-to-all dispatch over the `ep` axis.
+"""Sparse-expert FFNs.  Two layers live here: the top-1 switch layer with
+all-to-all dispatch over the `ep` axis described next (``moe_ffn``, training,
+``models/gpt2.py``'s ``moe_experts``), and below it an exact dropless top-k
+layer of gated experts (``dropless_moe_ffn``, ``models/llama.py``: OLMoE).
 
-A capability absent from the reference (SURVEY §2.4 "Expert parallel
+Expert parallelism: a capability absent from the reference (SURVEY §2.4 "Expert parallel
 (EP/MoE): absent") — built the TPU way: experts shard over the `ep` mesh
 axis, tokens route to experts via `lax.all_to_all` (one ICI all-to-all
 each way), top-1 switch routing with capacity dropping (Switch
@@ -94,3 +97,62 @@ def make_moe_ffn(mesh, *, axis_name: str = "ep", capacity_factor: float = 1.25):
         ),
         out_specs=P(axis_name, None),
     )
+
+
+# ----------------------------------------------------- dropless top-k (serving)
+
+
+def route(h: jax.Array, router_w: jax.Array, top_k: int):
+    """The router of a dropless top-k layer, in float32 whatever ``h`` is:
+    softmax over ALL experts, then the ``top_k`` largest probabilities.
+    h [T, E]; router_w [E, X].  Returns (weights [T, K] float32, chosen
+    [T, K] int32); the weights are the softmax's own values, NOT divided by
+    their sum (OLMoE publishes ``norm_topk_prob`` false; a model that
+    publishes true brings the option and its reference with it)."""
+    logits = jnp.dot(
+        h.astype(jnp.float32), router_w.astype(jnp.float32), precision=lax.Precision.HIGHEST
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    return lax.top_k(probs, top_k)
+
+
+def dropless_moe_ffn(
+    h: jax.Array,  # [T, E]
+    router_w: jax.Array,  # [E, X]
+    w_gate: jax.Array,  # [X, E, H]
+    w_up: jax.Array,  # [X, E, H]
+    w_down: jax.Array,  # [X, H, E]
+    *,
+    top_k: int,
+) -> Tuple[jax.Array, jax.Array]:
+    """Exact top-k routed SwiGLU experts: every row goes through all
+    ``top_k`` of its experts whatever the other rows chose -- no capacity,
+    nothing dropped -- and a row's result does not depend on its neighbours.
+
+    Computed as a masked contraction over ALL experts: every expert's
+    SwiGLU runs on every row, and the router's weight (zero for an expert
+    the row did not choose) multiplies the activation before ONE
+    down-projection contracts over experts and expert width together.  At
+    the engine's shapes (32 decode rows, 256 chunk rows, 64 experts, top 8)
+    every expert is chosen by some row, so every expert's weights are read
+    from HBM whichever way it is computed; masking costs X/K times the
+    routed FLOPs and needs no sort, no gather and no data-dependent shape.
+    On a v5e at OLMoE's widths (8 layers; PERF.md section 5, PR 28) the 32
+    decode rows' masked FLOPs are 1.0 ms at peak against 7.9 ms of weight
+    bytes, and the contractions read their weights at about 85% of the HBM
+    bandwidth.  A 256-row chunk's are 8.4 ms at peak against the same 7.9
+    ms of bytes: AT the roofline, not hidden under it (the chunk program
+    takes 12.7 ms, 67% of its byte roofline), so from that row count on a
+    grouped matmul over the routed rows alone would be the faster layer.
+    Returns (y [T, E] in h's dtype, chosen [T, K])."""
+    cd = h.dtype
+    n_experts = router_w.shape[-1]
+    with jax.named_scope("router"):
+        weights, chosen = route(h, router_w, top_k)
+        # [T, X]: a row's weight for each expert, zero where not chosen
+        dense_w = (jax.nn.one_hot(chosen, n_experts, dtype=jnp.float32) * weights[..., None]).sum(-2)
+    gate = jnp.einsum("te,xeh->xth", h, w_gate.astype(cd))
+    up = jnp.einsum("te,xeh->xth", h, w_up.astype(cd))
+    act = (jax.nn.silu(gate) * up).astype(jnp.float32) * dense_w.T[:, :, None]
+    y = jnp.einsum("xth,xhe->te", act.astype(cd), w_down.astype(cd), preferred_element_type=jnp.float32)
+    return y.astype(cd), chosen

@@ -146,8 +146,12 @@ def autoscale_tick():
 def delete(name: str):
     import ray_tpu
 
+    from ray_tpu._private import tpu
+
     controller = _get_or_create_controller()
-    ray_tpu.get(controller.delete_deployment.remote(name), timeout=60)
+    # the controller kills each replica and returns once a TPU replica's
+    # process has let go of its chips: as long as the reap wait, at worst
+    ray_tpu.get(controller.delete_deployment.remote(name), timeout=60 + tpu.REAP_WAIT_S)
 
 
 def shutdown():

@@ -171,6 +171,11 @@ class InferenceEngine:
         self._fatal: Optional[str] = None
         self._gauges = None
         self._last_gauges = 0.0
+        # an expert model's routing counter (the pool's third member, a
+        # wrapping int32 per expert on the device): its last reading, and
+        # the replica's running totals.  None for a dense model
+        self._moe_seen = None
+        self._moe_load = None
         self._tokens_reported = 0
         self.iterations = 0
         self._thread = threading.Thread(
@@ -501,10 +506,11 @@ class InferenceEngine:
                 # ranges are safe
                 srcs = np.asarray([m[0] for m in moves], np.int32)
                 dsts = np.asarray([m[1] for m in moves], np.int32)
-                kp, vp = self._pages
+                kp, vp, *rest = self._pages  # rest: an expert model's routing counter
                 self._pages = (
                     kp.at[:, dsts].set(kp[:, srcs]),
                     vp.at[:, dsts].set(vp[:, srcs]),
+                    *rest,
                 )
                 self.cache.apply_compaction(moves)
             frag = self.cache.allocator.fragmentation()
@@ -520,6 +526,10 @@ class InferenceEngine:
             out.update(self.cache.stats())
         out["iterations"] = float(self.iterations)
         out.update({f"compile_{k}": v for k, v in self.compile_stats().items()})
+        load = self._moe_load
+        if load is not None:  # as of the last gauge tick (gauge_period_s)
+            out["moe_assignments"] = float(load.sum())
+            out["moe_expert_load"] = load.tolist()
         return out
 
     def compile_stats(self) -> Dict[str, int]:
@@ -543,7 +553,27 @@ class InferenceEngine:
             return
         self._last_gauges = now
         with span("engine/gauges"):
+            self._read_moe_load()
             self._publish_gauges()
+
+    def _read_moe_load(self) -> None:
+        """Engine thread, gauge tick: fold the device's routing counter into
+        the running totals.  Read here and nowhere else: between turns no
+        call holds the (donated) pool, and twice a second costs nothing a
+        turn.  The device counts in wrapping int32; the difference between
+        two readings is exact as long as fewer than 2**32 assignments go to
+        one expert between ticks."""
+        if len(self._pages) < 3:
+            return
+        try:
+            seen = np.asarray(self._pages[2]).astype(np.uint32)
+        except Exception:  # noqa: BLE001 -- a dead loop's pool may be gone; the totals stay as they were
+            return
+        if self._moe_load is None:  # the pool starts at zero
+            self._moe_seen, self._moe_load = np.zeros_like(seen), np.zeros(seen.shape, np.int64)
+        # a new array each tick: stats() on another thread keeps a whole one
+        self._moe_load = self._moe_load + (seen - self._moe_seen).astype(np.int64)
+        self._moe_seen = seen
 
     def _publish_gauges(self) -> None:
         try:

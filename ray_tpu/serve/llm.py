@@ -16,6 +16,9 @@ Sharding layout (megatron-style, from LlamaModel.param_pspecs):
   wo/w_down            : [L, in, E]   — in split over tp (psum after)
   tok_emb / out_head   : vocab split over tp (psum gather / sharded logits)
   KV cache             : [L, B, S, KV, D] — KV heads split over tp
+  experts (n_experts)  : w_gate/w_up [L, X, E, H], w_down [L, X, H, E] — whole
+                         experts split over tp on X (psum after the
+                         down-projection); router and QK-norm scales replicated
 """
 
 from __future__ import annotations
@@ -77,7 +80,8 @@ def _filter_spec(spec, axis_names):
 
 
 class ShardedLLM:
-    """A llama-family model sharded over a 1-D tp mesh, ready to decode.
+    """A ``LlamaConfig`` model -- dense (Llama, Mistral) or sparse-expert
+    with QK-norm (OLMoE) -- sharded over a 1-D tp mesh, ready to decode.
 
     init:
       "random" — normal(0, 0.02) weights (bench/serving without a ckpt)
@@ -106,7 +110,8 @@ class ShardedLLM:
             raise ValueError(f"tp={tp} but only {len(devices)} devices")
         for dim, name in (
             (cfg.n_kv_heads, "n_kv_heads"),
-            (cfg.hidden_dim, "hidden_dim"),
+            # what tp splits in the FFN: whole experts, or a dense FFN's width
+            (cfg.n_experts, "n_experts") if cfg.n_experts else (cfg.hidden_dim, "hidden_dim"),
             (cfg.padded_vocab, "padded_vocab"),
             (cfg.dim, "dim"),
         ):
@@ -313,7 +318,9 @@ class ShardedLLM:
         # an inferred sharding, which flips the next call's jit cache key
         # — one silent recompile per program, exactly what the engine's
         # no-recompilation contract forbids
-        step_out = (repl, (page_sharding, page_sharding))
+        # (an expert model's pool ends with its small routing counter)
+        pool_sharding = (page_sharding, page_sharding) + ((repl,) if self.cfg.n_experts else ())
+        step_out = (repl, pool_sharding)
 
         def program(fn, *args, **kwargs):
             # a bare partial has no __name__: the compiler would call the
@@ -327,7 +334,7 @@ class ShardedLLM:
         return {
             "init": jax.jit(
                 program(self.model.init_pages, num_pages, page_size),
-                out_shardings=(page_sharding, page_sharding),
+                out_shardings=pool_sharding,
             ),
             "prefill": jax.jit(
                 program(self.model.prefill_chunk_paged, page_size=page_size),
@@ -457,6 +464,7 @@ def llm_deployment(
             return {
                 "platform": self.platform,
                 "params_b": round(self.engine.cfg.num_params() / 1e9, 2),
+                "active_params_b": round(self.engine.cfg.active_params_per_token() / 1e9, 2),
                 "tp": self.engine.tp,
                 "shards": self.engine.shard_stats(),
             }
@@ -709,6 +717,7 @@ def engine_llm_deployment(
                     for d in local
                 ),
                 "params_b": round(self.llm.cfg.num_params() / 1e9, 2),
+                "active_params_b": round(self.llm.cfg.active_params_per_token() / 1e9, 2),
                 "tp": self.llm.tp,
                 "engine": self.engine.stats(),
                 "shards": self.llm.shard_stats(),

@@ -214,3 +214,33 @@ def test_oom_policy_kills_retriable_worker(monkeypatch, shutdown_only):
     # the kill REALLY happened: the task body started twice
     assert _os.path.getsize(marker) == 2, "OOM policy never killed the first attempt"
     _os.unlink(marker)
+
+
+def test_ref_release_takes_no_lock(ray_start_regular):
+    """The collector runs ``ObjectRef.__del__`` on whatever thread it
+    interrupts, also one that is inside ``_add_local_ref`` and holds the
+    ref-count lock: the release must not take that lock (a plain one, so the
+    thread would wait for itself); the flush loop counts it down later."""
+    import threading
+    import time
+
+    from ray_tpu._private.worker import global_worker
+
+    cw = global_worker.core_worker
+    ref = ray_tpu.put(1)
+    oid = ref._id
+    n_before = cw._local_refs.get(oid, 0)
+    cw._add_local_ref(oid)
+    assert cw._refs_lock.acquire(timeout=5)
+    try:
+        release = threading.Thread(target=cw._remove_local_ref, args=(oid,))  # what __del__ calls
+        release.start()
+        release.join(5)
+        assert not release.is_alive(), "a release waited for the ref-count lock"
+        assert cw._local_refs[oid] == n_before + 1
+    finally:
+        cw._refs_lock.release()
+    deadline = time.time() + 5
+    while cw._local_refs.get(oid, 0) != n_before and time.time() < deadline:
+        time.sleep(0.05)
+    assert cw._local_refs.get(oid, 0) == n_before

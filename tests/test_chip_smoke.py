@@ -6,7 +6,9 @@ JAX_PLATFORMS=cpu (conftest.py)."""
 
 import importlib.util
 import json
+import logging
 import os
+import signal
 import subprocess
 import sys
 import time
@@ -83,6 +85,65 @@ def test_reap_waits_for_the_process():
         proc.kill()
         proc.wait()
     assert tpu.wait_pid_exit(proc.pid, 0.0)
+
+
+_IGNORES_SIGTERM = "import signal, time; signal.signal(signal.SIGTERM, signal.SIG_IGN); print('ready', flush=True); time.sleep(60)"
+
+
+def _reap_lines(caplog):
+    return [json.loads(r.getMessage()) for r in caplog.records if "tpu_worker_reaped" in r.getMessage()]
+
+
+def test_reap_says_how_long_the_worker_took(caplog):
+    proc = subprocess.Popen([sys.executable, "-c", "import time; time.sleep(60)"])
+    try:
+        proc.terminate()
+        with caplog.at_level(logging.INFO, logger="ray_tpu._private.tpu"):
+            assert tpu.reap_tpu_worker(proc.pid) is None
+    finally:
+        proc.kill()
+        proc.wait()
+    (line,) = _reap_lines(caplog)
+    assert line["pid"] == proc.pid and line["sigkill"] is False and line["gone"] is True
+    assert 0 <= line["seconds"] < tpu._EXIT_WAIT_S and line["chips"] == tpu.detect_chips()
+
+
+def test_reap_kills_a_worker_that_ignores_sigterm_and_says_so(caplog, monkeypatch):
+    monkeypatch.setattr(tpu, "_EXIT_WAIT_S", 0.3)
+    proc = subprocess.Popen([sys.executable, "-c", _IGNORES_SIGTERM], stdout=subprocess.PIPE)
+    try:
+        assert proc.stdout.readline().strip() == b"ready"  # the handler is in place
+        proc.terminate()
+        with caplog.at_level(logging.INFO, logger="ray_tpu._private.tpu"):
+            assert tpu.reap_tpu_worker(proc.pid) is None
+        assert proc.wait(timeout=5) == -signal.SIGKILL
+    finally:
+        proc.kill()
+        proc.wait()
+    (line,) = _reap_lines(caplog)
+    assert line["sigkill"] is True and line["gone"] is True and 0.3 <= line["seconds"] < 0.3 + tpu._KILL_WAIT_S
+
+
+def test_reap_gives_up_only_after_both_waits_have_run_out(caplog, monkeypatch):
+    """A worker that outlives SIGKILL (in the kernel, releasing its chips'
+    mappings) cannot be made here; a kill that does not arrive stands in."""
+    proc = subprocess.Popen([sys.executable, "-c", "import time; time.sleep(60)"])
+    try:
+        with monkeypatch.context() as m, caplog.at_level(logging.INFO, logger="ray_tpu._private.tpu"):
+            m.setattr(tpu, "_EXIT_WAIT_S", 0.3)
+            m.setattr(tpu, "_KILL_WAIT_S", 0.4)
+            m.setattr(tpu, "REAP_WAIT_S", 0.7)
+            m.setattr(tpu.os, "kill", lambda pid, sig: None)
+            t0 = time.monotonic()
+            err = tpu.reap_tpu_worker(proc.pid)
+            took = time.monotonic() - t0
+    finally:
+        proc.kill()
+        proc.wait()
+    assert err is not None and f"pid {proc.pid}" in err and "still alive 1s" in err
+    assert 0.7 <= took < 1.5
+    (line,) = _reap_lines(caplog)
+    assert line["sigkill"] is True and line["gone"] is False and line["seconds"] >= 0.7
 
 
 @pytest.mark.skipif(tpu.detect_chips() > 0, reason="this host has chips: the smoke would run")
