@@ -76,7 +76,6 @@ PHASES = (
     "serve_engine_admit",  # engine: slot + pages granted, prefill scheduled
     "serve_queue_enter",  # replica: request joined the batch queue
     "serve_queue_exit",  # replica: released into a batch
-    "serve_batch_assembled",  # replica: padded tensor batch built
     "serve_prefill_start",  # replica: prefill program dispatched
     "serve_first_token",  # replica: first token's logits ready (TTFT end)
     "serve_decode_end",  # replica: last token decoded
@@ -151,7 +150,6 @@ DURATIONS = {
     # one chunk an iteration, so under a burst this is where TTFT goes
     "serve_prefill_wait": ("serve_engine_admit", "serve_prefill_start"),
     "serve_queue_wait": ("serve_queue_enter", "serve_queue_exit"),
-    "serve_batch_assemble": ("serve_queue_exit", "serve_batch_assembled"),
     "serve_prefill": ("serve_prefill_start", "serve_first_token"),
     "serve_decode": ("serve_first_token", "serve_decode_end"),
     "serve_handler": ("serve_replica_recv", "serve_handler_end"),

@@ -2,15 +2,14 @@
 dense SwiGLU FFN (Llama, Mistral) or -- ``LlamaConfig.n_experts`` -- a routed
 layer of SwiGLU experts, dropless top-k with a float32 router
 (``parallel/moe.py dropless_moe_ffn``), and optionally RMSNorm on the query
-and key projections (``qk_norm``): together the OLMoE block.  One FFN function
-(``_ffn``) serves the training forward, the contiguous-cache decode and the
-engine's two paged programs.
+and key projections (``qk_norm``): together the OLMoE block.  Two forward
+paths share one ``_qkv`` and one ``_ffn``: ``apply`` (training, and the plain
+reference the engine's tests compare with) and the serving engine's paged
+pair, ``prefill_chunk_paged`` / ``decode_step_paged``.
 
-The serving-side flagship (BASELINE config #5: Serve Llama-2-7B replica).
 Same functional conventions as gpt2.py — pytree params with stacked
 [n_layer, ...] leading dim, lax.scan + remat, bf16 compute, declarative
-PartitionSpecs — plus an autoregressive KV-cache decode path for Serve
-replicas (fixed-shape cache, jit-friendly, batched).
+PartitionSpecs.
 
 The reference ships no LM; its serve replicas wrap user torch modules
 (reference: python/ray/serve/_private/replica.py:58).  Here the model is
@@ -21,7 +20,7 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -70,19 +69,10 @@ class LlamaConfig:
         return _round_up(self.vocab_size, 128)
 
     @classmethod
-    def llama2_7b(cls, **kw) -> "LlamaConfig":
-        return cls(**kw)
-
-    @classmethod
-    def llama2_13b(cls, **kw) -> "LlamaConfig":
-        return cls(dim=5120, n_layers=40, n_heads=40, n_kv_heads=40, hidden_dim=13824, **kw)
-
-    @classmethod
     def llama_3b(cls, **kw) -> "LlamaConfig":
         """~3.3B llama-family config sized for ONE 16G v5e chip in bf16
-        (6.7 GB weights + KV cache headroom; llama2_7b bf16 weights alone
-        are ~13.5 GB — 7B serving is a multi-chip mesh story).  head_dim
-        128 keeps the attention MXU/lane aligned."""
+        (6.7 GB weights + KV cache headroom).  head_dim 128 keeps the
+        attention MXU/lane aligned."""
         return cls(dim=3072, n_layers=26, n_heads=24, n_kv_heads=24, hidden_dim=8192, **kw)
 
     @classmethod
@@ -240,62 +230,29 @@ class LlamaModel:
             )
         return x + y.reshape(B, S, E), chosen.reshape(B, S, -1)
 
-    def _layer(self, x, lp, positions, kv_cache=None, cache_index=None, mesh=None):
+    def _layer(self, x, lp, positions, mesh=None):
+        """The plain causal layer: x [B, S, E] over the whole sequence."""
         cfg = self.config
         cd = cfg.compute_dtype
         B, S, E = x.shape
-        H, KV, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+        H, KV = cfg.n_heads, cfg.n_kv_heads
 
         q, k, v = self._qkv(x, lp, positions)
-
-        new_cache = None
-        if kv_cache is not None:
-            # decode: the FULL [L, B, max_seq, KV, D] cache rides through —
-            # the write is ONE token-sized dynamic_update_slice (25KB), not
-            # a rewrite of this layer's whole slice, so XLA keeps the scan
-            # carry in place and per-step HBM traffic is reads-only
-            # (weights + cache).  Rewriting per-layer slices through a
-            # layer-scan's stacked outputs measured 4-5x slower.
-            ck_all, cv_all, li = kv_cache
-            ck_all = jax.lax.dynamic_update_slice(
-                ck_all, k[None].astype(ck_all.dtype), (li, 0, cache_index, 0, 0)
-            )
-            cv_all = jax.lax.dynamic_update_slice(
-                cv_all, v[None].astype(cv_all.dtype), (li, 0, cache_index, 0, 0)
-            )
-            # li is a static python int (unrolled layer loop)
-            k = ck_all[li]
-            v = cv_all[li]
-            new_cache = (ck_all, cv_all)
-            kv_len = k.shape[1]
-            kv_pos = jnp.arange(kv_len)
-            mask = kv_pos[None, :] <= positions[:, None]  # [S(q), kv_len]
-        else:
-            mask = jnp.tril(jnp.ones((S, S), bool))
-
         # grouped-query: repeat kv heads up to H
         if KV != H:
             rep = H // KV
             k = jnp.repeat(k, rep, axis=2)
             v = jnp.repeat(v, rep, axis=2)
-        if kv_cache is None:
-            # train/prefill: the shared dispatch (splash pallas kernel on
-            # TPU, fused XLA elsewhere — ops/attention.py); decode keeps
-            # the masked einsum below (ragged kv lengths don't fit the
-            # block kernel)
-            from ray_tpu.ops.attention import causal_attention
+        # the shared dispatch (splash pallas kernel on TPU, fused XLA
+        # elsewhere — ops/attention.py)
+        from ray_tpu.ops.attention import causal_attention
 
-            attn = causal_attention(q, k, v, mesh=mesh).reshape(B, S, E)
-        else:
-            scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * (D**-0.5)
-            scores = jnp.where(mask[None, None], scores, -1e30)
-            probs = jax.nn.softmax(scores, axis=-1).astype(cd)
-            attn = jnp.einsum("bhqk,bkhd->bqhd", probs, v).reshape(B, S, E)
+        attn = causal_attention(q, k, v, mesh=mesh).reshape(B, S, E)
         x, _ = self._ffn(x + attn @ lp["wo"].astype(cd), lp)
-        return x, new_cache
+        return x
 
     def apply(self, params, tokens, mesh=None):
-        """Train/prefill forward: tokens [B, S] → logits [B, S, V] (bf16)."""
+        """The whole-sequence forward: tokens [B, S] → logits [B, S, V] (bf16)."""
         cfg = self.config
         cd = cfg.compute_dtype
         B, S = tokens.shape
@@ -303,13 +260,10 @@ class LlamaModel:
         positions = jnp.arange(S)
 
         def body(x, lp):
+            layer = lambda x_, lp_: self._layer(x_, lp_, positions, mesh=mesh)
             if cfg.remat:
-                y, _ = jax.checkpoint(
-                    lambda x_, lp_: self._layer(x_, lp_, positions, mesh=mesh)
-                )(x, lp)
-            else:
-                y, _ = self._layer(x, lp, positions, mesh=mesh)
-            return y, None
+                layer = jax.checkpoint(layer)
+            return layer(x, lp), None
 
         x, _ = jax.lax.scan(body, x, params["layers"])
         x = _rms_norm(x, params["final_norm"].astype(jnp.float32), cfg.norm_eps).astype(cd)
@@ -324,17 +278,6 @@ class LlamaModel:
         label_logit = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
         lse = jax.nn.logsumexp(logits, axis=-1)
         return (lse - label_logit).mean()
-
-    # -------------------------------------------------------------- decode
-
-    def init_cache(self, batch: int) -> Tuple:
-        """Per-layer fixed-shape KV cache: [L, B, max_seq, KV, D] pair."""
-        cfg = self.config
-        shape = (cfg.n_layers, batch, cfg.max_seq_len, cfg.n_kv_heads, cfg.head_dim)
-        return (
-            jnp.zeros(shape, cfg.compute_dtype),
-            jnp.zeros(shape, cfg.compute_dtype),
-        )
 
     # ------------------------------------------------- paged decode (engine)
     #
@@ -354,8 +297,8 @@ class LlamaModel:
     def _paged_write(self, buf, li: int, wpage, woff, vals):
         """Scatter one token per slot into layer ``li`` of a page pool.
         ``wpage`` rows for inactive/unallocated slots are out of range and
-        dropped — token-sized update on the full buffer, same in-place
-        contract as decode_step's dynamic_update_slice."""
+        dropped — a token-sized update on the full buffer, which is donated,
+        so XLA writes it in place."""
         return buf.at[li, wpage, woff].set(vals.astype(buf.dtype), mode="drop")
 
     def _paged_context(self, buf, li: int, gpage, goff):
@@ -520,29 +463,3 @@ class LlamaModel:
         logits = (x[0] @ params["out_head"].astype(cd))  # [C, V]
         last = jnp.clip(n_valid - 1, 0, C - 1)
         return self._sample_greedy(logits[last]), pages
-
-    def decode_step(self, params, cache, tokens, position: jax.Array):
-        """One token per sequence: tokens [B, 1], position scalar index.
-        Returns (logits [B, V], new_cache).  jit once, call per token —
-        the Serve replica's hot loop."""
-        cfg = self.config
-        cd = cfg.compute_dtype
-        B = tokens.shape[0]
-        x = params["tok_emb"].astype(cd)[tokens]  # [B, 1, E]
-        positions = jnp.array([position]) if jnp.ndim(position) == 0 else position[None]
-        positions = jnp.reshape(positions, (1,))
-
-        ck_all, cv_all = cache
-        # python loop over layers (unrolled, static layer index): each
-        # layer's cache update is a single token-sized in-place write into
-        # the full 5-D cache.  A lax.scan over layers would route the cache
-        # through stacked scan OUTPUTS, rewriting all L x [B,S,KV,D] slices
-        # every step — measured 63.8ms/step at B=16 vs ~15ms unrolled
-        for li in range(cfg.n_layers):
-            lp = jax.tree.map(lambda p: p[li], params["layers"])
-            x, (ck_all, cv_all) = self._layer(
-                x, lp, positions, kv_cache=(ck_all, cv_all, li), cache_index=position
-            )
-        x = _rms_norm(x, params["final_norm"].astype(jnp.float32), cfg.norm_eps).astype(cd)
-        logits = (x @ params["out_head"].astype(cd))[:, 0, :]
-        return logits, (ck_all, cv_all)

@@ -253,9 +253,8 @@ def make_lm_step_spec(
     """A GPT-2 training run as a ``TrainStepSpec`` (train/jax/step_dag.py):
     the SAME stage functions drive both the eager per-step path and the
     gang-scheduled resident DAG, so eager-vs-dag weight equality is a
-    property of the system, not the workload.  Used by the bench.py
-    dispatch-overhead pair, the multichip dryrun's gang phase, and
-    tests/test_train_dag.py."""
+    property of the system, not the workload.  Used by the multichip
+    dryrun's gang phase and tests/test_train_dag.py."""
     from ray_tpu.train.jax.step_dag import TrainStepSpec
 
     cfg = getattr(GPT2Config, model)()

@@ -35,8 +35,8 @@ class _BatchQueue:
         from ray_tpu.serve import tracing as serve_tracing
 
         if self.max_pending is not None and len(self.queue) >= self.max_pending:
-            # bounded failure mode for the static path too: reject at
-            # submit (the proxy's 503) instead of queueing unboundedly
+            # bounded failure mode: reject at submit (the proxy's 503)
+            # instead of queueing unboundedly
             from ray_tpu.exceptions import EngineOverloadedError
 
             raise EngineOverloadedError(
@@ -72,15 +72,12 @@ class _BatchQueue:
         for tr in traces:
             serve_tracing.stamp(tr, "serve_queue_exit")
         try:
-            # batch_scope: the model invocation below stamps assembly /
-            # prefill / decode onto every coalesced request via stamp_batch
-            with serve_tracing.batch_scope(traces):
-                if instance is not None:
-                    results = self.fn(instance, items)
-                else:
-                    results = self.fn(items)
-                if asyncio.iscoroutine(results):
-                    results = await results
+            if instance is not None:
+                results = self.fn(instance, items)
+            else:
+                results = self.fn(items)
+            if asyncio.iscoroutine(results):
+                results = await results
             if len(results) != len(items):
                 raise ValueError(
                     f"batched fn returned {len(results)} results for {len(items)} inputs"

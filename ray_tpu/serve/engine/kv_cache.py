@@ -2,7 +2,7 @@
 
 The device side is ONE fixed page pool per replica —
 ``[L, num_pages, page_size, KV, D]`` K/V buffers (KV heads sharded over
-the tp mesh axis, same layout the contiguous serving cache uses) — and
+the tp mesh axis) — and
 the host side is this module: a page allocator plus per-slot page tables
 mapping each sequence's logical pages onto physical pool pages.  Because
 every jitted engine program is shaped by (num_slots, pages_per_slot,

@@ -1,8 +1,8 @@
 """Iteration-level admission/retirement for the continuous-batching engine.
 
 The unit of scheduling is one TOKEN STEP, not one request (the
-iteration-level batching of Orca/vLLM, vs. the whole-request
-``@serve.batch`` path this engine replaces): every engine iteration the
+iteration-level batching of Orca/vLLM, vs. whole-request batching as
+``@serve.batch`` does it): every engine iteration the
 scheduler admits queued requests into free slots (page reservation
 gating), feeds at most one chunk of one prompt through prefill, decodes
 every slot already streaming, and retires sequences that hit EOS or

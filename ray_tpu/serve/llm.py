@@ -1,21 +1,20 @@
-"""Tensor-parallel sharded LLM serving engine.
+"""Tensor-parallel sharded LLM behind the continuous-batching engine.
 
-BASELINE config #5 serves Llama-2-7B, and one 16G v5e cannot hold 7B in
-bf16 (~13.5 GB weights before the KV cache) — so 7B serving is a MESH
-story: weights AND the KV cache are sharded over a ``tp`` axis, the
-per-token decode step is jitted once over the mesh with the cache buffers
-donated (no double-buffered carry), and XLA inserts the attention/MLP
-output-projection psums that ride ICI.  The reference never solves this
-inside Serve — its replicas wrap user torch modules and model sharding
-happens outside (reference: python/ray/serve/_private/replica.py:58);
-here the sharded engine IS the replica's model, so a deployment scales
-from one chip (tp=1) to a pod slice by changing one argument.
+A model that one chip cannot hold is served over a MESH: weights AND the
+paged KV pool are sharded over a ``tp`` axis, the engine's prefill-chunk
+and decode-step programs are jitted once over the mesh with the pool
+donated, and XLA inserts the attention/MLP output-projection psums that
+ride ICI.  The reference never solves this inside Serve — its replicas
+wrap user torch modules and model sharding happens outside (reference:
+python/ray/serve/_private/replica.py:58); here the sharded model IS the
+replica's model, so a deployment scales from one chip (tp=1) to a pod
+slice by changing one argument.
 
 Sharding layout (megatron-style, from LlamaModel.param_pspecs):
   wq/wk/wv/w_gate/w_up : [L, E, out]  — out (heads / ffn) split over tp
   wo/w_down            : [L, in, E]   — in split over tp (psum after)
   tok_emb / out_head   : vocab split over tp (psum gather / sharded logits)
-  KV cache             : [L, B, S, KV, D] — KV heads split over tp
+  KV page pool         : [L, pages, page, KV, D] — KV heads split over tp
   experts (n_experts)  : w_gate/w_up [L, X, E, H], w_down [L, X, H, E] — whole
                          experts split over tp on X (psum after the
                          down-projection); router and QK-norm scales replicated
@@ -29,12 +28,11 @@ import numpy as np
 
 from ray_tpu.models.llama import LlamaConfig, LlamaModel
 
-__all__ = ["ShardedLLM", "llm_deployment", "engine_llm_deployment"]
+__all__ = ["ShardedLLM", "engine_llm_deployment"]
 
 
 def _resolve_cfg(model, max_seq_len):
-    """LlamaConfig from a constructor name or an instance (worker-side —
-    shared by the static and engine deployment factories)."""
+    """LlamaConfig from a constructor name or an instance (worker-side)."""
     import dataclasses
 
     import jax.numpy as jnp
@@ -54,7 +52,7 @@ def _parse_prompt_spec(spec, vocab_size: int, default_new: int):
     """Normalize the three accepted request shapes into
     (prompt_ids, max_new_tokens, eos_token):
 
-    - int seed       -> one-token prompt (the static path's wire shape)
+    - int seed       -> one-token prompt
     - [ids...]       -> explicit prompt
     - {"prompt": int|[ids...], "max_new_tokens": n, "eos_token": t}
     """
@@ -81,10 +79,11 @@ def _filter_spec(spec, axis_names):
 
 class ShardedLLM:
     """A ``LlamaConfig`` model -- dense (Llama, Mistral) or sparse-expert
-    with QK-norm (OLMoE) -- sharded over a 1-D tp mesh, ready to decode.
+    with QK-norm (OLMoE) -- sharded over a 1-D tp mesh; ``engine_programs``
+    gives the serving engine its jitted programs over that mesh.
 
     init:
-      "random" — normal(0, 0.02) weights (bench/serving without a ckpt)
+      "random" — normal(0, 0.02) weights (serving without a ckpt)
       "cheap"  — deterministic iota-pattern fill (dryrun at 7B shape: no
                  7-billion-sample RNG on a 1-core host; still exercises
                  every collective with non-trivial values)
@@ -100,7 +99,6 @@ class ShardedLLM:
         seed: int = 0,
     ):
         import jax
-        import jax.numpy as jnp
         from jax.sharding import Mesh, NamedSharding
         from jax.sharding import PartitionSpec as P
 
@@ -128,8 +126,6 @@ class ShardedLLM:
             pspecs,
             is_leaf=lambda x: isinstance(x, P),
         )
-        self.cache_sharding = NamedSharding(self.mesh, P(None, None, None, "tp", None))
-        self._repl = NamedSharding(self.mesh, P())
 
         shapes = jax.eval_shape(self.model.init, jax.random.PRNGKey(seed))
         if isinstance(init, dict):
@@ -193,117 +189,14 @@ class ShardedLLM:
         else:
             raise ValueError(f"unknown init {init!r}")
 
-        model = self.model
-
-        def prefill(params, cache, prompt_t):
-            """Teacher-forced scan over prompt positions; returns the cache
-            and the last position's logits.  prompt_t: [P, B, 1]."""
-
-            def body(carry, xt):
-                cache, _ = carry
-                t, tok = xt
-                logits, cache = model.decode_step(params, cache, tok, t)
-                return (cache, logits), None
-
-            P_len = prompt_t.shape[0]
-            ts = jnp.arange(P_len)
-            init_logits = jnp.zeros(
-                (prompt_t.shape[1], cfg.padded_vocab), cfg.compute_dtype
-            )
-            (cache, logits), _ = jax.lax.scan(
-                body, (cache, init_logits), (ts, prompt_t)
-            )
-            return cache, logits
-
-        def generate_from(params, cache, logits, start_pos, n_new):
-            """Greedy decode n_new tokens starting from prefill logits
-            (n_new is static: the scan length is baked into the program)."""
-
-            def body(carry, t):
-                tok, cache = carry
-                logits, cache = model.decode_step(params, cache, tok, t)
-                nxt = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-                return (nxt, cache), nxt[:, 0]
-
-            first = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-            if n_new == 1:
-                return first, cache
-            (_, cache), toks = jax.lax.scan(
-                body, (first, cache), start_pos + jnp.arange(n_new - 1)
-            )
-            return jnp.concatenate([first.T, toks], axis=0).T, cache
-
-        def full_generate(params, cache, prompt_t, n_new):
-            cache, logits = prefill(params, cache, prompt_t)
-            toks, cache = generate_from(
-                params, cache, logits, prompt_t.shape[0], n_new
-            )
-            return toks
-
-        # ONE compiled program per (B, P, n_new): prompt scan + decode scan
-        # stay on-chip (no host turn per token); the cache is created
-        # outside and DONATED so XLA
-        # updates it in place instead of double-buffering the scan carry
-        # (the r4 B=16 HBM cliff).
-        self._generate = jax.jit(full_generate, static_argnums=(3,), donate_argnums=(1,))
-        # split pair for the traced serving path: prefill and decode as
-        # separate programs so the first token's logits are a HOST-VISIBLE
-        # boundary — what TTFT/TPOT measure (and the baseline the
-        # continuous-batching engine has to beat).  jit objects are lazy:
-        # untraced callers (dryrun, bench fused path) never compile these.
-        self._prefill = jax.jit(prefill, donate_argnums=(1,))
-        self._decode = jax.jit(
-            generate_from, static_argnums=(4,), donate_argnums=(1,)
-        )
-        self._init_cache = jax.jit(
-            self.model.init_cache, static_argnums=(0,), out_shardings=self.cache_sharding
-        )
-        self._jnp = jnp
-
-    # ------------------------------------------------------------------ api
-
-    def generate(self, prompts: np.ndarray, n_new: int, stage_cb=None) -> np.ndarray:
-        """prompts [B, P] int32 → generated tokens [B, n_new] (greedy).
-
-        ``stage_cb(phase)`` opts into the SPLIT prefill/decode pair so the
-        first token is a host-visible boundary: the callback fires with
-        ``serve_prefill_start`` / ``serve_first_token`` /
-        ``serve_decode_end`` (canonical task_events names — the serve
-        tracer's stamp_batch slots straight in).  Without it the fused
-        one-program path runs unchanged."""
-        import jax
-
-        jnp = self._jnp
-        prompts = np.asarray(prompts, np.int32)
-        B, P_len = prompts.shape
-        if P_len + n_new > self.cfg.max_seq_len:
-            raise ValueError(
-                f"prompt {P_len} + new {n_new} exceeds max_seq_len {self.cfg.max_seq_len}"
-            )
-        cache = self._init_cache(B)
-        prompt_t = jnp.asarray(prompts.T[:, :, None])  # [P, B, 1]
-        if stage_cb is None:
-            toks = self._generate(self.params, cache, prompt_t, int(n_new))
-            return np.asarray(toks)
-        stage_cb("serve_prefill_start")
-        cache, logits = self._prefill(self.params, cache, prompt_t)
-        # first token's logits resident on host clock = TTFT endpoint
-        jax.block_until_ready(logits)
-        stage_cb("serve_first_token")
-        toks, cache = self._decode(self.params, cache, logits, P_len, int(n_new))
-        toks = np.asarray(toks)  # device→host sync: decode truly done
-        stage_cb("serve_decode_end")
-        return toks
-
     def engine_programs(self, *, num_pages: int, page_size: int) -> Dict[str, Any]:
         """The continuous-batching engine's three jitted programs over
         THIS mesh: page-pool init, prefill chunk, decode step
-        (models/llama.py paged variants).  The pool is sharded like the
-        contiguous cache (KV heads over tp) and DONATED into every call,
-        so the engine's resident loop re-uses one in-place buffer per
-        program — and because the paged programs are shaped by pool
-        geometry only, the whole mixed-length fleet shares exactly one
-        compiled decode shape (the engine asserts this via
+        (models/llama.py).  The pool is sharded over its KV heads (tp) and
+        DONATED into every call, so the engine's resident loop re-uses one
+        in-place buffer per program — and because the paged programs are
+        shaped by pool geometry only, the whole mixed-length fleet shares
+        exactly one compiled decode shape (the engine asserts this via
         ``compile_stats``)."""
         import functools
 
@@ -368,110 +261,6 @@ class ShardedLLM:
         return {"total_bytes": total, "per_device_bytes": per_device}
 
 
-def llm_deployment(
-    model="llama_3b",
-    *,
-    max_seq_len: Optional[int] = None,
-    new_tokens: int = 32,
-    max_batch_size: int = 8,
-    batch_wait_timeout_s: float = 0.02,
-    num_tpus: int = 1,
-    tp: Optional[int] = None,
-    autoscaling_config: Optional[dict] = None,
-    prompt_pad: Optional[int] = None,
-):
-    """Build a Serve deployment wrapping a ShardedLLM replica.
-
-    ``model`` is a LlamaConfig constructor name ("llama_3b", "llama2_7b",
-    ...) or a LlamaConfig INSTANCE (resolved worker-side either way —
-    pass an instance for configs the name registry doesn't have).  The
-    replica claims ``num_tpus`` chips and shards over every device jax
-    exposes inside the actor (tp defaults to all of them) — the same code
-    path serves llama_3b on one chip and llama2_7b on a mesh."""
-    from ray_tpu import serve
-
-    @serve.deployment(
-        name="llm",
-        ray_actor_options={"num_tpus": num_tpus},
-        max_concurrent_queries=64,
-        autoscaling_config=autoscaling_config
-        or {
-            "min_replicas": 1,
-            "max_replicas": 1,
-            "target_num_ongoing_requests_per_replica": 32,
-        },
-    )
-    class LLMDeployment:
-        def __init__(self):
-            import jax
-
-            # an explicit max_seq_len overrides; otherwise the instance's
-            # own value stands
-            cfg = _resolve_cfg(model, max_seq_len)
-            self.engine = ShardedLLM(cfg, tp=tp)
-            self.platform = jax.devices()[0].platform
-
-        @serve.batch(
-            max_batch_size=max_batch_size, batch_wait_timeout_s=batch_wait_timeout_s
-        )
-        async def generate(self, prompts):
-            from ray_tpu.serve import tracing as serve_tracing
-
-            # run the EXACT batch — padding partial batches to
-            # max_batch_size with [[0]] rows decoded the padding at full
-            # cost (in a fixed-shape XLA program a "masked" row still buys
-            # every FLOP, so honesty means a smaller program, not a mask).
-            # The compile cache grows one program per distinct partial
-            # size, bounded by max_batch_size; steady-state traffic rides
-            # the full-batch program it always compiled anyway.
-            #
-            # Multi-token prompts (the mixed-length bench's wire shape)
-            # pad to the LONGEST prompt in the coalesced batch (or the
-            # fixed ``prompt_pad``, which also pins the compile shape) —
-            # whole-request batching's intrinsic cost: every short row
-            # pays the longest row's prefill AND waits out its decode.
-            # The continuous-batching engine exists to remove exactly
-            # this.
-            vocab = self.engine.cfg.vocab_size
-            rows = []
-            for p in prompts:
-                if isinstance(p, dict):
-                    p = p.get("prompt", 0)
-                if isinstance(p, (list, tuple)):
-                    rows.append([int(t) % vocab for t in p])
-                else:
-                    rows.append([int(p) % vocab])
-            P = prompt_pad or max(len(r) for r in rows)
-            ids = np.zeros((len(rows), P), np.int32)
-            for b, r in enumerate(rows):
-                ids[b, : min(len(r), P)] = r[:P]
-            if serve_tracing.batch_active():
-                # traced batch: stamp assembly + run the split
-                # prefill/decode pair so TTFT/TPOT are real measurements
-                serve_tracing.stamp_batch("serve_batch_assembled")
-                serve_tracing.set_batch_tokens(new_tokens)
-                out = self.engine.generate(
-                    ids, new_tokens, stage_cb=serve_tracing.stamp_batch
-                )
-            else:
-                out = self.engine.generate(ids, new_tokens)
-            return [out[b].tolist() for b in range(len(prompts))]
-
-        async def __call__(self, prompt):
-            return await self.generate(prompt)
-
-        def info(self):
-            return {
-                "platform": self.platform,
-                "params_b": round(self.engine.cfg.num_params() / 1e9, 2),
-                "active_params_b": round(self.engine.cfg.active_params_per_token() / 1e9, 2),
-                "tp": self.engine.tp,
-                "shards": self.engine.shard_stats(),
-            }
-
-    return LLMDeployment
-
-
 def engine_llm_deployment(
     model="llama_3b",
     *,
@@ -487,16 +276,20 @@ def engine_llm_deployment(
     name: str = "llm",
     autoscaling_config: Optional[dict] = None,
 ):
-    """Continuous-batching counterpart of :func:`llm_deployment`: the
-    replica hosts a resident :class:`~ray_tpu.serve.engine.InferenceEngine`
-    (iteration-level scheduling over a paged KV cache) instead of the
-    whole-request ``@serve.batch`` path.  Requests of any prompt length
+    """Build the Serve deployment of an LLM: the replica shards the model
+    over its chips (:class:`ShardedLLM`) and hosts a resident
+    :class:`~ray_tpu.serve.engine.InferenceEngine` (iteration-level
+    scheduling over a paged KV cache).  Requests of any prompt length
     admit/retire per token step, tokens stream incrementally over
     dag-channel token streams (``handle.stream_tokens`` / SSE at the
     proxy), and a full admission queue rejects FAST with
-    ``EngineOverloadedError`` (the proxy's 503).  Accepts the same
-    prompt wire shapes as the static path plus
-    ``{"prompt": [...], "max_new_tokens": n, "eos_token": t}`` dicts."""
+    ``EngineOverloadedError`` (the proxy's 503).
+
+    ``model`` is a LlamaConfig constructor name or a LlamaConfig INSTANCE
+    (resolved worker-side either way).  The replica claims ``num_tpus``
+    chips and shards over every device jax exposes inside the actor (tp
+    defaults to all of them).  A request is one of
+    ``_parse_prompt_spec``'s three shapes."""
     from ray_tpu import serve
 
     @serve.deployment(
@@ -618,35 +411,6 @@ def engine_llm_deployment(
             if done:
                 transport.hub().remove(int(sid))
             return frames, done
-
-        def engine_stream_state(self, sid):
-            """Stream delivery introspection (ops/debug surface): outbox
-            depth, writer/ring state, wire cursor."""
-            from ray_tpu.serve.engine import transport
-
-            st = transport.hub().get(int(sid))
-            if st is None:
-                return {"gone": True}
-            out = {
-                "frames_queued": len(st._frames),
-                "attached": st._writer is not None,
-                "seq": st._seq,
-                "closed": st.closed,
-                "finished": st.finished,
-            }
-            w = st._writer
-            if w is not None:
-                out.update(
-                    {
-                        "ring": w._ring is not None,
-                        "ring_unusable": w._ring_unusable,
-                        "broken": w.broken,
-                        "co_located": w._co_located,
-                    }
-                )
-                if w._ring is not None:
-                    out["ring_seqs"] = w._ring._seqs()
-            return out
 
         def engine_stream_cancel(self, sid):
             from ray_tpu.serve.engine import transport

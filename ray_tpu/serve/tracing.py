@@ -7,7 +7,7 @@ handle_request_streaming latency metrics; and vLLM-style TTFT/TPOT
 accounting for LLM serving).  A request record is born at the ingress
 (HTTP proxy or a bare DeploymentHandle), rides the call as a reserved
 kwarg (``_serve_trace``) into the replica, picks up replica-side stamps
-(queue wait, batch assembly, prefill, decode), and ships to the head on
+(queue wait, prefill, decode), and ships to the head on
 a fire-and-forget ``SERVE_TRACE`` frame — batched like DAG_STEP, never a
 per-request head round trip.  The head joins records next to the task
 flight records: same ring, same timeline, per-stage
@@ -33,11 +33,9 @@ Overhead contract: when recording is off (``RAY_TPU_TASK_EVENTS=0``)
 downstream site gates on that None — no dict, no clock read, no extra
 wire bytes (the reserved kwarg is only attached when a record exists).
 
-Propagation inside the replica uses contextvars, so the batch queue and
-the model engine stamp the right request(s) without threading a handle
-through every call: ``request_scope`` installs the in-flight record,
-``batch_scope`` installs the list of records coalesced into one model
-invocation (``stamp_batch`` fans a stamp out to all of them).
+Propagation inside the replica uses a contextvar, so the batch queue and
+the model engine find the right request without threading a handle
+through every call: ``request_scope`` installs the in-flight record.
 """
 
 from __future__ import annotations
@@ -55,10 +53,6 @@ from ray_tpu._private import task_events
 # the request currently being handled on this (asyncio) context
 _current_request: contextvars.ContextVar[Optional[dict]] = contextvars.ContextVar(
     "serve_request_trace", default=None
-)
-# the requests coalesced into the model batch currently executing
-_current_batch: contextvars.ContextVar[Optional[List[dict]]] = contextvars.ContextVar(
-    "serve_batch_traces", default=None
 )
 
 
@@ -117,42 +111,6 @@ def request_scope(trace: Optional[dict]):
         yield trace
     finally:
         _current_request.reset(token)
-
-
-@contextlib.contextmanager
-def batch_scope(traces: List[dict]):
-    """Around one coalesced model invocation: ``stamp_batch`` inside the
-    scope stamps every request in the batch."""
-    token = _current_batch.set(traces)
-    try:
-        yield traces
-    finally:
-        _current_batch.reset(token)
-
-
-def batch_active() -> bool:
-    return bool(_current_batch.get())
-
-
-def stamp_batch(phase: str) -> None:
-    """Stamp `phase` on every request record in the executing batch (a
-    no-op outside a batch_scope / with recording off)."""
-    traces = _current_batch.get()
-    if not traces:
-        return
-    now = time.time()
-    for tr in traces:
-        tr["phases"][phase] = now
-
-
-def set_batch_tokens(n: int) -> None:
-    """Record how many tokens each request in the batch received (the
-    TPOT denominator)."""
-    traces = _current_batch.get()
-    if not traces:
-        return
-    for tr in traces:
-        tr["tokens"] = int(n)
 
 
 def derive(trace: dict) -> dict:

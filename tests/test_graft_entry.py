@@ -1,6 +1,5 @@
-"""The driver runs bench.py at round end and the judge reads the bench
-artifacts — an import-time regression in any bench script must surface
-in CI, not at round end."""
+"""``__graft_entry__.py``'s helpers that decide, without touching JAX,
+whether a process may run the multichip dry run in place."""
 
 import importlib.util
 import os
@@ -15,12 +14,6 @@ def _import(name):
     return mod
 
 
-def test_bench_scripts_import():
-    for name in ("bench", "bench_rllib", "bench_serve"):
-        mod = _import(name)
-        assert hasattr(mod, "main")
-
-
 def test_graft_entry_helpers():
     mod = _import("__graft_entry__")
     # the static env probe must not touch jax
@@ -30,11 +23,3 @@ def test_graft_entry_helpers():
     assert not mod._cpu_mesh_ready(flags, 8)  # unset: jax would pick the chip
     dp, fsdp, tp, sp = mod._axes_for(8)
     assert dp * fsdp * tp * sp == 8
-
-
-def test_bench_config_env_knobs(monkeypatch):
-    monkeypatch.setenv("BENCH_MODEL", "gpt2_350m")
-    monkeypatch.setenv("BENCH_BATCH", "4")
-    mod = _import("bench")
-    cfg = mod._bench_config()
-    assert cfg["model"] == "gpt2_350m" and cfg["batch"] == 4

@@ -1,5 +1,5 @@
-"""Llama model tests: forward/loss, sharded train step, KV-cache decode
-parity with prefill."""
+"""Llama model tests: forward/loss, sharded train step, and the engine's
+paged programs against the plain forward."""
 
 import numpy as np
 import pytest
@@ -41,50 +41,35 @@ def test_llama_sharded_train_step():
     assert float(m["loss"]) < first - 1.0
 
 
-def test_decode_matches_prefill():
-    """Autoregressive KV-cache decode must produce the same logits as the
-    full-sequence forward at each position."""
-    import jax
+# The block's three kinds at a tiny size: grouped-query dense (Mistral's
+# shape), one KV head a query head, and routed experts with QK-norm (OLMoE's).
+# vocab_size 250 leaves six padded ids that must never be sampled.
+BLOCKS = {
+    "grouped-query": dict(n_kv_heads=2, hidden_dim=128),
+    "kv-equals-h": dict(n_kv_heads=4, hidden_dim=128),
+    "experts-qk-norm": dict(n_kv_heads=4, hidden_dim=32, n_experts=8, n_experts_per_tok=2, qk_norm=True),
+}
+
+
+@pytest.mark.parametrize("page_size", [4, 16])
+@pytest.mark.parametrize("block", list(BLOCKS))
+def test_paged_programs_match_the_plain_forward(block, page_size):
+    """The engine's two programs (chunked prefill into a paged pool, then
+    whole-fleet decode steps) give, token for token in float32, what the
+    plain causal forward gives for each prompt alone."""
     import jax.numpy as jnp
 
-    from ray_tpu.models.llama import LlamaConfig, LlamaModel
+    from _greedy import greedy_reference, paged_greedy
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.serve.llm import ShardedLLM
 
-    cfg = LlamaConfig.tiny(compute_dtype=jnp.float32)
-    model = LlamaModel(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    B, S = 2, 10
-    tokens = jax.random.randint(jax.random.PRNGKey(2), (B, S), 0, cfg.vocab_size)
-
-    prefill_logits = model.apply(params, tokens)  # [B, S, V]
-
-    cache = model.init_cache(B)
-    decode = jax.jit(model.decode_step)
-    for t in range(S):
-        step_logits, cache = decode(params, cache, tokens[:, t : t + 1], jnp.asarray(t))
-        np.testing.assert_allclose(
-            np.asarray(step_logits),
-            np.asarray(prefill_logits[:, t, :]),
-            rtol=2e-3,
-            atol=2e-3,
-        )
-
-
-def test_generation_greedy():
-    import jax
-    import jax.numpy as jnp
-
-    from ray_tpu.models.llama import LlamaConfig, LlamaModel
-
-    cfg = LlamaConfig.tiny(compute_dtype=jnp.float32)
-    model = LlamaModel(cfg)
-    params = model.init(jax.random.PRNGKey(0))
-    cache = model.init_cache(1)
-    decode = jax.jit(model.decode_step)
-    token = jnp.zeros((1, 1), jnp.int32)
-    out = []
-    for t in range(8):
-        logits, cache = decode(params, cache, token, jnp.asarray(t))
-        token = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-        out.append(int(token[0, 0]))
-    assert len(out) == 8
-    assert all(0 <= t < cfg.padded_vocab for t in out)
+    cfg = LlamaConfig(
+        vocab_size=250, dim=64, n_layers=2, n_heads=4, max_seq_len=64,
+        compute_dtype=jnp.float32, **BLOCKS[block],
+    )
+    llm = ShardedLLM(cfg, tp=1, init="random")
+    prompts = [[5, 7, 9], [3], list(range(1, 12))]  # under, at and over a chunk; over a page
+    outs = paged_greedy(llm, prompts, 8, page_size=page_size, chunk=4)
+    for prompt, out in zip(prompts, outs):
+        assert out == greedy_reference(llm.model, llm.params, prompt, 8)
+        assert all(0 <= t < cfg.vocab_size for t in out)

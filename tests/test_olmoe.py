@@ -17,6 +17,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
+from _greedy import greedy_reference  # noqa: E402
 from benchmarks.drivers import serve_moe  # noqa: E402
 from benchmarks.reference import olmoe_ref  # noqa: E402
 from ray_tpu.models.llama import LlamaConfig, LlamaModel  # noqa: E402
@@ -237,7 +238,7 @@ def test_the_engine_serves_an_expert_model_and_counts_its_routing():
         reqs.append(eng.submit(prompts[2], 8))
         outs = [r.sink.result(timeout=180) for r in reqs]
         for p, o in zip(prompts, outs):
-            assert o == llm.generate(np.asarray([p], np.int32), 8)[0].tolist()  # the contiguous-cache path, same FFN
+            assert o == greedy_reference(llm.model, llm.params, p, 8)  # the plain forward: no cache, no paging
         assert eng.compile_stats() == {"prefill": 1, "decode": 1}
         eng._wake.set()
         import time

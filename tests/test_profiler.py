@@ -1,12 +1,9 @@
 """Cluster-wide sampling profiler (ray_tpu/_private/profiler.py +
 util/profile_api.py): off-path contract, hot-function dominance,
 cluster-wide arm/disarm + collection across roles, timeline merge, the
-≤5% overhead bound on a tracked ray_perf pair, stack dumps, the
-deprecated RAY_TPU_HEAD_PROFILE alias, and the perf-trend gate
-(scripts/perf_trends.py)."""
+≤5% overhead bound on a tracked ray_perf pair, stack dumps, and the
+deprecated RAY_TPU_HEAD_PROFILE alias."""
 
-import importlib.util
-import json
 import os
 import threading
 import time
@@ -397,85 +394,3 @@ def test_head_profile_env_alias(shutdown_only, tmp_path):
         assert first.startswith("head;") and first.rsplit(" ", 1)[1].isdigit()
     finally:
         os.environ.pop("RAY_TPU_HEAD_PROFILE", None)
-
-
-# ----------------------------------------------------------- perf trends
-
-
-def _load_perf_trends():
-    path = os.path.join(
-        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-        "scripts",
-        "perf_trends.py",
-    )
-    spec = importlib.util.spec_from_file_location("perf_trends", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
-
-
-def test_perf_trends_real_trajectory_passes(capsys):
-    """The gate must pass on a trajectory in the artifacts' real shapes
-    (tests/fixtures/perf_trends: the driver's BENCH capture, both PERF
-    layouts, a SERVE_BENCH row) — including a BENCH run that died before
-    printing a metric, which the comparability guard excludes instead of
-    scoring as a regression."""
-    pt = _load_perf_trends()
-    fixtures = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "fixtures", "perf_trends"
-    )
-    rc = pt.main(["--dir", fixtures])
-    out = capsys.readouterr().out
-    assert rc == 0
-    assert "bench.gpt2_tok_per_s_per_chip" in out
-    assert "perf.queued_drain_per_sec" in out
-    assert "serve.decode_tok_per_s_per_chip" in out
-    assert "not comparable" in out  # the dead run's note surfaced
-
-
-def test_perf_trends_synthetic_regression_fails(tmp_path, capsys):
-    """An injected >15% drop in a tracked metric exits nonzero and names
-    the series; untracked (noisy microbench) swings never gate."""
-    pt = _load_perf_trends()
-
-    def write(run, drain, micro):
-        (tmp_path / f"PERF_r{run:02d}.json").write_text(
-            json.dumps(
-                {
-                    "microbench": {"single client tasks sync": micro},
-                    "scale_envelope": {
-                        "queued_tasks_10k": {"throughput_per_sec": drain}
-                    },
-                }
-            )
-        )
-
-    write(1, 600.0, 700.0)
-    write(2, 640.0, 200.0)  # microbench crater: info-only, must not gate
-    assert pt.main(["--dir", str(tmp_path)]) == 0
-    # a crashed (rc!=0) serve artifact must not enter the gated series
-    (tmp_path / "SERVE_BENCH_r01.json").write_text(
-        json.dumps(
-            {
-                "rc": 1,
-                "platform": "tpu",
-                "value": 1.0,
-                "loads": [{"offered_concurrency": 4, "p99_ms": 1.0}],
-            }
-        )
-    )
-    rc = pt.main(["--dir", str(tmp_path)])
-    assert rc == 0
-    out = capsys.readouterr()
-    assert "serve.p99_ms_at_peak_load" not in out.out
-    assert "SERVE_BENCH run not comparable" in out.out
-    write(3, 300.0, 900.0)  # tracked drain −53% vs best prior 640
-    rc = pt.main(["--dir", str(tmp_path)])
-    err = capsys.readouterr().err
-    assert rc == 1
-    assert "perf.queued_drain_per_sec" in err
-    # --no-gate renders the table without failing
-    assert pt.main(["--dir", str(tmp_path), "--no-gate"]) == 0
-    # corrupt artifacts are skipped, not fatal
-    (tmp_path / "PERF_r04.json").write_text("{not json")
-    assert pt.main(["--dir", str(tmp_path)]) == 1

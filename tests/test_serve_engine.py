@@ -5,10 +5,10 @@ tiny model on CPU throughout."""
 
 import time
 
-import numpy as np
 import pytest
 
 import ray_tpu
+from _greedy import greedy_reference
 from ray_tpu import serve
 from ray_tpu.exceptions import EngineOverloadedError, EngineStreamError
 
@@ -194,11 +194,11 @@ def tiny_llm():
     return _tiny_llm()
 
 
-def test_engine_mixed_lengths_match_static_path_one_shape(tiny_llm):
+def test_engine_mixed_lengths_match_the_plain_forward_one_shape(tiny_llm):
     """The tentpole invariant: concurrent sequences of different lengths
-    produce exactly the tokens the whole-request path produces, AND the
-    whole run uses ONE compiled prefill shape + ONE compiled decode shape
-    (no recompilation across the mix)."""
+    produce exactly the tokens the plain whole-sequence forward produces
+    for each alone, AND the whole run uses ONE compiled prefill shape +
+    ONE compiled decode shape (no recompilation across the mix)."""
     from ray_tpu.serve.engine import EngineConfig, InferenceEngine
 
     eng = InferenceEngine(
@@ -214,8 +214,7 @@ def test_engine_mixed_lengths_match_static_path_one_shape(tiny_llm):
         reqs = [eng.submit(p, 6) for p in prompts]
         outs = [r.sink.result(timeout=180) for r in reqs]
         for p, o in zip(prompts, outs):
-            ref = tiny_llm.generate(np.asarray([p], np.int32), 6)[0].tolist()
-            assert o == ref
+            assert o == greedy_reference(tiny_llm.model, tiny_llm.params, p, 6)
         assert eng.compile_stats() == {"prefill": 1, "decode": 1}
         # second wave re-uses recycled slots on the same programs
         r = eng.submit([9, 8, 7], 4)
@@ -299,7 +298,7 @@ def test_engine_defrag_mid_flight_preserves_decode(tiny_llm):
         deployment="t",
     )
     try:
-        ref = tiny_llm.generate(np.asarray([[5, 7, 9]], np.int32), 16)[0].tolist()
+        ref = greedy_reference(tiny_llm.model, tiny_llm.params, [5, 7, 9], 16)
         long_req = eng.submit([5, 7, 9], 16)
         short = [eng.submit([i + 1], 2) for i in range(2)]
         for r in short:
@@ -427,6 +426,35 @@ def test_engine_deployment_buffered_and_mixed(engine_cluster):
         timeout=60,
     )
     assert stats["compile_prefill"] == 1.0 and stats["compile_decode"] == 1.0
+
+
+def test_engine_deployment_three_prompt_forms(engine_cluster):
+    """A seed, a list of ids and a dict are one request in three shapes
+    (``_parse_prompt_spec``): through ``serve.run`` and the handle they
+    answer the same tokens, the dict's own budget and ids beyond the
+    vocabulary included, and ``info()`` reports the sharded model."""
+    from ray_tpu.serve.llm import _parse_prompt_spec
+
+    cfg, handle = engine_cluster
+    assert _parse_prompt_spec(5, 256, 6) == ([5], 6, None)
+    assert _parse_prompt_spec([5, 261], 256, 6) == ([5, 5], 6, None)
+    assert _parse_prompt_spec({"prompt": 5, "max_new_tokens": 3, "eos_token": 9}, 256, 6) == ([5], 3, 9)
+    seed, ids, spec, wrapped, short = ray_tpu.get(
+        [
+            handle.remote(5),
+            handle.remote([5]),
+            handle.remote({"prompt": [5]}),
+            handle.remote(5 + cfg.vocab_size),
+            handle.remote({"prompt": 5, "max_new_tokens": 3}),
+        ],
+        timeout=300,
+    )
+    assert len(seed) == 6 and seed == ids == spec == wrapped
+    assert short == seed[:3]
+    info = ray_tpu.get(
+        serve.get_deployment_handle("llm").method("info").remote(), timeout=60
+    )
+    assert info["tp"] == 1 and info["shards"]["total_bytes"] > 0
 
 
 def test_engine_deployment_info_and_fetched_request_records(engine_cluster):

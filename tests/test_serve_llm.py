@@ -1,66 +1,8 @@
-"""Serve + LLM: batched jitted Llama generation behind a deployment —
-BASELINE config #5 shape (Llama serving replica with batching) at toy
-scale on CPU."""
+"""ShardedLLM: a LlamaConfig model sharded over a tp mesh, decoding through
+the engine's paged programs — toy scale on the CPU's virtual devices."""
 
-import numpy as np
 import pytest
-
-import ray_tpu
-from ray_tpu import serve
-
-
-@pytest.fixture
-def ray_cluster():
-    info = ray_tpu.init(num_cpus=4)
-    yield info
-    ray_tpu.shutdown()
-
-
-def test_llama_generation_deployment(ray_cluster):
-    @serve.deployment(name="llm")
-    class LlamaService:
-        def __init__(self):
-            import jax
-            import jax.numpy as jnp
-
-            from ray_tpu.models.llama import LlamaConfig, LlamaModel
-
-            self.cfg = LlamaConfig.tiny(compute_dtype=jnp.float32)
-            self.model = LlamaModel(self.cfg)
-            self.params = self.model.init(jax.random.PRNGKey(0))
-            self._decode = jax.jit(self.model.decode_step)
-
-        @serve.batch(max_batch_size=4, batch_wait_timeout_s=0.1)
-        async def generate(self, prompts):
-            """Batched greedy generation: one jitted decode loop serves the
-            whole coalesced batch."""
-            import jax.numpy as jnp
-
-            B = len(prompts)
-            max_new = 6
-            cache = self.model.init_cache(B)
-            token = jnp.asarray([[p % self.cfg.vocab_size] for p in prompts], jnp.int32)
-            outs = [[] for _ in range(B)]
-            for t in range(max_new):
-                logits, cache = self._decode(self.params, cache, token, jnp.asarray(t))
-                token = jnp.argmax(logits, axis=-1)[:, None].astype(jnp.int32)
-                for b in range(B):
-                    outs[b].append(int(token[b, 0]))
-            return outs
-
-        async def __call__(self, prompt_token):
-            return await self.generate(prompt_token)
-
-    handle = serve.run(LlamaService.bind())
-    refs = [handle.remote(i) for i in range(4)]
-    results = ray_tpu.get(refs, timeout=300)
-    assert len(results) == 4
-    for seq in results:
-        assert len(seq) == 6
-        assert all(isinstance(t, int) for t in seq)
-
-
-# ---------------------------------------------------------------- ShardedLLM
+from _greedy import paged_greedy
 
 
 def _tiny_cfg():
@@ -72,16 +14,16 @@ def _tiny_cfg():
 
 
 def test_sharded_llm_tp_equals_single_device():
-    """tp-sharded decode must be bit-identical to the unsharded engine —
-    the psums XLA inserts for the sharded projections are exact."""
+    """The tp-sharded paged programs give the unsharded programs' tokens:
+    one prefill chunk a prompt, then five decode steps over both slots."""
     from ray_tpu.serve.llm import ShardedLLM
 
     cfg = _tiny_cfg()
-    prompts = np.array([[5, 7, 9], [3, 2, 1]], np.int32)
-    t1 = ShardedLLM(cfg, tp=1, init="random").generate(prompts, 6)
-    t2 = ShardedLLM(cfg, tp=2, init="random").generate(prompts, 6)
-    assert t1.shape == (2, 6)
-    assert (t1 == t2).all()
+    prompts = [[5, 7, 9], [3, 2, 1]]
+    t1 = paged_greedy(ShardedLLM(cfg, tp=1, init="random"), prompts, 6, page_size=4, chunk=4)
+    t2 = paged_greedy(ShardedLLM(cfg, tp=2, init="random"), prompts, 6, page_size=4, chunk=4)
+    assert [len(t) for t in t1] == [6, 6]
+    assert t1 == t2
 
 
 def test_sharded_llm_shard_stats_split_params():
@@ -96,12 +38,13 @@ def test_sharded_llm_shard_stats_split_params():
 
 
 def test_sharded_llm_cheap_init_decodes():
+    """The "cheap" per-shard fill decodes through the paged programs."""
     from ray_tpu.serve.llm import ShardedLLM
 
-    eng = ShardedLLM(_tiny_cfg(), tp=2, init="cheap")
-    toks = eng.generate(np.array([[1, 2, 3]], np.int32), 4)
-    assert toks.shape == (1, 4)
-    assert (toks >= 0).all()
+    cfg = _tiny_cfg()
+    (toks,) = paged_greedy(ShardedLLM(cfg, tp=2, init="cheap"), [[1, 2, 3]], 4, page_size=4, chunk=4)
+    assert len(toks) == 4
+    assert all(0 <= t < cfg.vocab_size for t in toks)
 
 
 def test_sharded_llm_rejects_bad_tp():
@@ -109,33 +52,3 @@ def test_sharded_llm_rejects_bad_tp():
 
     with pytest.raises(ValueError):
         ShardedLLM(_tiny_cfg(), tp=3, init="random")  # kv_heads=2 % 3
-
-
-def test_llm_deployment_through_serve(ray_cluster):
-    """The llm_deployment factory serves generation through the real
-    Serve path (handle → replica → ShardedLLM engine).  A config
-    INSTANCE is passed (it must resolve worker-side — a driver-side
-    monkeypatched constructor name would not exist in the replica's
-    process)."""
-    import jax.numpy as jnp
-
-    from ray_tpu.models.llama import LlamaConfig
-    from ray_tpu.serve import llm as llm_mod
-
-    cfg = LlamaConfig(
-        dim=64, n_layers=2, n_heads=4, n_kv_heads=2, hidden_dim=128,
-        vocab_size=256, compute_dtype=jnp.float32,
-    )
-    dep = llm_mod.llm_deployment(
-        cfg, max_seq_len=32, new_tokens=4, max_batch_size=4,
-        num_tpus=0, tp=1,
-    )
-    handle = serve.run(dep.bind())
-    refs = [handle.remote(i) for i in range(3)]
-    results = ray_tpu.get(refs, timeout=300)
-    assert all(len(seq) == 4 for seq in results)
-    info = ray_tpu.get(
-        serve.get_deployment_handle("llm").method("info").remote(), timeout=60
-    )
-    assert info["tp"] == 1
-    assert info["shards"]["total_bytes"] > 0

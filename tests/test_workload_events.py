@@ -2,7 +2,7 @@
 memory accounting, and the SLO watchdog.
 
 Covers the serve request-trace join (stage stamps propagate ingress →
-replica → batch queue → engine and sum to ≈ e2e, TTFT < total), the
+replica → engine and sum to ≈ e2e, TTFT < total), the
 StepProbe breakdown + jitter/MFU stats, memory-gauge aggregation
 (`ray-tpu summary memory` + /metrics scrape), SLO window math
 (pure-function unit tests) and the watchdog end-to-end (a deliberately
@@ -34,7 +34,7 @@ def _serve_summary(limit=0):
     return summarize_workloads("serve", limit=limit)
 
 
-def _llm_handle(new_tokens=4, max_batch=4):
+def _llm_handle(new_tokens=4):
     import jax.numpy as jnp
 
     from ray_tpu.models.llama import LlamaConfig
@@ -44,16 +44,16 @@ def _llm_handle(new_tokens=4, max_batch=4):
         dim=64, n_layers=2, n_heads=4, n_kv_heads=2, hidden_dim=128,
         vocab_size=256, compute_dtype=jnp.float32,
     )
-    dep = llm_mod.llm_deployment(
-        cfg, max_seq_len=32, new_tokens=new_tokens, max_batch_size=max_batch,
-        num_tpus=0, tp=1,
+    dep = llm_mod.engine_llm_deployment(
+        cfg, max_seq_len=32, new_tokens=new_tokens, num_slots=4, page_size=4,
+        prefill_chunk=4, num_tpus=0, tp=1,
     )
     return serve.run(dep.bind())
 
 
 def test_serve_request_trace_join(ray_cluster):
-    """End-to-end through the real serve path (handle → replica → batch
-    queue → ShardedLLM split prefill/decode): the head joins per-stage
+    """End-to-end through the real serve path (handle → replica → the
+    engine's admission, prefill and decode): the head joins per-stage
     spans whose sum ≈ e2e, TTFT is populated and strictly under the
     total, and TPOT is per-token."""
     new_tokens = 4
@@ -81,9 +81,8 @@ def test_serve_request_trace_join(ray_cluster):
             "serve_proxy_recv",
             "serve_route",
             "serve_replica_recv",
-            "serve_queue_enter",
-            "serve_queue_exit",
-            "serve_batch_assembled",
+            "serve_engine_submit",
+            "serve_engine_admit",
             "serve_prefill_start",
             "serve_first_token",
             "serve_decode_end",
@@ -103,8 +102,8 @@ def test_serve_request_trace_join(ray_cluster):
         assert stage_sum <= e2e + 0.005
         assert stage_sum >= 0.5 * e2e, (stage_sum, e2e, durs)
         inner = (
-            durs["serve_queue_wait"]
-            + durs["serve_batch_assemble"]
+            durs["serve_engine_queue"]
+            + durs["serve_prefill_wait"]
             + durs["serve_prefill"]
             + durs["serve_decode"]
         )
@@ -115,7 +114,7 @@ def test_serve_request_trace_join(ray_cluster):
         assert rec["tokens"] == new_tokens
     # aggregated surfaces: per-stage table + TTFT/TPOT percentiles
     stages = {(r["deployment"], r["stage"]) for r in reply["summary"]}
-    for stage in ("serve_queue_wait", "serve_prefill", "serve_decode", "serve_e2e"):
+    for stage in ("serve_engine_queue", "serve_prefill", "serve_decode", "serve_e2e"):
         assert ("llm", stage) in stages, stages
     assert reply["ttft"]["llm"]["count"] >= 3
     assert reply["tpot"]["llm"]["count"] >= 3
@@ -134,7 +133,7 @@ def test_serve_request_trace_join(ray_cluster):
         for e in events
         if e.get("cat") == "task_phase" and e["name"].startswith("serve:llm:")
     }
-    assert {"serve_queue_wait", "serve_prefill", "serve_decode"} <= sub, sub
+    assert {"serve_engine_queue", "serve_prefill", "serve_decode"} <= sub, sub
     serve.shutdown()
 
 
@@ -398,7 +397,7 @@ def test_workload_recording_disabled_no_stamps(monkeypatch, shutdown_only):
         assert probe.stats()["steps"] == 0
 
         ray_tpu.init(num_cpus=4)
-        handle = _llm_handle(new_tokens=2, max_batch=2)
+        handle = _llm_handle(new_tokens=2)
         out = ray_tpu.get(handle.remote(1), timeout=300)
         assert len(out) == 2
         from ray_tpu.experimental.state import summarize_workloads
