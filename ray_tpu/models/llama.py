@@ -102,6 +102,14 @@ def _rms_norm(x, scale, eps):
     return norm * scale
 
 
+def ctx_block_pages(pages_per_slot: int, page_size: int) -> int:
+    """Pages in one context block of the paged programs' walk: 256
+    positions (the chunk a prompt is prefilled in; coarser blocks walk past
+    more dead context, finer ones pay more loop trips), or the whole table
+    where that is shorter, in whole pages and never less than one."""
+    return max(1, min(256, pages_per_slot * page_size) // page_size)
+
+
 def _rope(x, positions, theta):
     # x: [..., seq, heads, head_dim]
     d = x.shape[-1]
@@ -287,12 +295,21 @@ class LlamaModel:
     # mapping logical page -> physical page (-1 = unallocated).  Shapes
     # depend only on (num_slots, pages_per_slot, page_size), never on any
     # sequence's length — the jit-shape invariant that keeps a mixed-length
-    # fleet on one compiled program (engine/DESIGN.md).  This is the
-    # gather-based reference formulation of paged attention (layout follows
-    # the TPU paged-attention kernel: k_pages/v_pages pools + page_indices +
-    # lengths); a production TPU build swaps the gather for the pallas
-    # paged-attention kernel with per-page async DMA — the pool layout and
-    # page tables are already kernel-shaped.
+    # fleet on one compiled program (engine/DESIGN.md).
+    #
+    # Attention reads the pool a context BLOCK at a time
+    # (``ctx_block_pages`` pages): a ``fori_loop`` whose trip count the
+    # program computes from the positions it is given walks the page table
+    # only as far as the longest live context of the call, gathers that
+    # block's pages [B, block, KV, D] and folds them into a running
+    # (online) softmax -- float32 maximum, denominator and accumulator.
+    # Grouped query heads are contracted against their KV head in place
+    # (q as [B, Q, KV, G, D]); K and V are never repeated to H heads.  A
+    # block that is wholly masked for a row leaves that row's three
+    # statistics bit for bit as they were, so a row's result does not depend
+    # on how far the others made the walk go.  Plain XLA: the layout (page
+    # pools + page indices + lengths) is the TPU paged-attention kernel's,
+    # which can replace the gather without touching the bookkeeping.
 
     def _paged_write(self, buf, li: int, wpage, woff, vals):
         """Scatter one token per slot into layer ``li`` of a page pool.
@@ -301,55 +318,99 @@ class LlamaModel:
         so XLA writes it in place."""
         return buf.at[li, wpage, woff].set(vals.astype(buf.dtype), mode="drop")
 
-    def _paged_context(self, buf, li: int, gpage, goff):
-        """Gather a slot's logical context [*, T, KV, D] from layer ``li``
-        of the pool (clipped indices; invalid rows are masked by the
-        caller's valid_ctx, never read as attention inputs)."""
-        return buf[li, gpage, goff]
-
-    def _paged_attend(self, q, keys, vals, valid_ctx):
-        """Masked single-direction attention over gathered paged context.
-        q [B, S, H, D]; keys/vals [B, T, KV, D]; valid_ctx [B, S, T]."""
-        cfg = self.config
-        H, KV, D = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-        if KV != H:
-            rep = H // KV
-            keys = jnp.repeat(keys, rep, axis=2)
-            vals = jnp.repeat(vals, rep, axis=2)
-        scores = jnp.einsum("bqhd,bkhd->bhqk", q, keys).astype(jnp.float32) * (
-            D**-0.5
-        )
-        scores = jnp.where(valid_ctx[:, None], scores, -1e30)
-        probs = jax.nn.softmax(scores, axis=-1).astype(cfg.compute_dtype)
-        return jnp.einsum("bhqk,bkhd->bqhd", probs, vals)
-
-    def _paged_layer(self, x, lp, li, positions, pages, wpage, woff, gpage, goff, valid_ctx, row_valid):
-        """One transformer layer over paged KV: write this step's K/V into
-        the pool, gather each slot's logical context, attend.  x [B, S, E]
-        (decode: B=slots,S=1; prefill chunk: B=1,S=chunk).  ``pages`` is
-        (k_pages, v_pages) and, for an expert model, the per-expert count
-        of routed assignments [X] int32, to which this layer adds the
-        choices of its ``row_valid`` [B, S] rows.  Returns (x, pages)."""
+    def _paged_attend(self, q, kp, vp, li: int, tables, q_pos, q_valid, n_blocks):
+        """Causal attention of q [B, Q, H, D] over layer ``li`` of the pool,
+        each row b through its page table cut into blocks, ``tables[b]``
+        [n, pages a block] (-1 = no page); a query at logical position
+        ``q_pos[b, q]`` sees positions 0..q_pos, ``q_valid`` [B, Q] masks
+        idle rows (their result is finite and unused).  Walks blocks
+        0..n_blocks-1 (a traced scalar, at least 1).  Returns [B, Q, H*D]."""
         cfg = self.config
         cd = cfg.compute_dtype
-        B, S, E = x.shape
+        B, Q, H, D = q.shape
+        KV = cfg.n_kv_heads
+        G = H // KV
+        NP, PS = kp.shape[1], kp.shape[2]
+        blk = tables.shape[2] * PS
+        q = q.reshape(B, Q, KV, G, D)
+        offs = jnp.arange(blk)
+
+        def block(i, carry):
+            m, l, acc = carry
+            tab = jax.lax.dynamic_index_in_dim(tables, i, axis=1, keepdims=False)  # [B, pages]
+            phys = jnp.clip(tab, 0, NP - 1)
+            keys = kp[li, phys].reshape(B, blk, KV, D)
+            vals = vp[li, phys].reshape(B, blk, KV, D)
+            seen = (i * blk + offs)[None, None, :] <= q_pos[:, :, None]  # [B, Q, blk]
+            valid = seen & q_valid[:, :, None] & jnp.repeat(tab >= 0, PS, axis=1)[:, None, :]
+            s = jnp.einsum("bqkgd,btkd->bkgqt", q, keys, preferred_element_type=jnp.float32)
+            s = jnp.where(valid[:, None, None], s * (D**-0.5), -1e30)
+            m_new = jnp.maximum(m, s.max(-1))
+            # a block masked for the whole row: m_new == m, so scale is
+            # exp(0) = 1 and every p is exp(-1e30 - m) = 0 -- nothing moves
+            scale = jnp.exp(m - m_new)
+            p = jnp.exp(s - m_new[..., None])
+            l = l * scale + p.sum(-1)
+            pv = jnp.einsum(
+                "bkgqt,btkd->bkgqd", p.astype(cd), vals, preferred_element_type=jnp.float32
+            )
+            return m_new, l, acc * scale[..., None] + pv
+
+        stat = (B, KV, G, Q)
+        init = (
+            jnp.full(stat, -1e30, jnp.float32),
+            jnp.zeros(stat, jnp.float32),
+            jnp.zeros(stat + (D,), jnp.float32),
+        )
+        _, l, acc = jax.lax.fori_loop(0, n_blocks, block, init)
+        # an idle row kept m = -1e30, so its p were exp(0) = 1: l > 0, finite
+        out = (acc / l[..., None]).astype(cd)  # [B, KV, G, Q, D]
+        return out.transpose(0, 3, 1, 2, 4).reshape(B, Q, H * D)
+
+    def _paged_layer(self, x, lp, li, pages, wpage, woff, tables, q_pos, q_valid, n_blocks):
+        """One transformer layer over paged KV: write this step's K/V into
+        the pool, then attend over the pool through the page tables.
+        x [B, S, E] (decode: B=slots,S=1; prefill chunk: B=1,S=chunk).
+        ``pages`` is (k_pages, v_pages) and, for an expert model, the
+        per-expert count of routed assignments [X] int32, to which this
+        layer adds the choices of its ``q_valid`` [B, S] rows.  Returns
+        (x, pages)."""
+        cfg = self.config
+        cd = cfg.compute_dtype
         KV, D = cfg.n_kv_heads, cfg.head_dim
         kp, vp, *load = pages
 
-        q, k, v = self._qkv(x, lp, positions)
+        q, k, v = self._qkv(x, lp, q_pos)
 
         kp = self._paged_write(kp, li, wpage, woff, k.reshape(-1, KV, D))
         vp = self._paged_write(vp, li, wpage, woff, v.reshape(-1, KV, D))
-        keys = self._paged_context(kp, li, gpage, goff)
-        vals = self._paged_context(vp, li, gpage, goff)
-        if keys.ndim == 3:  # single-slot prefill: add the batch dim
-            keys, vals = keys[None], vals[None]
-        attn = self._paged_attend(q, keys, vals, valid_ctx).reshape(B, S, E)
+        attn = self._paged_attend(q, kp, vp, li, tables, q_pos, q_valid, n_blocks)
         x, chosen = self._ffn(x + attn @ lp["wo"].astype(cd), lp)
         if chosen is not None:
-            hits = jax.nn.one_hot(chosen, cfg.n_experts, dtype=jnp.int32) * row_valid[..., None, None]
+            hits = jax.nn.one_hot(chosen, cfg.n_experts, dtype=jnp.int32) * q_valid[..., None, None]
             load = [load[0] + hits.sum((0, 1, 2))]
         return x, (kp, vp, *load)
+
+    def _paged_forward(self, params, x, pages, wpage, woff, tables, q_pos, q_valid):
+        """The layers of both paged programs: x [B, S, E] at positions
+        ``q_pos`` [B, S] through tables [B, MP] -> (normed x, pages).  The
+        walk ends at the block of the call's longest live position."""
+        cfg = self.config
+        PS = pages[0].shape[2]
+        B, MP = tables.shape
+        bp = ctx_block_pages(MP, PS)
+        # the table in blocks [B, n, bp]; a last block it does not fill is
+        # padded with pages that do not exist
+        tables = jnp.pad(tables, ((0, 0), (0, -MP % bp)), constant_values=-1).reshape(B, -1, bp)
+        longest = jnp.max(jnp.where(q_valid, q_pos, 0))
+        n_blocks = jnp.minimum(longest // (bp * PS) + 1, tables.shape[1])
+        for li in range(cfg.n_layers):
+            lp = jax.tree.map(lambda p: p[li], params["layers"])
+            x, pages = self._paged_layer(
+                x, lp, li, pages, wpage, woff, tables, q_pos, q_valid, n_blocks
+            )
+        x = _rms_norm(x, params["final_norm"].astype(jnp.float32), cfg.norm_eps)
+        return x.astype(cfg.compute_dtype), pages
 
     def _sample_greedy(self, logits):
         """argmax with the vocab padding masked (a padded id must never
@@ -386,34 +447,18 @@ class LlamaModel:
         fed token is written at); active [S] bool.  Returns
         (next_tokens [S] int32 — greedy, device-argmaxed so only S ints
         cross to the host per step — and the updated pool)."""
-        cfg = self.config
-        cd = cfg.compute_dtype
-        S, MP = tables.shape
+        cd = self.config.compute_dtype
         NP = pages[0].shape[1]
-        T = MP * page_size
 
         x = params["tok_emb"].astype(cd)[tokens][:, None, :]  # [S, 1, E]
-        pos2 = positions[:, None]  # [S, 1]: per-slot rope positions
         # write target: one pool row per slot; inactive or table-miss rows
         # go out of range and are dropped by the scatter
         wpage = jnp.take_along_axis(tables, (positions // page_size)[:, None], axis=1)[:, 0]
         wpage = jnp.where(active & (wpage >= 0), wpage, NP)
         woff = positions % page_size
-        # gather map: logical context index j -> (physical page, offset)
-        j = jnp.arange(T)
-        gpage = tables[:, j // page_size]  # [S, T]
-        goff = jnp.broadcast_to(j % page_size, (S, T))
-        valid_ctx = (gpage >= 0) & (j[None, :] <= positions[:, None])
-        valid_ctx = valid_ctx & active[:, None]
-        gpage = jnp.clip(gpage, 0, NP - 1)
-        valid_ctx = valid_ctx[:, None, :]  # [S, 1(q), T]
-
-        for li in range(cfg.n_layers):
-            lp = jax.tree.map(lambda p: p[li], params["layers"])
-            x, pages = self._paged_layer(
-                x, lp, li, pos2, pages, wpage, woff, gpage, goff, valid_ctx, active[:, None]
-            )
-        x = _rms_norm(x, params["final_norm"].astype(jnp.float32), cfg.norm_eps).astype(cd)
+        x, pages = self._paged_forward(
+            params, x, pages, wpage, woff, tables, positions[:, None], active[:, None]
+        )
         logits = (x @ params["out_head"].astype(cd))[:, 0, :]
         return self._sample_greedy(logits), pages
 
@@ -431,12 +476,9 @@ class LlamaModel:
         ceil(P/C) calls of ONE compiled program — chunked prefill never
         adds a shape, and in-flight decode streams wait at most one chunk
         (engine/DESIGN.md)."""
-        cfg = self.config
-        cd = cfg.compute_dtype
+        cd = self.config.compute_dtype
         C = tokens.shape[0]
-        (MP,) = table_row.shape
         NP = pages[0].shape[1]
-        T = MP * page_size
 
         pos = start_pos + jnp.arange(C)  # [C]
         valid_q = jnp.arange(C) < n_valid
@@ -444,22 +486,11 @@ class LlamaModel:
         wpage = table_row[pos // page_size]
         wpage = jnp.where(valid_q & (wpage >= 0), wpage, NP)
         woff = pos % page_size
-        j = jnp.arange(T)
-        gpage = table_row[j // page_size]  # [T]
-        goff = j % page_size
         # causal over the slot's logical context, chunk included (K/V land
-        # in the pool before the gather)
-        valid_ctx = (gpage[None, :] >= 0) & (j[None, :] <= pos[:, None])
-        valid_ctx = valid_ctx & valid_q[:, None]
-        gpage = jnp.clip(gpage, 0, NP - 1)
-        valid_ctx = valid_ctx[None]  # [1, C, T]
-
-        for li in range(cfg.n_layers):
-            lp = jax.tree.map(lambda p: p[li], params["layers"])
-            x, pages = self._paged_layer(
-                x, lp, li, pos[None, :], pages, wpage, woff, gpage, goff, valid_ctx, valid_q[None, :]
-            )
-        x = _rms_norm(x, params["final_norm"].astype(jnp.float32), cfg.norm_eps).astype(cd)
-        logits = (x[0] @ params["out_head"].astype(cd))  # [C, V]
+        # in the pool before a layer attends)
+        x, pages = self._paged_forward(
+            params, x, pages, wpage, woff, table_row[None], pos[None], valid_q[None]
+        )
+        logits = x[0] @ params["out_head"].astype(cd)  # [C, V]
         last = jnp.clip(n_valid - 1, 0, C - 1)
         return self._sample_greedy(logits[last]), pages

@@ -11,7 +11,7 @@ compiled decode step and the pool stays donated/in-place (the jit-shape
 invariant; engine/DESIGN.md).
 
 Layout follows the TPU paged-attention kernel convention (page pools +
-``page_indices`` + lengths) so the gather-based reference attention in
+``page_indices`` + lengths) so the plain-XLA block walk in
 models/llama.py can later be swapped for the pallas kernel without
 touching this bookkeeping.
 

@@ -178,6 +178,16 @@ class InferenceEngine:
         self._moe_load = None
         self._tokens_reported = 0
         self.iterations = 0
+        # how far the paged programs' walk over context blocks engages
+        # (models/llama.py): blocks walked, and blocks of whole tables, summed
+        # over prefill chunks and decode steps from the positions of each call
+        from ray_tpu.models.llama import ctx_block_pages
+
+        block_pages = ctx_block_pages(cfg.pages_per_slot, cfg.page_size)
+        self._ctx_block = block_pages * cfg.page_size
+        self._ctx_blocks_per_call = -(-cfg.pages_per_slot // block_pages)
+        self.ctx_blocks_walked = 0
+        self.ctx_blocks_full = 0
         self._thread = threading.Thread(
             target=self._run, name=f"engine-{deployment}", daemon=True
         )
@@ -358,6 +368,15 @@ class InferenceEngine:
     def running_snapshot(self) -> List[EngineRequest]:
         return list(self.sched.running.values())
 
+    def _note_walk(self, longest_pos: int) -> None:
+        """Count one program call whose longest live position is
+        ``longest_pos``: the blocks its attention walks, as the program
+        itself bounds them, and the blocks of a whole table."""
+        self.ctx_blocks_walked += min(
+            longest_pos // self._ctx_block + 1, self._ctx_blocks_per_call
+        )
+        self.ctx_blocks_full += self._ctx_blocks_per_call
+
     def _prefill_chunk(self, req: EngineRequest, start: int, toks: List[int]) -> None:
         with span("engine/build"):
             if start == 0:
@@ -367,6 +386,7 @@ class InferenceEngine:
             chunk = np.zeros(C, np.int32)
             chunk[:n_valid] = toks
             table = np.ascontiguousarray(self.cache.tables[req.slot])
+            self._note_walk(start + n_valid - 1)
         with span("engine/dispatch"):
             first, self._pages = self._programs["prefill"](
                 self.llm.params,
@@ -404,6 +424,7 @@ class InferenceEngine:
                 positions[s] = req.prompt_len + len(req.out) - 1
                 active[s] = True
             tables = np.ascontiguousarray(self.cache.tables)
+            self._note_walk(int(positions.max()))
         with span("engine/dispatch"):
             nxt, self._pages = self._programs["decode"](
                 self.llm.params,
@@ -525,6 +546,8 @@ class InferenceEngine:
             out = self.sched.stats()
             out.update(self.cache.stats())
         out["iterations"] = float(self.iterations)
+        out["ctx_blocks_walked"] = float(self.ctx_blocks_walked)
+        out["ctx_blocks_full"] = float(self.ctx_blocks_full)
         out.update({f"compile_{k}": v for k, v in self.compile_stats().items()})
         load = self._moe_load
         if load is not None:  # as of the last gauge tick (gauge_period_s)

@@ -26,17 +26,25 @@ def greedy_reference(model, params, prompt, n_new):
     return seq[0, n:].tolist()
 
 
-def paged_greedy(llm, prompts, n_new, *, page_size, chunk):
+def interleaved_tables(slots, pages_per_slot):
+    """Page tables [slots, pages_per_slot] that deal the physical pages to
+    the slots in reverse and interleaved, so a program that ignored the page
+    table would read another slot's rows."""
+    pages = np.arange(slots * pages_per_slot, dtype=np.int32)[::-1]
+    return np.ascontiguousarray(pages.reshape(pages_per_slot, slots).T)
+
+
+def paged_greedy(llm, prompts, n_new, *, page_size, chunk, pages_per_slot=None):
     """The same tokens from ``llm.engine_programs`` driven by hand, as the
     engine's thread drives them: one slot a prompt, each prompt prefilled in
     chunks of ``chunk``, then ``n_new - 1`` decode steps over all slots at
-    once.  Physical pages are dealt to the slots in reverse and interleaved,
-    so a program that ignored the page table would read another slot's rows."""
+    once, through ``interleaved_tables``.  A slot's table holds the longest sequence, or ``pages_per_slot`` pages.
+    Each program must have compiled exactly once by the end."""
     slots = len(prompts)
-    pages_per_slot = -(-(max(map(len, prompts)) + n_new) // page_size)
-    tables = np.ascontiguousarray(
-        np.arange(slots * pages_per_slot, dtype=np.int32)[::-1].reshape(pages_per_slot, slots).T
-    )
+    needed = -(-(max(map(len, prompts)) + n_new) // page_size)
+    pages_per_slot = pages_per_slot or needed
+    assert pages_per_slot >= needed
+    tables = interleaved_tables(slots, pages_per_slot)
     programs = llm.engine_programs(num_pages=slots * pages_per_slot, page_size=page_size)
     pages = programs["init"]()
     outs = []
@@ -57,4 +65,5 @@ def paged_greedy(llm, prompts, n_new, *, page_size, chunk):
         )
         for o, t in zip(outs, np.asarray(nxt)):
             o.append(int(t))
+    assert programs["prefill"]._cache_size() == programs["decode"]._cache_size() == 1
     return outs

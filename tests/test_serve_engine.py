@@ -224,6 +224,40 @@ def test_engine_mixed_lengths_match_the_plain_forward_one_shape(tiny_llm):
         eng.shutdown()
 
 
+@pytest.mark.parametrize("table", ["two-blocks", "one-block"])
+def test_engine_counts_how_far_the_context_walk_goes(table):
+    """``ctx_blocks_walked / ctx_blocks_full``: under 1 while the fleet's
+    contexts end in the first of a table's two blocks, 1 per call once one
+    reaches the second, and 1 throughout where the table is one block."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+    from ray_tpu.serve.llm import ShardedLLM
+
+    seq = {"two-blocks": 512, "one-block": 256}[table]
+    llm = ShardedLLM(LlamaConfig.tiny(compute_dtype=jnp.float32, max_seq_len=seq), tp=1, init="random")
+    eng = InferenceEngine(
+        llm, EngineConfig(num_slots=2, page_size=16, max_seq_len=seq, prefill_chunk=64), deployment="t"
+    )
+    try:
+        per_call = seq // 256
+        eng.submit([5, 7, 9], 4).sink.result(timeout=120)
+        st = eng.stats()
+        # one chunk and three decode steps, each inside block 0
+        assert (st["ctx_blocks_walked"], st["ctx_blocks_full"]) == (4.0, 4.0 * per_call)
+        if table == "two-blocks":
+            # chunks at 0..192 stay in block 0; the chunk at 256 (whose first
+            # token is delivered from the prefill) and two decode steps reach block 1
+            eng.submit(list(range(1, 231)) + list(range(1, 61)), 3).sink.result(timeout=120)
+            now = eng.stats()
+            assert now["ctx_blocks_walked"] - st["ctx_blocks_walked"] == 4 * 1 + 3 * 2
+            assert now["ctx_blocks_full"] - st["ctx_blocks_full"] == 7 * 2
+        assert eng.compile_stats() == {"prefill": 1, "decode": 1}
+    finally:
+        eng.shutdown()
+
+
 def test_engine_eos_truncates(tiny_llm):
     from ray_tpu.serve.engine import EngineConfig, InferenceEngine
 
