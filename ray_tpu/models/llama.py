@@ -64,6 +64,11 @@ class LlamaConfig:
     def head_dim(self) -> int:
         return self.dim // self.n_heads
 
+    def build_model(self) -> "LlamaModel":
+        """The model this configuration describes (``serve/llm.py``
+        builds it from the configuration alone)."""
+        return LlamaModel(self)
+
     @property
     def padded_vocab(self) -> int:
         return _round_up(self.vocab_size, 128)
@@ -391,19 +396,26 @@ class LlamaModel:
             load = [load[0] + hits.sum((0, 1, 2))]
         return x, (kp, vp, *load)
 
-    def _paged_forward(self, params, x, pages, wpage, woff, tables, q_pos, q_valid):
-        """The layers of both paged programs: x [B, S, E] at positions
-        ``q_pos`` [B, S] through tables [B, MP] -> (normed x, pages).  The
-        walk ends at the block of the call's longest live position."""
-        cfg = self.config
-        PS = pages[0].shape[2]
+    def _walk_blocks(self, tables, page_size: int, q_pos, q_valid):
+        """The page tables [B, MP] cut into the walk's blocks [B, n, pages a
+        block] (a last block the table does not fill is padded with pages
+        that do not exist), and how many of them the call's longest live
+        position reaches."""
         B, MP = tables.shape
-        bp = ctx_block_pages(MP, PS)
-        # the table in blocks [B, n, bp]; a last block it does not fill is
-        # padded with pages that do not exist
+        bp = ctx_block_pages(MP, page_size)
         tables = jnp.pad(tables, ((0, 0), (0, -MP % bp)), constant_values=-1).reshape(B, -1, bp)
         longest = jnp.max(jnp.where(q_valid, q_pos, 0))
-        n_blocks = jnp.minimum(longest // (bp * PS) + 1, tables.shape[1])
+        return tables, jnp.minimum(longest // (bp * page_size) + 1, tables.shape[1])
+
+    def _paged_forward(self, params, x, pages, wpage, woff, tables, q_pos, q_valid, slot=None):
+        """The layers of both paged programs: x [B, S, E] at positions
+        ``q_pos`` [B, S] through tables [B, MP] -> (normed x, pages).  The
+        walk ends at the block of the call's longest live position.
+        ``slot`` is the prefill chunk's slot (None in a decode step, whose
+        rows are the slots): a model with per-slot state needs it, this one
+        keeps everything in pages."""
+        cfg = self.config
+        tables, n_blocks = self._walk_blocks(tables, pages[0].shape[2], q_pos, q_valid)
         for li in range(cfg.n_layers):
             lp = jax.tree.map(lambda p: p[li], params["layers"])
             x, pages = self._paged_layer(
@@ -421,19 +433,36 @@ class LlamaModel:
             logits = jnp.where(pad, -jnp.inf, logits)
         return jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
-    def init_pages(self, num_pages: int, page_size: int) -> Tuple:
+    def init_pages(self, num_pages: int, page_size: int, num_slots: int = 0) -> Tuple:
         """Physical KV page pool shared by every engine slot:
         [L, num_pages, page_size, KV, D] pair.  An expert model's pool
         carries a third member, the routing counter [n_experts] int32
         (assignments per expert, summed over layers and calls, wrapping):
         it rides through both programs with the pool, so the engine reads
-        no further array per turn."""
+        no further array per turn.
+
+        The pool's contract with the engine, whatever the model: members 0
+        and 1 are indexed by physical page on axis 1 (``defrag`` moves
+        them), member 2 is the routing counter, and any further members
+        are per-SLOT state, fixed tensors indexed by slot (``num_slots``
+        of them; this model has none) that no page move touches.
+        ``pool_pspecs`` gives each member's sharding."""
         cfg = self.config
         shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
         pool = (jnp.zeros(shape, cfg.compute_dtype), jnp.zeros(shape, cfg.compute_dtype))
         if cfg.n_experts:
             pool += (jnp.zeros((cfg.n_experts,), jnp.int32),)
         return pool
+
+    def pool_pspecs(self) -> Tuple:
+        """A PartitionSpec per member of ``init_pages``' pool: the pages
+        over their KV heads (tp), the routing counter whole."""
+        page = P(None, None, None, "tp", None)
+        return (page, page) + ((P(),) if self.config.n_experts else ())
+
+    def held_experts(self) -> slice:
+        """Which of the routing counter's experts this model holds: all."""
+        return slice(0, self.config.n_experts)
 
     def decode_step_paged(
         self, params, pages, tables, tokens, positions, active, page_size: int
@@ -463,7 +492,7 @@ class LlamaModel:
         return self._sample_greedy(logits), pages
 
     def prefill_chunk_paged(
-        self, params, pages, table_row, tokens, start_pos, n_valid, page_size: int
+        self, params, pages, table_row, tokens, start_pos, n_valid, slot=None, *, page_size: int
     ):
         """One chunk of one slot's prompt: write positions
         start_pos..start_pos+n_valid-1 into the pool and return the greedy
@@ -471,7 +500,9 @@ class LlamaModel:
         on the final chunk — the request's first generated token).
 
         tokens [C] int32 (tail chunks are padded; padding masked by
-        n_valid); table_row [MP] int32; start_pos / n_valid scalars.  The
+        n_valid); table_row [MP] int32; start_pos / n_valid scalars; slot
+        the scalar index of the slot the prompt was admitted to (where the
+        model keeps per-slot state beside the pages).  The
         chunk length C is static, so a prompt of any length runs as
         ceil(P/C) calls of ONE compiled program — chunked prefill never
         adds a shape, and in-flight decode streams wait at most one chunk
@@ -489,7 +520,7 @@ class LlamaModel:
         # causal over the slot's logical context, chunk included (K/V land
         # in the pool before a layer attends)
         x, pages = self._paged_forward(
-            params, x, pages, wpage, woff, table_row[None], pos[None], valid_q[None]
+            params, x, pages, wpage, woff, table_row[None], pos[None], valid_q[None], slot
         )
         logits = x[0] @ params["out_head"].astype(cd)  # [C, V]
         last = jnp.clip(n_valid - 1, 0, C - 1)

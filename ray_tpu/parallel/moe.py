@@ -107,8 +107,8 @@ def route(h: jax.Array, router_w: jax.Array, top_k: int):
     softmax over ALL experts, then the ``top_k`` largest probabilities.
     h [T, E]; router_w [E, X].  Returns (weights [T, K] float32, chosen
     [T, K] int32); the weights are the softmax's own values, NOT divided by
-    their sum (OLMoE publishes ``norm_topk_prob`` false; a model that
-    publishes true brings the option and its reference with it)."""
+    their sum (``dropless_moe_ffn`` does that where a model publishes
+    ``norm_topk_prob`` true)."""
     logits = jnp.dot(
         h.astype(jnp.float32), router_w.astype(jnp.float32), precision=lax.Precision.HIGHEST
     )
@@ -124,12 +124,24 @@ def dropless_moe_ffn(
     w_down: jax.Array,  # [X, H, E]
     *,
     top_k: int,
+    renormalize: bool = False,
+    expert_offset: int = 0,
 ) -> Tuple[jax.Array, jax.Array]:
     """Exact top-k routed SwiGLU experts: every row goes through all
     ``top_k`` of its experts whatever the other rows chose -- no capacity,
     nothing dropped -- and a row's result does not depend on its neighbours.
 
-    Computed as a masked contraction over ALL experts: every expert's
+    The layer is told which experts it HOLDS: the router scores all X
+    experts of ``router_w``, and ``w_gate``/``w_up``/``w_down`` are those of
+    experts ``expert_offset .. expert_offset + w_gate.shape[0] - 1``, all of
+    them (OLMoE) or one device's share of an expert-parallel deployment
+    (Qwen3-Next: 128 of 512).  The result is the held experts' part of the
+    sum; the shares of all holders add up to the whole layer, and a row none
+    of whose choices is held here gets zero.  ``renormalize`` divides a row's
+    ``top_k`` weights by their sum (``norm_topk_prob``: OLMoE false,
+    Qwen3-Next true).
+
+    Computed as a masked contraction over ALL held experts: every one's
     SwiGLU runs on every row, and the router's weight (zero for an expert
     the row did not choose) multiplies the activation before ONE
     down-projection contracts over experts and expert width together.  At
@@ -146,11 +158,15 @@ def dropless_moe_ffn(
     grouped matmul over the routed rows alone would be the faster layer.
     Returns (y [T, E] in h's dtype, chosen [T, K])."""
     cd = h.dtype
-    n_experts = router_w.shape[-1]
+    n_experts, held = router_w.shape[-1], w_gate.shape[0]
     with jax.named_scope("router"):
         weights, chosen = route(h, router_w, top_k)
+        if renormalize:  # over the row's top_k, held here or not
+            weights = weights / weights.sum(-1, keepdims=True)
         # [T, X]: a row's weight for each expert, zero where not chosen
         dense_w = (jax.nn.one_hot(chosen, n_experts, dtype=jnp.float32) * weights[..., None]).sum(-2)
+        if held != n_experts:
+            dense_w = dense_w[:, expert_offset : expert_offset + held]
     gate = jnp.einsum("te,xeh->xth", h, w_gate.astype(cd))
     up = jnp.einsum("te,xeh->xth", h, w_up.astype(cd))
     act = (jax.nn.silu(gate) * up).astype(jnp.float32) * dense_w.T[:, :, None]

@@ -144,7 +144,7 @@ class InferenceEngine:
         self.llm = llm
         self.deployment = deployment
         self._programs = llm.engine_programs(
-            num_pages=cfg.pool_pages(), page_size=cfg.page_size
+            num_pages=cfg.pool_pages(), page_size=cfg.page_size, num_slots=cfg.num_slots
         )
         self._pages = self._programs["init"]()
         self.cache = PagedKVCache(
@@ -176,6 +176,11 @@ class InferenceEngine:
         # the replica's running totals.  None for a dense model
         self._moe_seen = None
         self._moe_load = None
+        # per-slot state beside the pages (the pool's members from the
+        # fourth on, ``LlamaModel.init_pages``): its size, and how many
+        # chunks began a sequence and so reset their slot's state
+        self._state_bytes = sum(int(a.nbytes) for a in self._pages[3:])
+        self.state_resets = 0
         self._tokens_reported = 0
         self.iterations = 0
         # how far the paged programs' walk over context blocks engages
@@ -381,6 +386,7 @@ class InferenceEngine:
         with span("engine/build"):
             if start == 0:
                 serve_tracing.stamp(req.trace, "serve_prefill_start")
+                self.state_resets += 1
             C = self.cfg.prefill_chunk
             n_valid = len(toks)
             chunk = np.zeros(C, np.int32)
@@ -395,6 +401,7 @@ class InferenceEngine:
                 chunk,
                 np.int32(start),
                 np.int32(n_valid),
+                np.int32(req.slot),
             )
         if not self.sched.note_prefill(req, n_valid):
             return
@@ -551,8 +558,15 @@ class InferenceEngine:
         out.update({f"compile_{k}": v for k, v in self.compile_stats().items()})
         load = self._moe_load
         if load is not None:  # as of the last gauge tick (gauge_period_s)
-            out["moe_assignments"] = float(load.sum())
-            out["moe_expert_load"] = load.tolist()
+            # the counter is over the router's experts; a replica that holds
+            # a share of them serves the assignments that fell on its own
+            held = load[self.llm.model.held_experts()]
+            out["moe_assignments"] = out["moe_assignments_held"] = float(held.sum())
+            out["moe_assignments_seen"] = float(load.sum())
+            out["moe_expert_load"] = held.tolist()
+        if self._state_bytes:
+            out["state_bytes"] = float(self._state_bytes)
+            out["state_resets"] = float(self.state_resets)
         return out
 
     def compile_stats(self) -> Dict[str, int]:

@@ -26,7 +26,7 @@ from typing import Any, Dict, Optional, Sequence
 
 import numpy as np
 
-from ray_tpu.models.llama import LlamaConfig, LlamaModel
+from ray_tpu.models.llama import LlamaConfig
 
 __all__ = ["ShardedLLM", "engine_llm_deployment"]
 
@@ -78,8 +78,9 @@ def _filter_spec(spec, axis_names):
 
 
 class ShardedLLM:
-    """A ``LlamaConfig`` model -- dense (Llama, Mistral) or sparse-expert
-    with QK-norm (OLMoE) -- sharded over a 1-D tp mesh; ``engine_programs``
+    """A ``LlamaConfig`` model -- dense (Llama, Mistral), sparse-expert
+    with QK-norm (OLMoE), or a subclass that builds its own model
+    (``cfg.build_model()``: Qwen3-Next) -- sharded over a 1-D tp mesh; ``engine_programs``
     gives the serving engine its jitted programs over that mesh.
 
     init:
@@ -117,7 +118,7 @@ class ShardedLLM:
                 raise ValueError(f"{name}={dim} not divisible by tp={tp}")
         self.cfg = cfg
         self.tp = tp
-        self.model = LlamaModel(cfg)
+        self.model = cfg.build_model()
         self.mesh = Mesh(np.array(devices[:tp]), ("tp",))
 
         pspecs = self.model.param_pspecs()
@@ -189,7 +190,7 @@ class ShardedLLM:
         else:
             raise ValueError(f"unknown init {init!r}")
 
-    def engine_programs(self, *, num_pages: int, page_size: int) -> Dict[str, Any]:
+    def engine_programs(self, *, num_pages: int, page_size: int, num_slots: int = 0) -> Dict[str, Any]:
         """The continuous-batching engine's three jitted programs over
         THIS mesh: page-pool init, prefill chunk, decode step
         (models/llama.py).  The pool is sharded over its KV heads (tp) and
@@ -204,15 +205,15 @@ class ShardedLLM:
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
-        page_sharding = NamedSharding(self.mesh, P(None, None, None, "tp", None))
         repl = NamedSharding(self.mesh, P())
         # explicit out_shardings keep the pool's NamedSharding STABLE
         # across calls: without them the first program's output drops to
         # an inferred sharding, which flips the next call's jit cache key
         # — one silent recompile per program, exactly what the engine's
         # no-recompilation contract forbids
-        # (an expert model's pool ends with its small routing counter)
-        pool_sharding = (page_sharding, page_sharding) + ((repl,) if self.cfg.n_experts else ())
+        # (the pool's members are the model's: pages, an expert model's
+        # routing counter, per-slot state -- ``model.pool_pspecs``)
+        pool_sharding = tuple(NamedSharding(self.mesh, spec) for spec in self.model.pool_pspecs())
         step_out = (repl, pool_sharding)
 
         def program(fn, *args, **kwargs):
@@ -226,7 +227,7 @@ class ShardedLLM:
 
         return {
             "init": jax.jit(
-                program(self.model.init_pages, num_pages, page_size),
+                program(self.model.init_pages, num_pages, page_size, num_slots),
                 out_shardings=pool_sharding,
             ),
             "prefill": jax.jit(
