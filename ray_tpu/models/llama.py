@@ -217,7 +217,21 @@ class LlamaModel:
         if cfg.qk_norm:
             q = _rms_norm(q, lp["q_norm"].astype(jnp.float32), cfg.norm_eps).astype(cd)
             k = _rms_norm(k, lp["k_norm"].astype(jnp.float32), cfg.norm_eps).astype(cd)
-        v = (h @ lp["wv"].astype(cd)).reshape(B, S, KV, D)
+        v = h @ lp["wv"].astype(cd)
+        if not cfg.qk_norm:
+            # Nothing stands between these matmuls and the split into heads,
+            # and the TPU compiler would fold the split INTO them: it then
+            # reads the weight as [heads, D, E], contraction axis minor,
+            # which a row-major stack [L, E, heads * D] is not, and re-lays
+            # all of wq/wk/wv on every call of either paged program (1.6 GB
+            # moved, 2.3 ms at Mistral-7B's widths and 16 layers).  Behind
+            # the barrier they stay the plain 2-D matmuls the FFN's are,
+            # which read a layer's slice of the stack where it lies; the
+            # split is a view of a few rows.  No value changes
+            # (tests/test_weight_copies.py compiles both programs for the
+            # v5e without a chip and holds this).
+            q, k, v = jax.lax.optimization_barrier((q, k, v))
+        v = v.reshape(B, S, KV, D)
         q = _rope(q.reshape(B, S, H, D), positions, cfg.rope_theta)
         k = _rope(k.reshape(B, S, KV, D), positions, cfg.rope_theta)
         return q, k, v

@@ -298,24 +298,25 @@ class InferenceEngine:
         resolving it here means a device-tier ref lands zero-copy when the
         trainer shares this process/mesh, and rides the collective pull
         plane cross-node — the host object path never re-serializes the
-        checkpoint (core/DEVICE_TIER.md)."""
+        checkpoint (core/DEVICE_TIER.md).
+
+        Either form is placed on the replica's mesh as ``llm.params`` is
+        (``ShardedLLM.place``) here, on the caller's thread: the swap
+        itself is one assignment, and both programs stay the ones compiled."""
         if (params is None) == (ref is None):
             raise ValueError("update_weights wants exactly one of params=/ref=")
         if ref is not None:
             import ray_tpu
 
             params = ray_tpu.get(ref, timeout=300)
-        import jax
-        import jax.numpy as jnp
-
         if hasattr(params, "ndim") and getattr(params, "ndim") == 1:
             # flat vector → this model's own tree structure
+            import jax.numpy as jnp
             from jax.flatten_util import ravel_pytree
 
             _, unravel = ravel_pytree(self.llm.params)
-            new = unravel(jnp.asarray(params))
-        else:
-            new = jax.tree.map(jnp.asarray, params)
+            params = unravel(jnp.asarray(params))
+        new = self.llm.place(params)
         with self._lock:
             self._pending_params = new
         self._wake.set()

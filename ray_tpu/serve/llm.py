@@ -89,6 +89,12 @@ class ShardedLLM:
                  7-billion-sample RNG on a 1-core host; still exercises
                  every collective with non-trivial values)
       dict     — a params pytree (or host arrays) to shard onto the mesh
+      "abstract" — no weights: ``params`` is the tree of
+                 ``jax.ShapeDtypeStruct``s with the replica's shardings, to
+                 compile ``engine_programs`` ahead of time
+                 (``.lower(llm.params, ...)``), also over the devices of a
+                 TPU topology this host has no chip of
+                 (tests/test_weight_copies.py)
     """
 
     def __init__(
@@ -130,8 +136,10 @@ class ShardedLLM:
 
         shapes = jax.eval_shape(self.model.init, jax.random.PRNGKey(seed))
         if isinstance(init, dict):
+            self.params = self.place(init)
+        elif init == "abstract":
             self.params = jax.tree.map(
-                lambda x, sh: jax.device_put(x, sh), init, self.param_shardings
+                lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh), shapes, self.param_shardings
             )
         elif init == "cheap":
             # deterministic per-shard numpy fill via make_array_from_callback
@@ -190,6 +198,14 @@ class ShardedLLM:
         else:
             raise ValueError(f"unknown init {init!r}")
 
+    def place(self, params):
+        """``params`` (a tree like ``self.params``, of device or host
+        arrays) on the mesh with the shardings ``self.params`` has -- what
+        keeps a weight swap on the two programs already compiled."""
+        import jax
+
+        return jax.tree.map(jax.device_put, params, self.param_shardings)
+
     def engine_programs(self, *, num_pages: int, page_size: int, num_slots: int = 0) -> Dict[str, Any]:
         """The continuous-batching engine's three jitted programs over
         THIS mesh: page-pool init, prefill chunk, decode step
@@ -198,7 +214,12 @@ class ShardedLLM:
         in-place buffer per program — and because the paged programs are
         shaped by pool geometry only, the whole mixed-length fleet shares
         exactly one compiled decode shape (the engine asserts this via
-        ``compile_stats``)."""
+        ``compile_stats``).
+
+        Weights are stored row-major and both programs read every stack
+        where it lies: no weight-sized copy runs in either
+        (``LlamaModel._qkv`` says what it took; tests/test_weight_copies.py
+        compiles them for the v5e without a chip and checks)."""
         import functools
 
         import jax
