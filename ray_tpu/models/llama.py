@@ -438,6 +438,10 @@ class LlamaModel:
         x = _rms_norm(x, params["final_norm"].astype(jnp.float32), cfg.norm_eps)
         return x.astype(cfg.compute_dtype), pages
 
+    def _logits(self, params, x):
+        """Normed activations [..., E] -> logits over the padded vocabulary."""
+        return x @ params["out_head"].astype(self.config.compute_dtype)
+
     def _sample_greedy(self, logits):
         """argmax with the vocab padding masked (a padded id must never
         enter a sequence — it has no embedding semantics)."""
@@ -455,12 +459,9 @@ class LlamaModel:
         it rides through both programs with the pool, so the engine reads
         no further array per turn.
 
-        The pool's contract with the engine, whatever the model: members 0
-        and 1 are indexed by physical page on axis 1 (``defrag`` moves
-        them), member 2 is the routing counter, and any further members
-        are per-SLOT state, fixed tensors indexed by slot (``num_slots``
-        of them; this model has none) that no page move touches.
-        ``pool_pspecs`` gives each member's sharding."""
+        The pool's contract with the engine, whatever the model: a tuple
+        whose members the model names (``pool_roles``) and shards
+        (``pool_pspecs``); the engine counts no positions."""
         cfg = self.config
         shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads, cfg.head_dim)
         pool = (jnp.zeros(shape, cfg.compute_dtype), jnp.zeros(shape, cfg.compute_dtype))
@@ -473,6 +474,14 @@ class LlamaModel:
         over their KV heads (tp), the routing counter whole."""
         page = P(None, None, None, "tp", None)
         return (page, page) + ((P(),) if self.config.n_experts else ())
+
+    def pool_roles(self) -> Tuple[str, ...]:
+        """What each member of ``init_pages``' pool is to the engine:
+        "pages" (indexed by physical page on axis 1: ``defrag`` moves
+        them), "counter" (the routing counter, at most one) or "state"
+        (per-SLOT tensors, ``num_slots`` wide, that no page move touches;
+        this model has none)."""
+        return ("pages", "pages") + (("counter",) if self.config.n_experts else ())
 
     def held_experts(self) -> slice:
         """Which of the routing counter's experts this model holds: all."""
@@ -502,7 +511,7 @@ class LlamaModel:
         x, pages = self._paged_forward(
             params, x, pages, wpage, woff, tables, positions[:, None], active[:, None]
         )
-        logits = (x @ params["out_head"].astype(cd))[:, 0, :]
+        logits = self._logits(params, x)[:, 0, :]
         return self._sample_greedy(logits), pages
 
     def prefill_chunk_paged(
@@ -536,6 +545,6 @@ class LlamaModel:
         x, pages = self._paged_forward(
             params, x, pages, wpage, woff, table_row[None], pos[None], valid_q[None], slot
         )
-        logits = x[0] @ params["out_head"].astype(cd)  # [C, V]
+        logits = self._logits(params, x[0])  # [C, V]
         last = jnp.clip(n_valid - 1, 0, C - 1)
         return self._sample_greedy(logits[last]), pages

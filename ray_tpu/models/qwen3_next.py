@@ -288,7 +288,7 @@ class Qwen3NextModel(LlamaModel):
     # --------------------------------------------------------------- pool
 
     def init_pages(self, num_pages: int, page_size: int, num_slots: int = 0) -> Tuple:
-        """``LlamaModel.init_pages``' contract: K/V pages of the FULL
+        """The pool (``pool_roles`` names its members): K/V pages of the FULL
         layers only [L_full, NP, PS, KV, D], the routing counter over the
         router's experts [n_routed_experts] int32, and per slot the Gated
         DeltaNet layers' recurrent state [L_lin, slots, Hv, Dk, Dv] float32
@@ -312,6 +312,9 @@ class Qwen3NextModel(LlamaModel):
     def pool_pspecs(self) -> Tuple:
         # nothing of the mixers is split, so neither is what they keep
         return (P(), P(), P(), P(), P())
+
+    def pool_roles(self) -> Tuple[str, ...]:
+        return ("pages", "pages", "counter", "state", "state")
 
     def held_experts(self) -> slice:
         cfg = self.config

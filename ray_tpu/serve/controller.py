@@ -812,6 +812,13 @@ class ServeController:
         actor_cls = ray_tpu.remote(Replica)
         opts = dict(dep["actor_options"])
         opts["name"] = rname
+        # a replica is an async actor, and the worker runs at most
+        # max(max_concurrency, 100) of its calls at once: let as many in as
+        # the deployment's handles may have in flight (and a few control
+        # calls beside them), or requests the handle admitted wait in the
+        # actor's mailbox where the replica's own queue cannot see them -- a
+        # 128-slot engine under 192 callers ran 99 slots (PERF.md, PR 35)
+        opts.setdefault("max_concurrency", int(dep["max_concurrent_queries"]) + 8)
         handle = actor_cls.options(**opts).remote(
             dep["cls"], dep["init_args"], dep["init_kwargs"],
             user_config=dep.get("user_config"),

@@ -171,15 +171,17 @@ class InferenceEngine:
         self._fatal: Optional[str] = None
         self._gauges = None
         self._last_gauges = 0.0
-        # an expert model's routing counter (the pool's third member, a
-        # wrapping int32 per expert on the device): its last reading, and
-        # the replica's running totals.  None for a dense model
+        # what each member of the pool is, by the model's word
+        # (``LlamaModel.pool_roles``): "pages", "counter" or "state"
+        self._pool_roles = tuple(llm.model.pool_roles())
+        # an expert model's routing counter (a wrapping int32 per expert on
+        # the device): its last reading, and the replica's running totals.
+        # None for a model without one
         self._moe_seen = None
         self._moe_load = None
-        # per-slot state beside the pages (the pool's members from the
-        # fourth on, ``LlamaModel.init_pages``): its size, and how many
-        # chunks began a sequence and so reset their slot's state
-        self._state_bytes = sum(int(a.nbytes) for a in self._pages[3:])
+        # per-slot state beside the pages: its size, and how many chunks
+        # began a sequence and so reset their slot's state
+        self._state_bytes = sum(int(a.nbytes) for a, role in zip(self._pages, self._pool_roles) if role == "state")
         self.state_resets = 0
         self._tokens_reported = 0
         self.iterations = 0
@@ -535,11 +537,10 @@ class InferenceEngine:
                 # ranges are safe
                 srcs = np.asarray([m[0] for m in moves], np.int32)
                 dsts = np.asarray([m[1] for m in moves], np.int32)
-                kp, vp, *rest = self._pages  # rest: an expert model's routing counter
-                self._pages = (
-                    kp.at[:, dsts].set(kp[:, srcs]),
-                    vp.at[:, dsts].set(vp[:, srcs]),
-                    *rest,
+                # the members indexed by page move; a counter and per-slot state are handed on
+                self._pages = tuple(
+                    a.at[:, dsts].set(a[:, srcs]) if role == "pages" else a
+                    for a, role in zip(self._pages, self._pool_roles)
                 )
                 self.cache.apply_compaction(moves)
             frag = self.cache.allocator.fragmentation()
@@ -601,10 +602,10 @@ class InferenceEngine:
         turn.  The device counts in wrapping int32; the difference between
         two readings is exact as long as fewer than 2**32 assignments go to
         one expert between ticks."""
-        if len(self._pages) < 3:
+        if "counter" not in self._pool_roles:
             return
         try:
-            seen = np.asarray(self._pages[2]).astype(np.uint32)
+            seen = np.asarray(self._pages[self._pool_roles.index("counter")]).astype(np.uint32)
         except Exception:  # noqa: BLE001 -- a dead loop's pool may be gone; the totals stay as they were
             return
         if self._moe_load is None:  # the pool starts at zero

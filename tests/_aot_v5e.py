@@ -39,10 +39,14 @@ def topology_devices():
 
 def load_config(name: str, n_layers: int):
     """(the program's config at the file's widths and ``n_layers``, the file's engine geometry)."""
-    from benchmarks.drivers import serve, serve_moe, serve_qwen3_next
+    from benchmarks.drivers import serve, serve_jamba, serve_moe, serve_qwen3_next
 
     with open(os.path.join(ROOT, "benchmarks", "configs", f"{name}.json")) as f:
         cfg = json.load(f)
+    if cfg["kind"] == "serve_jamba":  # a few layers of BOTH kinds: the file's own list, not the first ``n_layers``
+        lcfg = serve_jamba.reference_config(cfg)
+        assert lcfg.n_layers == n_layers, (lcfg.n_layers, n_layers)
+        return lcfg, cfg["engine"]
     build = {"serve": serve.llama_config, "serve_moe": serve_moe.moe_config, "serve_qwen3_next": serve_qwen3_next.hybrid_config}[cfg["kind"]]
     return dataclasses.replace(build(cfg), n_layers=n_layers), cfg["engine"]
 
@@ -153,6 +157,11 @@ def weight_relayouts(hlo: str) -> list:
         if sizes and max(sizes) >= BIG:
             found.append((name, re.sub(r"\{[^{}]*\}", "", result)[:120], sum(sizes)))
     return found
+
+
+def array_shapes(hlo: str) -> set:
+    """Every array shape the module's text names: {(dtype, (dims...))}."""
+    return {(dt, tuple(int(d) for d in dims.split(",") if d)) for dt, dims in _SHAPE.findall(hlo)}
 
 
 def readings(name: str, n_layers: int, devices) -> dict:

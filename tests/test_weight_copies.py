@@ -4,8 +4,9 @@ weight swap lands where the weights were (``ShardedLLM.place``).
 For the v5e, without a chip: both engine programs of each serving
 configuration compiled at its published widths (``_aot_v5e.py``) and held to
 what was read when ``_qkv`` got its barrier -- no weight-sized copy in either
-Mistral program, the other two configurations' programs as they were -- so
-that a compiler update that changes its mind fails here first.  On the CPU:
+Mistral program, OLMoE's and Qwen3-Next's programs as they were, Jamba's with
+no weight-sized copy and no expanded scan state -- so that a compiler update
+that changes its mind fails here first.  On the CPU:
 the barrier changes no token, and every way weights arrive is placed with the
 shardings ``llm.params`` has, so the two compiled programs go on serving."""
 
@@ -80,6 +81,45 @@ def test_v5e_programs_copy_no_weight(name, layers):
         assert _aot_v5e.device_ops_named(hlo, "slice_bitcast_fusion") == [], prog
         assert len(_aot_v5e.device_ops_named(hlo_before, "slice_bitcast_fusion")) == 3, prog
         assert nbytes_before - nbytes >= 0.05e9 * layers, (prog, nbytes_before, nbytes)
+
+
+def test_v5e_jamba_programs_copy_no_weight_and_hold_no_expanded_state(monkeypatch):
+    """Jamba2-3B's two programs at the published widths over the reference
+    check's four layers (Mamba Mamba attention Mamba), with the scan's Pallas
+    kernel (the ahead-of-time compile takes the Mosaic call): no weight-sized
+    copy -- the tied head is contracted against the embedding where it lies,
+    there is no [E, V] twin --, the pool aliased into the result, and no array
+    of rows x d_inner x d_state elements or more but the weights and the pool's
+    own members: the scan's expanded state never reaches HBM."""
+    import numpy as np
+
+    from ray_tpu.ops import selective_scan
+
+    devices = _v5e()
+    monkeypatch.setattr(selective_scan, "_on_tpu", lambda: True)  # the chip's form; this process sees the CPU
+    lcfg, engine = _aot_v5e.load_config("jamba2-3b", 4)
+    assert lcfg.layer_kinds == ("mamba", "mamba", "attn", "mamba") and (lcfg.dim, lcfg.d_inner, lcfg.d_state) == (2560, 5120, 16)
+    compiled = _aot_v5e.compile_programs(lcfg, engine, devices)
+    model = lcfg.build_model()
+    slots = int(engine["num_slots"])
+    pool = jax.eval_shape(lambda: model.init_pages(slots * int(engine["max_seq_len"]) // int(engine["page_size"]), int(engine["page_size"]), slots))
+    weights = jax.tree.leaves(jax.eval_shape(model.init, jax.random.PRNGKey(0)))
+    allowed = {tuple(a.shape) for a in pool}
+    for a, role in zip(pool, model.pool_roles()):
+        if role == "pages":  # one attending layer's pages, as the walk views them (its one KV head squeezed)
+            one = tuple(a.shape[1:])
+            allowed |= {one, (1, *one), tuple(d for d in one if d != 1), (1, *(d for d in one if d != 1))}
+    for w in weights:  # a stack, one layer of it, and that layer as a stack of one
+        allowed |= {tuple(w.shape), tuple(w.shape[1:]), (1, *w.shape[1:])}
+    for prog, rows in (("decode", slots), ("prefill", int(engine["prefill_chunk"]))):
+        hlo, _ = compiled[prog]
+        assert _aot_v5e.weight_relayouts(hlo) == [], prog
+        assert hlo.count('custom_call_target="tpu_custom_call"') == 3, prog  # one scan a Mamba layer
+        shapes = _aot_v5e.array_shapes(hlo)
+        assert ("bf16", (lcfg.dim, lcfg.padded_vocab)) not in shapes and ("bf16", (1, lcfg.dim, lcfg.padded_vocab)) not in shapes, prog
+        expanded = rows * lcfg.d_inner * lcfg.d_state
+        big = {(dt, dims) for dt, dims in shapes if int(np.prod(dims)) >= expanded and dims not in allowed}
+        assert not big, (prog, sorted(big))
 
 
 def test_the_relayout_reader_sees_a_copy_where_there_is_one():
