@@ -4,17 +4,28 @@ step per iteration.
 Per PAPERS.md §2 (Pathways) the scarce resource on a single-controller
 TPU runtime is per-step DISPATCH latency, so the engine is a loop that
 lives inside the replica actor and whose host work per token step is
-near zero: build four small int arrays, call ONE pre-compiled program
+near zero: build three small arrays, call ONE pre-compiled program
 over the tp mesh (active-slot masking covers empty slots), read S int32s
-back.  That device→host read is deliberate — it is the host-visible
-token frontier that makes per-request TTFT/TPOT real measurements and
-feeds every stream its next frame; batching it per step (not per
+back.  The read is what makes per-request TTFT/TPOT real measurements
+and feeds every stream its next frame; batching it per step (not per
 request) is what keeps the loop O(1) in concurrency.
+
+The loop keeps ONE decode step in flight.  The token frontier — what the
+next step decodes from — stays on the device (the step before's result,
+with the row that joins this turn taking its chunk's sampled token inside
+the program), so step N+1 is dispatched before step N's tokens are read,
+and everything the host does with them — the read, bookkeeping,
+delivery, flushes, gauges, the next admission and build — runs under a
+program that is already queued.  Counts are all a step's other arguments
+need, and the host has them without reading a token:
+``len(out) + unread``.  The depth is one and fixed: there is no other
+order of a turn and no switch (DESIGN.md, "One step in flight").
 
 Iteration shape (scheduler.py decides, this module executes):
 
-    admit  →  [one prefill chunk]  →  [one decode step over the fleet]
-           →  deliver frames  →  retire / recycle slots
+    admit  →  [dispatch one prefill chunk]  →  [dispatch decode step N+1]
+           →  read step N: deliver frames, retire / recycle slots
+           →  [read the chunk's first token: deliver]
 
 Inside a profiler capture the same shape reads as spans on this thread
 (``serve/tracing.py span``, vocabulary ``ENGINE_SPANS``), on the device
@@ -22,10 +33,13 @@ trace's clock:
 
     engine/iteration
       engine/admit
-      engine/prefill   engine/build → engine/dispatch → [engine/sync → engine/deliver]
-      engine/decode    engine/build → engine/dispatch → engine/sync → engine/deliver
+      engine/prefill   engine/build → engine/dispatch                  (the chunk)
+      engine/decode    [engine/build → engine/dispatch]                (step N+1)
+                       [engine/sync → engine/deliver]                  (step N)
+                       [engine/sync → engine/deliver]                  (a first token)
       engine/flush     (only with laggard streams)
       engine/gauges    (only when the gauges publish)
+      engine/sync      (after it: a routing counter's read, which waits for step N+1)
     engine/idle        (the wake wait of a turn with no work)
 
 Nothing here talks to the head: token frames leave through delivery
@@ -40,7 +54,7 @@ import math
 import threading
 import time
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional
+from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
@@ -100,10 +114,10 @@ class BufferSink:
 
     def emit(self, frame: dict) -> None:
         """Engine-thread only (single producer)."""
-        self.tokens.extend(frame.get("t") or [])
-        if frame.get("error"):
+        self.tokens.extend(frame["t"])
+        if frame["error"]:
             self.error = str(frame["error"])
-        if frame.get("done"):
+        if frame["done"]:
             with self._lock:
                 self._done.set()
                 cbs, self._cbs = self._cbs, []
@@ -185,6 +199,18 @@ class InferenceEngine:
         self.state_resets = 0
         self._tokens_reported = 0
         self.iterations = 0
+        # the token frontier, on the device: the last decode step's result
+        # (zeros before the first), which the next step decodes from unread
+        self._frontier = self._programs["place"](np.zeros(cfg.num_slots, np.int32))
+        self._no_token = self._programs["place"](np.int32(0))  # ``join_token`` of a step nobody joins
+        # the decode step in flight: (its result, [(request, slot), ...]),
+        # dispatched and not yet read; None between a read and the next dispatch
+        self._ahead = None
+        self.decode_steps = 0
+        # decode steps dispatched while the step before's tokens were unread,
+        # and rows computed for a request that had ended by the time they were read
+        self.steps_ahead = 0
+        self.rows_discarded = 0
         # how far the paged programs' walk over context blocks engages
         # (models/llama.py): blocks walked, and blocks of whole tables, summed
         # over prefill chunks and decode steps from the positions of each call
@@ -239,7 +265,8 @@ class InferenceEngine:
 
     def cancel(self, req: EngineRequest) -> None:
         """Consumer abandoned the request: retire it at the next
-        iteration boundary (mid-step cancel would desync the fleet)."""
+        iteration boundary.  A row of it may be in the step in flight:
+        what that computes is discarded at the read."""
         req.cancelled = True
         self._wake.set()
 
@@ -259,7 +286,9 @@ class InferenceEngine:
             while not self._stop:
                 with self._lock:
                     busy = self.sched.has_work()
-                if not busy:
+                # a step whose rows all ended while it ran is read (and
+                # discarded) by one more turn: nothing is in flight past here
+                if not busy and self._ahead is None:
                     self._run_defrags()
                     self._flush_laggards()
                     self._maybe_gauges()
@@ -280,6 +309,14 @@ class InferenceEngine:
         finally:
             self._stop = True
             reason = self._fatal or "engine shut down"
+            ahead, self._ahead = self._ahead, None
+            if ahead is not None:
+                # the device finishes what was queued before the pool goes;
+                # its tokens go nowhere: every request fails just below
+                try:
+                    np.asarray(ahead[0])
+                except Exception:  # noqa: BLE001 -- the error that killed the loop, once more
+                    pass
             with self._lock:
                 victims = self.sched.fail_all(reason)
                 parked, self._defrag_reqs = self._defrag_reqs, []
@@ -292,7 +329,8 @@ class InferenceEngine:
 
     def update_weights(self, params=None, *, ref=None) -> None:
         """Stage a live weight hot-swap; applied at the next iteration
-        boundary (decode never sees a half-swapped tree).
+        boundary (decode never sees a half-swapped tree; the step in
+        flight at that boundary keeps the weights it was dispatched with).
 
         ``params`` is a pytree matching ``llm.params`` OR a flat 1-D
         vector (``ravel_pytree`` order — what a trainer broadcasts through
@@ -338,31 +376,46 @@ class InferenceEngine:
                 self._apply_pending_params()
                 self._run_defrags()
                 with self._lock:
-                    self._reap_cancelled()
+                    reaped = self._reap_cancelled()
                     admitted = self.sched.admit()
+                for req in reaped:  # their final frames, with the lock released
+                    self._deliver(req, [], done=True, error=None)
                 for req in admitted:
                     serve_tracing.stamp(req.trace, "serve_engine_admit")
 
             # -- one prefill chunk (chunked: decode never waits on a whole prompt)
             with self._lock:
                 pf = self.sched.next_prefill()
+            joined = None
             if pf is not None:
                 with span("engine/prefill"):
-                    self._prefill_chunk(*pf)
+                    joined = self._prefill_chunk(*pf)
 
             # -- one decode step over the whole fleet: ONE program, any mix of
-            # sequence lengths, inactive slots masked
+            # sequence lengths, inactive slots masked.  It goes out BEFORE the
+            # step before it is read; then the reads, oldest first: the step in
+            # flight since last turn, then this turn's chunk (which waits for
+            # the chunk alone: the device moves on to the step just queued)
             fleet = self.sched.decode_fleet()
-            if fleet:
+            ahead, self._ahead = self._ahead, None
+            if fleet or ahead is not None or joined is not None:
                 with span("engine/decode"):
-                    self._decode_step(fleet)
+                    if fleet:
+                        self._decode_step(fleet, joined)
+                        self.steps_ahead += ahead is not None
+                    if ahead is not None:
+                        self._read_step(*ahead)
+                    if joined is not None:
+                        self._read_first(*joined)
             self._flush_laggards()
             self._maybe_gauges()
 
-    def _reap_cancelled(self) -> None:
+    def _reap_cancelled(self) -> List[EngineRequest]:
         """Lock held.  Retire cancelled running requests at the iteration
         boundary — and seal their (deferred) trace records: a cancelled
-        request still happened."""
+        request still happened.  The caller delivers each its final frame.
+        (A row of one in the step in flight is discarded when that step is
+        read.)"""
         victims = [r for r in self.running_snapshot() if r.cancelled]
         for req in victims:
             self.sched.retire(req, error=None)
@@ -371,7 +424,7 @@ class InferenceEngine:
             if req.trace is not None:
                 req.trace["tokens"] = len(req.out)
             serve_tracing.finish_request(req.trace, error=False, final=True)
-            self._deliver(req, [], done=True, error=None)
+        return victims
 
     def running_snapshot(self) -> List[EngineRequest]:
         return list(self.sched.running.values())
@@ -385,7 +438,10 @@ class InferenceEngine:
         )
         self.ctx_blocks_full += self._ctx_blocks_per_call
 
-    def _prefill_chunk(self, req: EngineRequest, start: int, toks: List[int]) -> None:
+    def _prefill_chunk(self, req: EngineRequest, start: int, toks: List[int]):
+        """Dispatch one chunk.  Returns ``(req, first)`` when the prompt is
+        now fully resident: ``first``, the chunk's sampled token, is the
+        request's first generated token, still on the device."""
         with span("engine/build"):
             if start == 0:
                 serve_tracing.stamp(req.trace, "serve_prefill_start")
@@ -394,7 +450,9 @@ class InferenceEngine:
             n_valid = len(toks)
             chunk = np.zeros(C, np.int32)
             chunk[:n_valid] = toks
-            table = np.ascontiguousarray(self.cache.tables[req.slot])
+            # a copy, here and in the decode step: the call may still be
+            # reading its arguments when a later retirement rewrites the table
+            table = self.cache.tables[req.slot].copy()
             self._note_walk(start + n_valid - 1)
         with span("engine/dispatch"):
             first, self._pages = self._programs["prefill"](
@@ -407,65 +465,91 @@ class InferenceEngine:
                 np.int32(req.slot),
             )
         if not self.sched.note_prefill(req, n_valid):
-            return
-        # prompt fully resident: the chunk's sampled token IS the first
-        # generated token, host-visible right here — the TTFT endpoint
+            return None
+        req.state = DECODE
+        req.unread = 1
+        return req, first
+
+    def _read_first(self, req: EngineRequest, first) -> None:
+        """The first token of a prompt whose last chunk this turn ran:
+        host-visible right here — the TTFT endpoint."""
         with span("engine/sync"):
             tok0 = int(first)
         with span("engine/deliver"):
             serve_tracing.stamp(req.trace, "serve_first_token")
-            req.state = DECODE
-            with self._lock:
-                finished = self.sched.note_token(req, tok0)
-            if finished:
-                self._retire(req, last_tokens=[tok0])
-            else:
-                self._deliver(req, [tok0])
+            self._hand_on([(req, tok0)])
 
-    def _decode_step(self, fleet: List[EngineRequest]) -> None:
+    def _decode_step(self, fleet: List[EngineRequest], joined) -> None:
+        """Dispatch one decode step from the frontier on the device.  Every
+        row of ``fleet`` either ran in the step before (its input is that
+        step's result, at its slot) or is ``joined``'s request."""
         with span("engine/build"):
             S = self.cfg.num_slots
-            tokens = np.zeros(S, np.int32)
+            slots = [req.slot for req in fleet]
             positions = np.zeros(S, np.int32)
+            positions[slots] = [r.prompt_len + len(r.out) + r.unread - 1 for r in fleet]
             active = np.zeros(S, bool)
+            active[slots] = True
             for req in fleet:
-                s = req.slot
-                tokens[s] = req.out[-1]
-                positions[s] = req.prompt_len + len(req.out) - 1
-                active[s] = True
-            tables = np.ascontiguousarray(self.cache.tables)
+                req.unread += 1
+            join_slot, join_token = -1, self._no_token
+            if joined is not None and active[joined[0].slot]:  # a budget of one token never joins
+                join_slot, join_token = joined[0].slot, joined[1]
+            tables = self.cache.tables.copy()
             self._note_walk(int(positions.max()))
         with span("engine/dispatch"):
             nxt, self._pages = self._programs["decode"](
                 self.llm.params,
                 self._pages,
                 tables,
-                tokens,
+                self._frontier,
                 positions,
                 active,
+                np.int32(join_slot),
+                join_token,
             )
+        self._frontier = nxt
+        # each row with the slot it ran in: a retirement unsets ``req.slot``
+        self._ahead = (nxt, list(zip(fleet, slots)))
+        self.decode_steps += 1
+
+    def _read_step(self, nxt, rows) -> None:
+        """Read one decode step's tokens (the host sees them one step after
+        the device had them) and hand them on.  A device error surfaces
+        here, a step late."""
         with span("engine/sync"):
-            nxt = np.asarray(nxt)  # the per-step host sync: the token frontier
+            nxt = np.asarray(nxt)
         with span("engine/deliver"):
-            for req in fleet:
-                tok = int(nxt[req.slot])
-                with self._lock:
-                    finished = self.sched.note_token(req, tok)
-                if finished:
-                    self._retire(req, last_tokens=[tok])
-                else:
-                    self._deliver(req, [tok])
+            toks = nxt.tolist()
+            # a request that ended (EOS, cancel) after this step went out has
+            # given its slot up: its row is never delivered nor counted.  What
+            # the row wrote is out of every live request's reach (DESIGN.md,
+            # "One step in flight")
+            live = [(req, toks[slot]) for req, slot in rows if req.slot == slot]
+            self.rows_discarded += len(rows) - len(live)
+            self._hand_on(live)
+
+    def _hand_on(self, fresh: List[Tuple[EngineRequest, int]]) -> None:
+        """``fresh`` is ``[(request, token just read), ...]``: note every
+        token and retire what ended under ONE hold of the lock, then emit a
+        frame a request with the lock released — a sink may block (a full
+        ring, a slow consumer) and ``stats()`` on another thread must not
+        wait for that."""
+        with self._lock:
+            ended = self.sched.note_tokens(fresh)
+            for (req, _), end in zip(fresh, ended):
+                req.unread -= 1
+                if end:
+                    self.sched.retire(req)
+        for (req, tok), end in zip(fresh, ended):
+            if end:
+                serve_tracing.stamp(req.trace, "serve_decode_end")
+                if req.trace is not None:
+                    req.trace["tokens"] = len(req.out)
+                serve_tracing.finish_request(req.trace, error=False, final=True)
+            self._deliver(req, [tok], done=end)
 
     # ----------------------------------------------------------- delivery
-
-    def _retire(self, req: EngineRequest, last_tokens: Optional[List[int]] = None) -> None:
-        serve_tracing.stamp(req.trace, "serve_decode_end")
-        if req.trace is not None:
-            req.trace["tokens"] = len(req.out)
-        with self._lock:
-            self.sched.retire(req)
-        serve_tracing.finish_request(req.trace, error=False, final=True)
-        self._deliver(req, last_tokens or [], done=True)
 
     def _deliver(
         self,
@@ -481,7 +565,7 @@ class InferenceEngine:
         if sink is None:
             return
         try:
-            sink.emit({"t": toks, "done": bool(done), "error": error})
+            sink.emit({"t": toks, "done": done, "error": error})
             if getattr(sink, "needs_flush", None) is not None and sink.needs_flush():
                 self._laggards.add(sink)
         except Exception:  # noqa: BLE001 -- a broken consumer must not stall the fleet
@@ -506,10 +590,10 @@ class InferenceEngine:
 
     def defrag(self, timeout: float = 30.0) -> Dict[str, Any]:
         """Compact the page pool: move allocated pages to the lowest
-        physical ids and rewrite the page tables.  The device copy runs
-        ON THE ENGINE THREAD at an iteration boundary — the loop runs
-        jitted steps outside the lock with the pool buffers DONATED, so
-        any other thread touching ``self._pages`` races a buffer that may
+        physical ids and rewrite the page tables.  The device copy is
+        dispatched ON THE ENGINE THREAD at an iteration boundary — the loop
+        runs jitted steps outside the lock with the pool buffers DONATED,
+        so any other thread touching ``self._pages`` races a buffer that may
         already be consumed; this call just parks a request and waits."""
         done = threading.Event()
         result: Dict[str, Any] = {}
@@ -523,8 +607,11 @@ class InferenceEngine:
         return result
 
     def _run_defrags(self) -> None:
-        """Engine thread, iteration boundary: the one place where nothing
-        is mid-flight through a donated pages buffer."""
+        """Engine thread, iteration boundary: the one place where no call
+        is being made with the donated pool.  A decode step may still be in
+        flight: ``self._pages`` is then its result, the moves below queue
+        behind it on the device, and it reads the copy of the tables it was
+        given; every later call is built from the rewritten ones."""
         with self._lock:
             reqs, self._defrag_reqs = self._defrag_reqs, []
         if not reqs:
@@ -555,6 +642,9 @@ class InferenceEngine:
             out = self.sched.stats()
             out.update(self.cache.stats())
         out["iterations"] = float(self.iterations)
+        out["decode_steps"] = float(self.decode_steps)
+        out["steps_ahead"] = float(self.steps_ahead)
+        out["rows_discarded"] = float(self.rows_discarded)
         out["ctx_blocks_walked"] = float(self.ctx_blocks_walked)
         out["ctx_blocks_full"] = float(self.ctx_blocks_full)
         out.update({f"compile_{k}": v for k, v in self.compile_stats().items()})
@@ -591,21 +681,25 @@ class InferenceEngine:
         if not force and now - self._last_gauges < self.cfg.gauge_period_s:
             return
         self._last_gauges = now
+        # publish first, under the step in flight; the counter's read then
+        # waits out that step and the loop goes straight on to the next dispatch
         with span("engine/gauges"):
-            self._read_moe_load()
             self._publish_gauges()
+        self._read_moe_load()
 
     def _read_moe_load(self) -> None:
         """Engine thread, gauge tick: fold the device's routing counter into
         the running totals.  Read here and nowhere else: between turns no
-        call holds the (donated) pool, and twice a second costs nothing a
-        turn.  The device counts in wrapping int32; the difference between
+        call is being made with the (donated) pool, so the member read is
+        the newest result; the read waits for the step in flight, twice a
+        second.  The device counts in wrapping int32; the difference between
         two readings is exact as long as fewer than 2**32 assignments go to
         one expert between ticks."""
         if "counter" not in self._pool_roles:
             return
         try:
-            seen = np.asarray(self._pages[self._pool_roles.index("counter")]).astype(np.uint32)
+            with span("engine/sync"):  # a blocking read like the tokens': host time it is not
+                seen = np.asarray(self._pages[self._pool_roles.index("counter")]).astype(np.uint32)
         except Exception:  # noqa: BLE001 -- a dead loop's pool may be gone; the totals stay as they were
             return
         if self._moe_load is None:  # the pool starts at zero

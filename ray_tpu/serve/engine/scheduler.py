@@ -46,6 +46,9 @@ class EngineRequest:
     slot: int = -1
     fill: int = 0  # prompt tokens already written to the cache
     out: List[int] = field(default_factory=list)
+    # tokens the device has computed (or been asked for) that the host has
+    # not read yet: the engine keeps one decode step in flight (loop.py)
+    unread: int = 0
     trace: Optional[dict] = None
     sink: Optional[object] = None  # delivery sink (engine/loop.py)
     error: Optional[str] = None
@@ -171,7 +174,16 @@ class EngineScheduler:
         return req.fill >= req.prompt_len
 
     def decode_fleet(self) -> List[EngineRequest]:
-        return [r for r in self.running.values() if r.state == DECODE]
+        """The rows of the next decode step: every sequence past its prompt
+        that still has a token to compute once those in flight are read.  A
+        row whose unread token is its last by budget stays out; one that
+        will end on EOS cannot be known yet and stays in (the engine
+        discards what it computes)."""
+        return [
+            r
+            for r in self.running.values()
+            if r.state == DECODE and len(r.out) + r.unread < r.max_new_tokens
+        ]
 
     # ----------------------------------------------------------- lifecycle
 
@@ -179,11 +191,20 @@ class EngineScheduler:
         """Record one generated token; True when the sequence retires
         (EOS or budget).  The caller delivers the token and, on True,
         calls :meth:`retire`."""
-        req.out.append(int(token))
-        self.n_tokens += 1
-        if req.eos_token is not None and int(token) == int(req.eos_token):
-            return True
-        return len(req.out) >= req.max_new_tokens
+        return self.note_tokens([(req, token)])[0]
+
+    def note_tokens(self, fresh: List[Tuple[EngineRequest, int]]) -> List[bool]:
+        """:meth:`note_token` for every ``(request, token)`` of one read, in
+        order: the whole fleet's tokens in one call, under the one hold of
+        the engine's lock that delivering a decode step takes."""
+        ended = []
+        for req, token in fresh:
+            token = int(token)
+            req.out.append(token)
+            eos = req.eos_token
+            ended.append((eos is not None and token == int(eos)) or len(req.out) >= req.max_new_tokens)
+        self.n_tokens += len(fresh)
+        return ended
 
     def drop_cancelled_queued(self) -> List[EngineRequest]:
         """Remove cancelled requests still waiting in the queue (the

@@ -209,7 +209,8 @@ class ShardedLLM:
     def engine_programs(self, *, num_pages: int, page_size: int, num_slots: int = 0) -> Dict[str, Any]:
         """The continuous-batching engine's three jitted programs over
         THIS mesh: page-pool init, prefill chunk, decode step
-        (models/llama.py).  The pool is sharded over its KV heads (tp) and
+        (models/llama.py), and ``place``, which puts a host value where
+        the programs put their token results.  The pool is sharded over its KV heads (tp) and
         DONATED into every call, so the engine's resident loop re-uses one
         in-place buffer per program — and because the paged programs are
         shaped by pool geometry only, the whole mixed-length fleet shares
@@ -237,6 +238,22 @@ class ShardedLLM:
         pool_sharding = tuple(NamedSharding(self.mesh, spec) for spec in self.model.pool_pspecs())
         step_out = (repl, pool_sharding)
 
+        def decode_step_paged(params, pages, tables, tokens, positions, active, join_slot=None, join_token=None):
+            # The engine keeps its token frontier on the device: ``tokens`` is
+            # the step before's result, unread, and the one row that joins
+            # the fleet this turn takes the token its last chunk sampled
+            # (``join_token``, unread too) at ``join_slot``; -1 joins nobody.
+            # The substitution rides inside this program because a program of
+            # its own would be a third module on the device between the two
+            # the benchmark's readers pair with their dispatches in order.
+            # Called without the two, as the references call it, this is
+            # ``model.decode_step_paged`` and nothing else.
+            if join_slot is not None:
+                import jax.numpy as jnp
+
+                tokens = jnp.where(jnp.arange(tokens.shape[0]) == join_slot, join_token, tokens)
+            return self.model.decode_step_paged(params, pages, tables, tokens, positions, active, page_size=page_size)
+
         def program(fn, *args, **kwargs):
             # a bare partial has no __name__: the compiler would call the
             # module jit__unknown.  Named after the model's method, the
@@ -256,11 +273,11 @@ class ShardedLLM:
                 donate_argnums=(1,),
                 out_shardings=step_out,
             ),
-            "decode": jax.jit(
-                program(self.model.decode_step_paged, page_size=page_size),
-                donate_argnums=(1,),
-                out_shardings=step_out,
-            ),
+            "decode": jax.jit(decode_step_paged, donate_argnums=(1,), out_shardings=step_out),
+            # a host value placed as the programs' token results are: what the
+            # engine's frontier starts from, so that the decode program sees
+            # ``tokens`` of one kind from its first call on
+            "place": lambda x: jax.device_put(x, repl),
         }
 
     def param_count(self) -> int:

@@ -69,7 +69,8 @@ def compile_programs(lcfg, engine: dict, devices) -> Dict[str, Tuple[str, float]
     pool = tuple(sds(a.shape, a.dtype) for a in jax.eval_shape(programs["init"]))
     i32 = jnp.int32
     args = {
-        "decode": (llm.params, pool, sds((slots, per_slot), i32), sds((slots,), i32), sds((slots,), i32), sds((slots,), jnp.bool_)),
+        # as the engine calls it (loop.py): the frontier on the device, and the slot and token of the row that joins
+        "decode": (llm.params, pool, sds((slots, per_slot), i32), sds((slots,), i32), sds((slots,), i32), sds((slots,), jnp.bool_), sds((), i32), sds((), i32)),
         # the engine passes the chunk's slot to every model (loop.py)
         "prefill": (llm.params, pool, sds((per_slot,), i32), sds((chunk,), i32), sds((), i32), sds((), i32), sds((), i32)),
     }

@@ -128,8 +128,31 @@ def test_span_parents(traced, child):
 def test_iteration_spans_count_the_engines_iterations(traced):
     assert traced["iterations"] > 0
     assert len(_named(traced, "engine/iteration")) == traced["iterations"]
-    # a decode step every turn that has a fleet, a prefill chunk per prompt chunk
+    # a prefill span per prompt chunk (its first token is read under engine/decode)
     assert len(_named(traced, "engine/prefill")) == sum(-(-len(p) // 4) for p in PROMPTS)
+
+
+def test_a_turn_dispatches_its_step_before_it_reads(traced):
+    """One decode step in flight, as spans: every blocking read lies in an
+    ``engine/decode`` (a chunk's span holds its dispatch alone: its token is
+    read once the step it joins is out), a decode span's dispatch comes
+    before its reads, and turns that do both are the rule."""
+    prefills, decodes = _named(traced, "engine/prefill"), _named(traced, "engine/decode")
+    syncs, dispatches = _named(traced, "engine/sync"), _named(traced, "engine/dispatch")
+    assert syncs
+    for s in syncs:
+        assert any(_inside(s, d) for d in decodes), s
+        assert not any(_inside(s, pf) for pf in prefills), s
+    both = 0
+    for d in decodes:
+        out = [ev for ev in dispatches if _inside(ev, d)]
+        reads = [ev for ev in syncs if _inside(ev, d)]
+        assert len(out) <= 1 and 0 < len(out) + len(reads) <= 3, d
+        assert all(o[1] + o[2] <= r[1] for o in out for r in reads), d
+        both += bool(out and reads)
+    st = traced["eng"].stats()
+    assert both > len(decodes) // 2 and 0 < st["steps_ahead"] < st["decode_steps"]
+    assert st["rows_discarded"] == 0.0
 
 
 def test_dispatch_events_keep_their_names_inside_dispatch_spans(traced):

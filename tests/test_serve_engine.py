@@ -419,6 +419,343 @@ def test_engine_tracing_stamps_and_single_seal(tiny_llm):
         task_events.set_enabled(old)
 
 
+# ------------------------------------------ one decode step in flight
+
+
+class _StubLLM:
+    """An engine's ``llm`` whose programs are Python: the chunk program
+    answers the prompt's last token + 1 and the decode program each active
+    row's input + 1, at once, as objects that log when the host READS them.
+    The log holds the engine's dispatches, its reads and its spans in order."""
+
+    class _Result:
+        def __init__(self, log, tag, value):
+            self.log, self.tag, self.value = log, tag, value
+
+        def _read(self):
+            if self.tag is not None:
+                self.log.append(("read",) + self.tag)
+            return self.value
+
+        def __array__(self, dtype=None, copy=None):
+            return self._read()
+
+        def __int__(self):
+            return int(self._read())
+
+    def __init__(self, step_s=0.0):
+        import types
+
+        self.log, self.step_s = [], step_s
+        self.cfg = types.SimpleNamespace(max_seq_len=4096)
+        self.model = types.SimpleNamespace(pool_roles=lambda: ("pages", "pages"))
+        self.params = None
+        self.counts = {"prefill": 0, "decode": 0}
+
+    def _out(self, kind, value):
+        tag = (kind, self.counts[kind])
+        self.counts[kind] += 1
+        self.log.append(("dispatch",) + tag)
+        return self._Result(self.log, tag, value)
+
+    def engine_programs(self, *, num_pages, page_size, num_slots):
+        import numpy as np
+
+        def prefill(params, pages, table, chunk, start, n_valid, slot):
+            return self._out("prefill", np.int32(chunk[n_valid - 1] + 1)), pages
+
+        def decode(params, pages, tables, tokens, positions, active, join_slot, join_token):
+            time.sleep(self.step_s)
+            toks = np.array(tokens.value)
+            if join_slot >= 0:
+                toks[join_slot] = join_token.value
+            return self._out("decode", np.where(active, toks + 1, -7).astype(np.int32)), pages
+
+        return {
+            "init": lambda: ("k", "v"),
+            "prefill": prefill,
+            "decode": decode,
+            "place": lambda x: self._Result(self.log, None, x),
+        }
+
+
+def _in_flight(log):
+    """Decode steps dispatched and not read, after each entry of the log."""
+    n, out = 0, []
+    for ev in log:
+        if ev[1:2] == ("decode",):
+            n += 1 if ev[0] == "dispatch" else -1
+        out.append(n)
+    return out
+
+
+def test_engine_dispatches_the_next_step_before_it_reads_the_last(monkeypatch):
+    """The order of a turn, on stub programs: decode step N+1 goes out before
+    step N's tokens are read, a chunk's first token is read after the step
+    it joins went out, and nothing is in flight while the loop idles, nor
+    after ``shutdown``."""
+    import contextlib
+    import threading
+
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+    from ray_tpu.serve.engine import loop as loop_mod
+
+    llm = _StubLLM()
+
+    @contextlib.contextmanager
+    def span(name):
+        llm.log.append(("span", name))
+        yield
+        llm.log.append(("end", name))
+
+    monkeypatch.setattr(loop_mod, "span", span)
+
+    class LoggedLock:
+        """The engine's lock, each hold of it an entry of the log."""
+
+        def __init__(self, lock):
+            self.lock = lock
+
+        def __enter__(self):
+            if threading.current_thread() is eng._thread:
+                llm.log.append(("lock",))
+            return self.lock.__enter__()
+
+        def __exit__(self, *exc):
+            return self.lock.__exit__(*exc)
+
+    cfg = EngineConfig(num_slots=2, page_size=4, max_seq_len=4096, prefill_chunk=4)
+    eng = InferenceEngine(llm, cfg, deployment="t")
+    eng._lock = LoggedLock(eng._lock)
+    try:
+        assert eng.submit([10, 11], 5).sink.result(timeout=30) == [12, 13, 14, 15, 16]
+        # an EOS is learnt a step late: the step in flight then is read, and
+        # its row discarded, before the loop idles
+        assert eng.submit([20], 9, eos_token=23).sink.result(timeout=30) == [21, 22, 23]
+        deadline = time.monotonic() + 10
+        while eng.stats()["rows_discarded"] < 1 and time.monotonic() < deadline:
+            time.sleep(0.01)
+        time.sleep(0.1)  # a few idle turns
+        log = list(llm.log)
+        at = {ev: i for i, ev in enumerate(log)}
+        steps = llm.counts["decode"]
+        assert steps == 4 + 3  # budget 5: the chunk's token + 4 steps; EOS at the third token: 2 steps + 1 discarded
+        for k in range(steps):
+            assert ("read", "decode", k) in at, k
+        ahead = [k for k in range(1, steps) if at[("dispatch", "decode", k)] < at[("read", "decode", k - 1)]]
+        assert ahead == [1, 2, 3, 5, 6]  # every step but each request's first
+        for j in range(2):  # the first token: read after the step its row joined went out
+            joined = 0 if j == 0 else 4
+            assert at[("dispatch", "prefill", j)] < at[("dispatch", "decode", joined)] < at[("read", "prefill", j)]
+        flying = _in_flight(log)
+        assert max(flying) == 2 and flying[-1] == 0
+        idles = [i for i, ev in enumerate(log) if ev == ("span", "engine/idle")]
+        assert idles and all(flying[i] == 0 for i in idles)
+        # a delivery notes and retires under ONE hold of the lock, however many rows
+        delivers = [i for i, ev in enumerate(log) if ev == ("span", "engine/deliver")]
+        assert len(delivers) == steps + 2  # a read a step, and a first token a request
+        for i in delivers:
+            assert log[i + 1 : i + 3] == [("lock",), ("end", "engine/deliver")], log[i : i + 4]
+        st = eng.stats()
+        assert (st["decode_steps"], st["steps_ahead"], st["rows_discarded"]) == (7.0, 5.0, 1.0)
+        assert st["tokens_generated"] == 8.0  # the discarded row is not counted
+    finally:
+        eng.shutdown()
+
+    # shutdown with a step in flight: the device is waited for, the caller fails typed
+    llm = _StubLLM(step_s=0.002)
+    eng = InferenceEngine(llm, cfg, deployment="t")
+    req = eng.submit([1], 4000)
+    deadline = time.monotonic() + 10
+    while llm.counts["decode"] < 5 and time.monotonic() < deadline:
+        time.sleep(0.001)
+    eng.shutdown()
+    assert not eng._thread.is_alive()
+    assert llm.counts["decode"] >= 5 and _in_flight(llm.log)[-1] == 0
+    with pytest.raises(EngineStreamError):
+        req.sink.result(timeout=5)
+
+
+def _eos_cut(full):
+    """(eos token, the answer it cuts ``full`` to): the first token past the
+    first that has not appeared before it."""
+    k = next(i for i in range(1, len(full) - 1) if full[i] not in full[:i])
+    return full[k], full[: k + 1]
+
+
+@pytest.mark.parametrize("tp", [1, 2])
+def test_engine_mixed_fleet_with_an_eos_matches_each_request_alone(tp):
+    """Lengths, budgets and an EOS in the middle of the fleet: every request
+    answers, token for token, what the plain forward gives it alone; the
+    request queued behind two slots takes the EOS request's slot and answers
+    its own tokens too.  A budget never costs a discarded row, an EOS one."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+    from ray_tpu.serve.llm import ShardedLLM
+
+    llm = ShardedLLM(LlamaConfig.tiny(compute_dtype=jnp.float32), tp=tp, init="random")
+    ref = lambda p, n: greedy_reference(llm.model, llm.params, p, n)  # noqa: E731
+    eng = InferenceEngine(
+        llm, EngineConfig(num_slots=2, page_size=4, max_seq_len=48, prefill_chunk=4), deployment="t"
+    )
+    try:
+        jobs = [([5, 7, 9], 6), ([3], 1), (list(range(1, 12)), 9), ([4, 4], 2), (list(range(20, 29)), 4)]
+        reqs = [eng.submit(p, n) for p, n in jobs]
+        for (p, n), r in zip(jobs, reqs):
+            assert r.sink.result(timeout=180) == ref(p, n), (p, n)
+        st = eng.stats()
+        assert st["rows_discarded"] == 0.0 and 0 < st["steps_ahead"] < st["decode_steps"]
+
+        long_p, eos_p, queued_p = [9, 8, 7, 6, 5], [5, 7, 9], [2, 4, 6, 8]
+        eos, cut = _eos_cut(ref(eos_p, 10))
+        slots, pages = [], {}
+        admit = eng.sched.admit
+
+        def admit_and_note():
+            got = admit()
+            for r in got:
+                slots.append((r.rid, r.slot))
+                pages[r.rid] = set(eng.cache.slot_pages(r.slot))
+            return got
+
+        eng.sched.admit = admit_and_note
+        a = eng.submit(long_p, 14)
+        b = eng.submit(eos_p, 10, eos_token=eos)
+        c = eng.submit(queued_p, 7)
+        assert b.sink.result(timeout=180) == cut
+        assert a.sink.result(timeout=180) == ref(long_p, 14)
+        assert c.sink.result(timeout=180) == ref(queued_p, 7)
+        # the slot and the pages the EOS freed, with a row of their old owner
+        # still in flight over them, went to the request queued behind
+        assert dict(slots)[c.rid] == dict(slots)[b.rid] and pages[c.rid] & pages[b.rid]
+        now = eng.stats()
+        assert now["rows_discarded"] == 1.0  # one ending on a stop token, one row in flight then
+        assert now["steps_ahead"] > st["steps_ahead"]
+        assert now["requests_failed"] == 0.0 and now["pages_used"] == 0.0
+        assert eng.compile_stats() == {"prefill": 1, "decode": 1}
+    finally:
+        eng.shutdown()
+
+
+def test_engine_cancel_defrag_and_new_weights_with_a_step_in_flight(tiny_llm):
+    """A sink that holds the engine thread inside a delivery — so with the
+    next step already in flight — while a neighbour is cancelled, a defrag
+    parked and the same weights staged again: the held request's answer is
+    its answer alone, the pages really moved, and both programs are the two
+    that compiled."""
+    import threading
+
+    import jax
+
+    from ray_tpu.serve.engine import BufferSink, EngineConfig, InferenceEngine
+
+    class GateSink(BufferSink):
+        def __init__(self, at):
+            super().__init__()
+            self.at, self.reached, self.go = at, threading.Event(), threading.Event()
+
+        def emit(self, frame):
+            super().emit(frame)
+            if len(self.tokens) == self.at:
+                self.reached.set()
+                assert self.go.wait(60)
+
+    eng = InferenceEngine(
+        tiny_llm,
+        EngineConfig(num_slots=3, page_size=4, max_seq_len=48, prefill_chunk=4),
+        deployment="t",
+    )
+    try:
+        class FramesSink(BufferSink):
+            def __init__(self):
+                super().__init__()
+                self.frames = []
+
+            def emit(self, frame):
+                self.frames.append((list(frame["t"]), frame["done"], frame["error"]))
+                super().emit(frame)
+
+        gate = GateSink(at=6)
+        short = eng.submit([1], 2)  # admitted first: the lowest pages, free again by the gate
+        held = eng.submit([5, 7, 9], 30, sink=gate)
+        other = eng.submit([2, 4], 30, sink=FramesSink())
+        assert gate.reached.wait(120)
+        assert eng._ahead is not None and len(short.sink.result(timeout=5)) == 2
+        # a sink that blocks holds the engine thread, not the engine's lock
+        seen = {}
+        asker = threading.Thread(target=lambda: seen.update(eng.stats()))
+        asker.start()
+        asker.join(10)
+        assert not asker.is_alive() and seen["steps_ahead"] > 0
+        assert any(req is other for req, _ in eng._ahead[1])  # a token of it is in flight
+        eng.cancel(other)
+        before = len(other.sink.frames)
+        eng.update_weights(jax.tree.map(lambda x: x + 0, tiny_llm.params))
+        moved = {}
+        parked = threading.Thread(target=lambda: moved.update(eng.defrag(timeout=120)))
+        parked.start()
+        gate.go.set()
+        assert held.sink.result(timeout=180) == greedy_reference(tiny_llm.model, tiny_llm.params, [5, 7, 9], 30)
+        parked.join(120)
+        assert moved["moves"] > 0
+        got = other.sink.result(timeout=60)
+        assert 0 < len(got) < 30 and got == greedy_reference(tiny_llm.model, tiny_llm.params, [2, 4], len(got))
+        # after the cancel: the rest of the delivery the gate interrupted (its
+        # row comes after the held one), then the final frame, empty; the
+        # token in flight at the cancel went nowhere
+        assert [(len(t), done, err) for t, done, err in other.sink.frames[before:]] == [(1, False, None), (0, True, None)]
+        assert [done for _, done, _ in other.sink.frames].count(True) == 1
+        st = eng.stats()
+        assert eng.weight_updates == 1 and st["rows_discarded"] >= 1.0
+        assert st["requests_failed"] == 0.0 and st["slots_active"] == 0.0
+        assert eng.compile_stats() == {"prefill": 1, "decode": 1}
+    finally:
+        eng.shutdown()
+
+
+def test_engine_routing_totals_stay_exact_with_a_step_in_flight():
+    """An expert model's routing counter is read at the gauge tick, here every
+    turn, each time behind the step in flight: the totals are what the
+    device computed, to the assignment -- every prompt token and every
+    generated token but a request's last, a discarded row included, through
+    2 layers x 2 experts -- and no reading ever goes back."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+    from ray_tpu.serve.llm import ShardedLLM
+
+    cfg = LlamaConfig(
+        vocab_size=256, dim=64, n_layers=2, n_heads=4, n_kv_heads=4, hidden_dim=32, max_seq_len=128,
+        n_experts=8, n_experts_per_tok=2, qk_norm=True, compute_dtype=jnp.float32, param_dtype=jnp.float32, remat=False,
+    )
+    llm = ShardedLLM(cfg, tp=1, init="random")
+    eng = InferenceEngine(
+        llm, EngineConfig(num_slots=3, page_size=4, max_seq_len=48, prefill_chunk=4, gauge_period_s=0.0), deployment="t"
+    )
+    try:
+        eos, cut = _eos_cut(greedy_reference(llm.model, llm.params, [5, 7, 9], 12))
+        jobs = [([5, 7, 9], 12, eos), (list(range(1, 12)), 9, None), ([4, 4], 6, None)]
+        reqs = [eng.submit(p, n, eos_token=e) for p, n, e in jobs]
+        readings = []
+        while not all(r.done for r in reqs):
+            readings.append(eng.stats().get("moe_assignments", 0.0))
+        outs = [r.sink.result(timeout=180) for r in reqs]
+        assert outs[0] == cut and [len(o) for o in outs[1:]] == [9, 6]
+        eng._wake.set()
+        time.sleep(0.3)  # an idle tick reads the counter with nothing in flight
+        st = eng.stats()
+        assert readings == sorted(readings) and readings[-1] <= st["moe_assignments"]
+        assert st["rows_discarded"] == 1.0 and st["steps_ahead"] > 0
+        rows = sum(len(p) + len(o) - 1 for (p, _, _), o in zip(jobs, outs)) + int(st["rows_discarded"])
+        assert st["moe_assignments"] == rows * 2 * 2 == sum(st["moe_expert_load"])
+        assert eng.compile_stats() == {"prefill": 1, "decode": 1}
+    finally:
+        eng.shutdown()
+
+
 # --------------------------------------------------------- serve e2e paths
 
 
@@ -675,10 +1012,13 @@ def test_proxy_sse_streams_and_503_sheds(engine_cluster):
         # overload: saturate the single slot + 1-deep queue with slow
         # requests, then expect a bounded 503 rejection
         slow = {"prompt": [1, 2], "max_new_tokens": 400}
-        refs = [handle.remote(slow) for _ in range(4)]
+        refs = []
         saw_503 = False
         deadline = time.time() + 60
         while time.time() < deadline and not saw_503:
+            # topped up every round: a slow request is 400 steps of a tiny
+            # model, which the engine may finish before the proxy's request lands
+            refs += [handle.remote(slow) for _ in range(4)]
             try:
                 urllib.request.urlopen(
                     urllib.request.Request(
