@@ -99,7 +99,8 @@ PHASES = (
 
 # Engine-thread span vocabulary (ray_tpu/serve/tracing.py ``span``): the
 # phases of ONE engine iteration, written as profiler TraceAnnotations on
-# the ``engine-<deployment>`` thread, so they sit on the device trace's
+# the ``engine-<deployment>`` thread and on no other (the gauges' publisher,
+# ``gauges-<deployment>``, writes none), so they sit on the device trace's
 # clock.  They complement the ``serve_*`` stamps above (a request's life
 # across threads, wall clock); graftlint GL008 checks literal span() sites
 # against this tuple, the benchmark's readers match the same names.
@@ -110,10 +111,12 @@ ENGINE_SPANS = (
     "engine/decode",  # one decode step over the fleet
     "engine/build",  # host arrays for the program call (in prefill/decode)
     "engine/dispatch",  # the jitted call (holds the PjitFunction event)
-    "engine/sync",  # the blocking device->host read of the sampled tokens
-    "engine/deliver",  # note_token + deliver/retire of the step's tokens
+    "engine/sync",  # a blocking device->host read: the host waits for a busy device
+    "engine/deliver",  # one read's tokens handed on: lock, bookkeeping under it, emit
+    "engine/lock",  # the ACQUISITION of the engine's lock, nothing else (in admit, iteration, deliver)
+    "engine/emit",  # the pass over the sinks with the lock released (in deliver)
     "engine/flush",  # re-flush of streams whose ring was full
-    "engine/gauges",  # occupancy gauges, only when they publish
+    "engine/gauges",  # twice a second: what is left of the gauge tick on this thread
     "engine/idle",  # the wake wait of a loop with no work
 )
 

@@ -206,6 +206,7 @@ def _print_engine_gauges(engine: dict) -> None:
             f"kv_pages={pages:.0f}/{ptotal:.0f} "
             f"queue={gauges.get('queue_depth', 0):.0f} "
             f"frag={gauges.get('page_fragmentation', 0):.2f} "
+            f"host={gauges.get('host_share', 0):.2f} "
             f"tokens={gauges.get('tokens_total', 0):.0f}"
         )
 

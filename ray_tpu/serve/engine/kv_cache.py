@@ -32,7 +32,25 @@ from ray_tpu.util.lockwitness import named_lock
 
 import numpy as np
 
-__all__ = ["PageAllocator", "PagedKVCache"]
+__all__ = ["PageAllocator", "PagedKVCache", "fragmentation_of"]
+
+
+def fragmentation_of(free: List[int]) -> float:
+    """Fragmentation of a free list given as page ids in any order: 0.0 =
+    the free space is one contiguous run, 1.0 = maximally scattered.
+    Indirection through page tables makes fragmentation harmless for
+    correctness; the metric (and compaction) exist for HBM locality and for
+    shrinking the pool live.  It takes a COPY of the list where another
+    thread may change it (``PagedKVCache.free_pages``): the walk is over
+    the whole pool and belongs under no lock."""
+    nfree = len(free)
+    if nfree <= 1:
+        return 0.0
+    ids = np.sort(np.asarray(free, np.int64))
+    # a run ends where the next id is not the next page
+    ends = np.flatnonzero(np.diff(ids) != 1)
+    longest = int(np.diff(np.concatenate(([-1], ends, [nfree - 1]))).max())
+    return 1.0 - longest / nfree
 
 
 class PageAllocator:
@@ -86,19 +104,7 @@ class PageAllocator:
     # ------------------------------------------------------------ defrag
 
     def fragmentation(self) -> float:
-        """0.0 = the free space is one contiguous run, 1.0 = maximally
-        scattered.  Indirection through page tables makes fragmentation
-        harmless for correctness; the metric (and compaction) exist for
-        HBM locality and for shrinking the pool live."""
-        nfree = len(self._free)
-        if nfree <= 1:
-            return 0.0
-        ids = sorted(self._free)
-        longest = run = 1
-        for a, b in zip(ids, ids[1:]):
-            run = run + 1 if b == a + 1 else 1
-            longest = max(longest, run)
-        return 1.0 - longest / nfree
+        return fragmentation_of(self._free)
 
     def compaction_plan(self, allocated: List[int]) -> List[Tuple[int, int]]:
         """Plan a defrag: moves ``[(src, dst), ...]`` relocating allocated
@@ -181,6 +187,11 @@ class PagedKVCache:
         with self._lock:
             return list(self._slot_pages.get(slot, []))
 
+    def free_pages(self) -> List[int]:
+        """A copy of the free list, for ``fragmentation_of`` outside the lock."""
+        with self._lock:
+            return list(self.allocator._free)
+
     # ------------------------------------------------------------ defrag
 
     def compaction_plan(self) -> List[Tuple[int, int]]:
@@ -204,11 +215,10 @@ class PagedKVCache:
             self.allocator.apply_compaction(n_alloc)
 
     def stats(self) -> Dict[str, float]:
+        """Counts only: fragmentation is a walk over the pool, which the
+        caller makes over ``free_pages()`` with no lock held."""
         with self._lock:
             return {
                 "pages_total": float(self.allocator.num_pages),
                 "pages_used": float(self.allocator.used),
-                "page_size": float(self.page_size),
-                "fragmentation": self.allocator.fragmentation(),
-                "slots_with_pages": float(len(self._slot_pages)),
             }

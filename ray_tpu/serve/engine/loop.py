@@ -15,7 +15,7 @@ next step decodes from — stays on the device (the step before's result,
 with the row that joins this turn taking its chunk's sampled token inside
 the program), so step N+1 is dispatched before step N's tokens are read,
 and everything the host does with them — the read, bookkeeping,
-delivery, flushes, gauges, the next admission and build — runs under a
+delivery, flushes, the next admission and build — runs under a
 program that is already queued.  Counts are all a step's other arguments
 need, and the host has them without reading a token:
 ``len(out) + unread``.  The depth is one and fixed: there is no other
@@ -32,24 +32,36 @@ Inside a profiler capture the same shape reads as spans on this thread
 trace's clock:
 
     engine/iteration
-      engine/admit
+      engine/admit     engine/lock                                     (reap, admit)
+      engine/lock                                                      (next_prefill)
       engine/prefill   engine/build → engine/dispatch                  (the chunk)
       engine/decode    [engine/build → engine/dispatch]                (step N+1)
                        [engine/sync → engine/deliver]                  (step N)
                        [engine/sync → engine/deliver]                  (a first token)
+                         engine/deliver = engine/lock → bookkeeping under the lock → engine/emit
       engine/flush     (only with laggard streams)
-      engine/gauges    (only when the gauges publish)
-      engine/sync      (after it: a routing counter's read, which waits for step N+1)
+      engine/sync      (twice a second, a model with a routing counter: its read, which waits for step N+1)
+      engine/gauges    (twice a second: what is left of the tick on this thread, the fold of that read)
     engine/idle        (the wake wait of a turn with no work)
 
-Nothing here talks to the head: token frames leave through delivery
-sinks (buffered result, or dag-channel streams via engine/transport.py)
-and observability leaves through the serve tracer's batched SERVE_TRACE
-frames plus ``ray_tpu_serve_engine_*`` gauges.
+``engine/lock`` is the ACQUISITION of the engine's lock and nothing else
+(the time work waited for ``submit()`` or a ``stats()`` caller on another
+thread); ``engine/emit`` is the pass over the sinks with the lock released.
+The thread also keeps three clocks of its own, whole window and no
+profiler (``stats()``): ``turn_s``, ``sync_wait_s``, ``lock_wait_s``.
+
+Nothing on this thread talks to the head: token frames leave through
+delivery sinks (buffered result, or dag-channel streams via
+engine/transport.py), observability leaves through the serve tracer's
+batched SERVE_TRACE frames, and the ``ray_tpu_serve_engine_*`` gauges are
+written by a publisher thread of the engine's own (``gauges-<deployment>``:
+one ``stats()`` and ten blocking writes to the head every
+``gauge_period_s``, which this thread used to pay for).
 """
 
 from __future__ import annotations
 
+import contextlib
 import math
 import threading
 import time
@@ -61,7 +73,7 @@ import numpy as np
 from ray_tpu.exceptions import EngineStreamError
 from ray_tpu.serve import tracing as serve_tracing
 from ray_tpu.serve.tracing import span
-from ray_tpu.serve.engine.kv_cache import PagedKVCache
+from ray_tpu.serve.engine.kv_cache import PagedKVCache, fragmentation_of
 from ray_tpu.serve.engine.scheduler import (
     DECODE,
     EngineRequest,
@@ -184,7 +196,7 @@ class InferenceEngine:
         self._stop = False
         self._fatal: Optional[str] = None
         self._gauges = None
-        self._last_gauges = 0.0
+        self._last_tick = 0.0
         # what each member of the pool is, by the model's word
         # (``LlamaModel.pool_roles``): "pages", "counter" or "state"
         self._pool_roles = tuple(llm.model.pool_roles())
@@ -198,7 +210,17 @@ class InferenceEngine:
         self._state_bytes = sum(int(a.nbytes) for a, role in zip(self._pages, self._pool_roles) if role == "state")
         self.state_resets = 0
         self._tokens_reported = 0
+        self._published = (0.0, 0.0)  # turn_s, sync_wait_s as of the last publish
         self.iterations = 0
+        # the thread's own clocks (seconds, this thread their only writer,
+        # whole turns only): inside ``_iteration``, of that waiting in the
+        # blocking reads for a device still busy, and waiting for ``_lock``.
+        # What is left of a turn is the host's work; ``sync_wait_s`` no longer
+        # growing means the host sets the pace (DESIGN.md, "Observability")
+        self.turn_s = 0.0
+        self.sync_wait_s = 0.0
+        self.lock_wait_s = 0.0
+        self._sync_ns = self._lock_ns = 0  # of the turn under way
         # the token frontier, on the device: the last decode step's result
         # (zeros before the first), which the next step decodes from unread
         self._frontier = self._programs["place"](np.zeros(cfg.num_slots, np.int32))
@@ -221,10 +243,18 @@ class InferenceEngine:
         self._ctx_blocks_per_call = -(-cfg.pages_per_slot // block_pages)
         self.ctx_blocks_walked = 0
         self.ctx_blocks_full = 0
+        # the gauges' publisher: every write is a blocking round trip to
+        # the head, so none of them is made on the engine thread
+        self._publish_lock = named_lock("InferenceEngine._publish_lock")
+        self._halt = threading.Event()
+        self._publisher = threading.Thread(
+            target=self._publish_loop, name=f"gauges-{deployment}", daemon=True
+        )
         self._thread = threading.Thread(
             target=self._run, name=f"engine-{deployment}", daemon=True
         )
         self._thread.start()
+        self._publisher.start()
 
     # -------------------------------------------------------------- intake
 
@@ -284,14 +314,16 @@ class InferenceEngine:
         profiler.set_thread_role("engine")
         try:
             while not self._stop:
-                with self._lock:
-                    busy = self.sched.has_work()
+                # read without the lock: a hint only.  Whoever gives the loop
+                # work sets ``_wake`` after it, so a stale "no" costs nothing,
+                # and a turn looks again under the lock
+                busy = self.sched.has_work()
                 # a step whose rows all ended while it ran is read (and
                 # discarded) by one more turn: nothing is in flight past here
                 if not busy and self._ahead is None:
                     self._run_defrags()
                     self._flush_laggards()
-                    self._maybe_gauges()
+                    self._tick()
                     fast = any(
                         getattr(s, "flushable", lambda: False)()
                         for s in self._laggards
@@ -325,7 +357,8 @@ class InferenceEngine:
             for done, result in parked:  # never strand a defrag waiter
                 result.update({"moves": 0, "error": reason})
                 done.set()
-            self._maybe_gauges(force=True)
+            self._halt.set()
+            self._publish_gauges()  # the last state, once: nothing is left to serve
 
     def update_weights(self, params=None, *, ref=None) -> None:
         """Stage a live weight hot-swap; applied at the next iteration
@@ -362,20 +395,37 @@ class InferenceEngine:
         self._wake.set()
 
     def _apply_pending_params(self) -> None:
-        with self._lock:
-            new, self._pending_params = self._pending_params, None
-        if new is None:
+        if self._pending_params is None:  # staged under the lock; seen a turn late at worst
             return
+        with self._locked():
+            new, self._pending_params = self._pending_params, None
         self.llm.params = new
         self.weight_updates += 1
 
+    @contextlib.contextmanager
+    def _locked(self):
+        """Engine thread, inside a turn: hold ``_lock`` for the body.  The
+        ACQUISITION alone is the ``engine/lock`` span and ``lock_wait_s``:
+        time the turn's work waited for ``submit()`` or a ``stats()`` caller
+        on another thread."""
+        t0 = time.perf_counter_ns()
+        with span("engine/lock"):
+            self._lock.acquire()
+        self._lock_ns += time.perf_counter_ns() - t0
+        try:
+            yield
+        finally:
+            self._lock.release()
+
     def _iteration(self) -> None:
+        t0 = time.perf_counter_ns()
+        self._sync_ns = self._lock_ns = 0  # what an idle turn's tick read is no turn's wait
         with span("engine/iteration"):
             self.iterations += 1
             with span("engine/admit"):
                 self._apply_pending_params()
                 self._run_defrags()
-                with self._lock:
+                with self._locked():
                     reaped = self._reap_cancelled()
                     admitted = self.sched.admit()
                 for req in reaped:  # their final frames, with the lock released
@@ -384,7 +434,7 @@ class InferenceEngine:
                     serve_tracing.stamp(req.trace, "serve_engine_admit")
 
             # -- one prefill chunk (chunked: decode never waits on a whole prompt)
-            with self._lock:
+            with self._locked():
                 pf = self.sched.next_prefill()
             joined = None
             if pf is not None:
@@ -408,7 +458,12 @@ class InferenceEngine:
                     if joined is not None:
                         self._read_first(*joined)
             self._flush_laggards()
-            self._maybe_gauges()
+            self._tick()
+        # the turn first: a reader that takes the waits first (``stats()``)
+        # never sees them exceed it
+        self.turn_s += (time.perf_counter_ns() - t0) * 1e-9
+        self.sync_wait_s += self._sync_ns * 1e-9
+        self.lock_wait_s += self._lock_ns * 1e-9
 
     def _reap_cancelled(self) -> List[EngineRequest]:
         """Lock held.  Retire cancelled running requests at the iteration
@@ -473,11 +528,20 @@ class InferenceEngine:
     def _read_first(self, req: EngineRequest, first) -> None:
         """The first token of a prompt whose last chunk this turn ran:
         host-visible right here — the TTFT endpoint."""
-        with span("engine/sync"):
-            tok0 = int(first)
+        tok0 = int(self._await(first))
         with span("engine/deliver"):
             serve_tracing.stamp(req.trace, "serve_first_token")
             self._hand_on([(req, tok0)])
+
+    def _await(self, result) -> np.ndarray:
+        """The blocking device->host read, wherever this thread makes one:
+        the ``engine/sync`` span and, inside a turn, ``sync_wait_s``.  Under
+        it the host waits for a device that is busy."""
+        t0 = time.perf_counter_ns()
+        with span("engine/sync"):
+            out = np.asarray(result)
+        self._sync_ns += time.perf_counter_ns() - t0
+        return out
 
     def _decode_step(self, fleet: List[EngineRequest], joined) -> None:
         """Dispatch one decode step from the frontier on the device.  Every
@@ -517,8 +581,7 @@ class InferenceEngine:
         """Read one decode step's tokens (the host sees them one step after
         the device had them) and hand them on.  A device error surfaces
         here, a step late."""
-        with span("engine/sync"):
-            nxt = np.asarray(nxt)
+        nxt = self._await(nxt)
         with span("engine/deliver"):
             toks = nxt.tolist()
             # a request that ended (EOS, cancel) after this step went out has
@@ -534,20 +597,23 @@ class InferenceEngine:
         token and retire what ended under ONE hold of the lock, then emit a
         frame a request with the lock released — a sink may block (a full
         ring, a slow consumer) and ``stats()`` on another thread must not
-        wait for that."""
-        with self._lock:
+        wait for that.  Inside the caller's ``engine/deliver``: the lock's
+        acquisition and the pass over the sinks have spans of their own, so
+        a long delivery says which of the three it was."""
+        with self._locked():
             ended = self.sched.note_tokens(fresh)
             for (req, _), end in zip(fresh, ended):
                 req.unread -= 1
                 if end:
                     self.sched.retire(req)
-        for (req, tok), end in zip(fresh, ended):
-            if end:
-                serve_tracing.stamp(req.trace, "serve_decode_end")
-                if req.trace is not None:
-                    req.trace["tokens"] = len(req.out)
-                serve_tracing.finish_request(req.trace, error=False, final=True)
-            self._deliver(req, [tok], done=end)
+        with span("engine/emit"):
+            for (req, tok), end in zip(fresh, ended):
+                if end:
+                    serve_tracing.stamp(req.trace, "serve_decode_end")
+                    if req.trace is not None:
+                        req.trace["tokens"] = len(req.out)
+                    serve_tracing.finish_request(req.trace, error=False, final=True)
+                self._deliver(req, [tok], done=end)
 
     # ----------------------------------------------------------- delivery
 
@@ -612,11 +678,10 @@ class InferenceEngine:
         flight: ``self._pages`` is then its result, the moves below queue
         behind it on the device, and it reads the copy of the tables it was
         given; every later call is built from the rewritten ones."""
-        with self._lock:
-            reqs, self._defrag_reqs = self._defrag_reqs, []
-        if not reqs:
+        if not self._defrag_reqs:  # parked under the lock by a caller that then sets ``_wake``
             return
         with self._lock:
+            reqs, self._defrag_reqs = self._defrag_reqs, []
             moves = self.cache.compaction_plan()
             if moves:
                 # one gather/scatter per buffer: every source page
@@ -630,7 +695,8 @@ class InferenceEngine:
                     for a, role in zip(self._pages, self._pool_roles)
                 )
                 self.cache.apply_compaction(moves)
-            frag = self.cache.allocator.fragmentation()
+            free = self.cache.free_pages()
+        frag = fragmentation_of(free)
         for done, result in reqs:
             result.update({"moves": len(moves), "fragmentation": frag})
             done.set()
@@ -638,10 +704,20 @@ class InferenceEngine:
     # ------------------------------------------------------------- observe
 
     def stats(self) -> Dict[str, Any]:
+        """Any thread.  The engine's lock is held for the counts and a copy
+        of the free list; the walk over that copy is made with it released
+        (``_hand_on`` on the engine thread waits for this lock)."""
         with self._lock:
             out = self.sched.stats()
             out.update(self.cache.stats())
+            free = self.cache.free_pages()
+        out["fragmentation"] = fragmentation_of(free)
         out["iterations"] = float(self.iterations)
+        # the waits before the turn that holds them (``_iteration`` writes the
+        # other way round): turn_s >= sync_wait_s + lock_wait_s in every reply
+        out["lock_wait_s"] = self.lock_wait_s
+        out["sync_wait_s"] = self.sync_wait_s
+        out["turn_s"] = self.turn_s
         out["decode_steps"] = float(self.decode_steps)
         out["steps_ahead"] = float(self.steps_ahead)
         out["rows_discarded"] = float(self.rows_discarded)
@@ -673,42 +749,47 @@ class InferenceEngine:
                 out[name] = -1
         return out
 
-    def _maybe_gauges(self, force: bool = False) -> None:
-        """Publish slot/page occupancy gauges at most every
-        ``gauge_period_s`` (off the per-token path).  Outside a connected
-        worker (unit tests drive the engine bare) this is a no-op."""
+    def _tick(self) -> None:
+        """Engine thread, at most every ``gauge_period_s``: what of the
+        gauges has to happen between two calls of the programs.  That is
+        the routing counter's read at a model that has one (between turns
+        no call is being made with the donated pool, so the member read is
+        the newest result; the read waits for the step in flight) and the
+        fold of it into the running totals, which is all that
+        ``engine/gauges`` still holds here: the gauges themselves are the
+        publisher thread's."""
         now = time.monotonic()
-        if not force and now - self._last_gauges < self.cfg.gauge_period_s:
+        if now - self._last_tick < self.cfg.gauge_period_s:
             return
-        self._last_gauges = now
-        # publish first, under the step in flight; the counter's read then
-        # waits out that step and the loop goes straight on to the next dispatch
+        self._last_tick = now
+        seen = None
+        if "counter" in self._pool_roles:
+            try:
+                seen = self._await(self._pages[self._pool_roles.index("counter")]).astype(np.uint32)
+            except Exception:  # noqa: BLE001 -- a dead loop's pool may be gone; the totals stay as they were
+                pass
         with span("engine/gauges"):
-            self._publish_gauges()
-        self._read_moe_load()
+            if seen is not None:
+                # the device counts in wrapping int32: the difference between
+                # two readings is exact as long as fewer than 2**32
+                # assignments go to one expert between ticks
+                if self._moe_load is None:  # the pool starts at zero
+                    self._moe_seen, self._moe_load = np.zeros_like(seen), np.zeros(seen.shape, np.int64)
+                # a new array each tick: stats() on another thread keeps a whole one
+                self._moe_load = self._moe_load + (seen - self._moe_seen).astype(np.int64)
+                self._moe_seen = seen
 
-    def _read_moe_load(self) -> None:
-        """Engine thread, gauge tick: fold the device's routing counter into
-        the running totals.  Read here and nowhere else: between turns no
-        call is being made with the (donated) pool, so the member read is
-        the newest result; the read waits for the step in flight, twice a
-        second.  The device counts in wrapping int32; the difference between
-        two readings is exact as long as fewer than 2**32 assignments go to
-        one expert between ticks."""
-        if "counter" not in self._pool_roles:
-            return
-        try:
-            with span("engine/sync"):  # a blocking read like the tokens': host time it is not
-                seen = np.asarray(self._pages[self._pool_roles.index("counter")]).astype(np.uint32)
-        except Exception:  # noqa: BLE001 -- a dead loop's pool may be gone; the totals stay as they were
-            return
-        if self._moe_load is None:  # the pool starts at zero
-            self._moe_seen, self._moe_load = np.zeros_like(seen), np.zeros(seen.shape, np.int64)
-        # a new array each tick: stats() on another thread keeps a whole one
-        self._moe_load = self._moe_load + (seen - self._moe_seen).astype(np.int64)
-        self._moe_seen = seen
+    def _publish_loop(self) -> None:
+        """The publisher thread: the gauges every ``gauge_period_s`` until
+        the loop ends.  It writes no ``engine/*`` span: those are the engine
+        thread's turn, and their readers take every thread's."""
+        while not self._halt.wait(self.cfg.gauge_period_s):
+            self._publish_gauges()
 
     def _publish_gauges(self) -> None:
+        """Publish slot/page occupancy, the token counter and the host's
+        share of the engine thread's turn.  Outside a connected worker (unit
+        tests drive the engine bare) this is a no-op."""
         try:
             from ray_tpu._private import worker as worker_mod
 
@@ -716,21 +797,29 @@ class InferenceEngine:
         except Exception:  # noqa: BLE001 -- bare engine: no metrics plane to publish to
             return
         try:
-            g, c = self._ensure_gauges()
-            st = self.stats()
-            dep = {"deployment": self.deployment}
-            g["slots"].set(st["slots_active"], {**dep, "kind": "active"})
-            g["slots"].set(st["slots_decode"], {**dep, "kind": "decode"})
-            g["slots"].set(st["slots_prefill"], {**dep, "kind": "prefill"})
-            g["slots"].set(st["slots_total"], {**dep, "kind": "total"})
-            g["queue"].set(st["queue_depth"], dep)
-            g["pages"].set(st["pages_used"], {**dep, "kind": "used"})
-            g["pages"].set(st["pages_total"], {**dep, "kind": "total"})
-            g["frag"].set(st["fragmentation"], dep)
-            delta = int(st["tokens_generated"]) - self._tokens_reported
-            if delta > 0:
-                c.inc(delta, dep)
-                self._tokens_reported += delta
+            with self._publish_lock:  # the publisher's tick and the loop's last publish
+                g, c = self._ensure_gauges()
+                st = self.stats()
+                dep = {"deployment": self.deployment}
+                g["slots"].set(st["slots_active"], {**dep, "kind": "active"})
+                g["slots"].set(st["slots_decode"], {**dep, "kind": "decode"})
+                g["slots"].set(st["slots_prefill"], {**dep, "kind": "prefill"})
+                g["slots"].set(st["slots_total"], {**dep, "kind": "total"})
+                g["queue"].set(st["queue_depth"], dep)
+                g["pages"].set(st["pages_used"], {**dep, "kind": "used"})
+                g["pages"].set(st["pages_total"], {**dep, "kind": "total"})
+                g["frag"].set(st["fragmentation"], dep)
+                # of the turns since the last publish, the share the thread did
+                # not spend waiting for the device; 0 while no turn ran
+                turn, wait = st["turn_s"], st["sync_wait_s"]
+                d_turn, d_wait = turn - self._published[0], wait - self._published[1]
+                self._published = (turn, wait)
+                # (two replies may split a turn between them: never below 0)
+                g["host"].set(max(0.0, 1.0 - d_wait / d_turn) if d_turn > 0 else 0.0, dep)
+                delta = int(st["tokens_generated"]) - self._tokens_reported
+                if delta > 0:
+                    c.inc(delta, dep)
+                    self._tokens_reported += delta
         except Exception:  # noqa: BLE001 -- observability is best-effort; serving already progressed
             import logging
 
@@ -764,6 +853,11 @@ class InferenceEngine:
                         "Free-list fragmentation of the KV page pool (0=contiguous)",
                         tag_keys=("deployment",),
                     ),
+                    "host": Gauge(
+                        "ray_tpu_serve_engine_host_share",
+                        "Share of the engine thread's turns not spent waiting for the device (1=host-bound)",
+                        tag_keys=("deployment",),
+                    ),
                 },
                 Counter(
                     "ray_tpu_serve_engine_tokens_total",
@@ -785,3 +879,5 @@ class InferenceEngine:
         self._stop = True
         self._wake.set()
         self._thread.join(timeout)
+        self._halt.set()  # the loop's end sets it too; a loop that outlived the join has not
+        self._publisher.join(timeout)

@@ -199,3 +199,239 @@ def test_untraced_engine_gives_the_traced_tokens(traced, monkeypatch, how):
     else:
         assert type(tracing.span("engine/iteration")).__name__ == "TraceAnnotation"
     assert _serve(traced["eng"]) == traced["outs"]
+
+
+# ---- PR 38: the lock's acquisition and the sinks' pass inside a delivery, the
+# thread's own clocks, and the gauges' publisher off the engine thread
+
+
+@pytest.mark.parametrize("child,parent", [
+    ("engine/lock", "engine/deliver"), ("engine/emit", "engine/deliver"),
+    ("engine/lock", "engine/admit"), ("engine/lock", "engine/iteration"),
+])
+def test_lock_and_emit_lie_where_they_claim(traced, child, parent):
+    children, parents = _named(traced, child), _named(traced, parent)
+    assert children and parents
+    for p in parents:  # a delivery takes the lock once and emits once; an admission and a turn take it at least once
+        held = [c for c in children if _inside(c, p)]
+        assert len(held) == 1 if parent == "engine/deliver" else held, p
+    turns = _named(traced, "engine/iteration")
+    assert all(any(_inside(c, t) for t in turns) for c in children)  # never from an idle turn
+    if child == "engine/emit":
+        assert all(any(_inside(c, p) for p in parents) for c in children)
+        for p in parents:  # the lock is given up before the sinks are walked
+            (lock,) = [c for c in _named(traced, "engine/lock") if _inside(c, p)]
+            (emit,) = [c for c in children if _inside(c, p)]
+            assert lock[1] + lock[2] <= emit[1]
+
+
+def test_a_lock_span_is_the_acquisition_alone(traced):
+    locks = _named(traced, "engine/lock")
+    others = [ev for ev in traced["spans"] if ev[0] != "engine/lock"]
+    for lock in locks:
+        assert not any(_inside(ev, lock) for ev in others), lock
+    # three a turn (admit, next_prefill, and one a delivery): a turn delivers at most twice
+    per_turn = [sum(_inside(c, t) for c in locks) for t in _named(traced, "engine/iteration")]
+    assert min(per_turn) >= 2 and max(per_turn) <= 4, sorted(set(per_turn))
+
+
+def test_the_threads_own_clocks_order_and_only_grow(traced):
+    """``stats()`` from more threads than cores while the engine serves, the
+    interpreter switching threads every 10 us: in EVERY reply, a turn under
+    way or not, the turn's time covers its waits, and no clock runs back."""
+    import sys
+    import threading
+
+    eng = traced["eng"]
+    seen, stop = [], threading.Event()
+
+    def ask():
+        mine = [eng.stats()]
+        while not stop.is_set():
+            mine.append(eng.stats())
+        seen.append(mine)
+
+    askers = [threading.Thread(target=ask) for _ in range((os.cpu_count() or 4) + 2)]
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        for t in askers:
+            t.start()
+        first = eng.stats()
+        assert _serve(eng) == traced["outs"]
+        last = eng.stats()
+    finally:
+        stop.set()
+        for t in askers:
+            t.join(30)
+        sys.setswitchinterval(interval)
+    assert not any(t.is_alive() for t in askers) and len(seen) == len(askers)
+    for mine in seen:
+        for a, b in zip(mine, mine[1:]):
+            for key in ("turn_s", "sync_wait_s", "lock_wait_s", "iterations"):
+                assert b[key] >= a[key] >= 0.0, key
+        for st in mine:
+            assert st["turn_s"] >= st["sync_wait_s"] + st["lock_wait_s"], st
+    assert last["turn_s"] > first["turn_s"] and last["sync_wait_s"] > first["sync_wait_s"]
+    # an idle loop's ticks and wake waits are no turn's time
+    time.sleep(0.1)
+    idle = eng.stats()
+    time.sleep(0.1)
+    assert eng.stats()["turn_s"] == idle["turn_s"] and eng.stats()["sync_wait_s"] == idle["sync_wait_s"]
+
+
+def test_a_held_lock_is_lock_wait_and_not_sync_wait(traced):
+    import threading
+
+    from ray_tpu.serve.engine import BufferSink
+
+    eng = traced["eng"]
+    holding = threading.Event()
+
+    def hold():
+        with eng._lock:
+            holding.set()
+            time.sleep(0.05)
+
+    holder = threading.Thread(target=hold)
+
+    class Sink(BufferSink):
+        def emit(self, frame):  # engine thread, lock released: the next acquisition of its turn finds it taken
+            super().emit(frame)
+            if not holder.ident:
+                holder.start()
+                assert holding.wait(5)
+
+    before = eng.stats()
+    assert len(eng.submit([5, 7, 9], BUDGET, sink=Sink()).sink.result(timeout=180)) == BUDGET
+    holder.join()
+    after = eng.stats()
+    d = {k: after[k] - before[k] for k in ("turn_s", "sync_wait_s", "lock_wait_s")}
+    assert d["lock_wait_s"] >= 0.03, d
+    assert d["turn_s"] >= d["sync_wait_s"] + d["lock_wait_s"], d  # disjoint: the 50 ms is counted once
+
+
+def test_stats_walks_the_free_list_with_no_lock_held(traced, monkeypatch):
+    import threading
+
+    from ray_tpu.serve.engine import kv_cache
+    from ray_tpu.serve.engine import loop as loop_mod
+
+    eng = traced["eng"]
+    walks = []
+
+    def probing(free):
+        got = []
+
+        def probe():  # another thread can take both locks while the walk runs
+            for lock in (eng._lock, eng.cache._lock):
+                ok = lock.acquire(timeout=2)
+                got.append(ok)
+                if ok:
+                    lock.release()
+
+        t = threading.Thread(target=probe)
+        t.start()
+        t.join()
+        walks.append(got)
+        return kv_cache.fragmentation_of(free)
+
+    def refuse(self):
+        raise AssertionError("stats() walked the allocator's own list")
+
+    monkeypatch.setattr(loop_mod, "fragmentation_of", probing)
+    monkeypatch.setattr(kv_cache.PageAllocator, "fragmentation", refuse)
+    out = []
+    caller = threading.Thread(target=lambda: out.append(eng.stats()))
+    caller.start()
+    caller.join(10)
+    assert out and 0.0 <= out[0]["fragmentation"] <= 1.0
+    assert [True, True] in walks
+
+
+def _fragmentation_by_the_loop(ids):
+    """The walk as it was written before numpy took it: the reference."""
+    if len(ids) <= 1:
+        return 0.0
+    ordered = sorted(ids)
+    longest = run = 1
+    for a, b in zip(ordered, ordered[1:]):
+        run = run + 1 if b == a + 1 else 1
+        longest = max(longest, run)
+    return 1.0 - longest / len(ids)
+
+
+@pytest.mark.parametrize("ids,expected", [
+    ([], 0.0), ([7], 0.0), ([3, 2, 1, 0], 0.0), ([9, 8, 5, 4, 3, 0], 0.5), ([0, 2, 4, 6], 0.75),
+    (list(range(100, 40, -1)) + [7, 5], 1 - 60 / 62), ("random", None),
+])
+def test_fragmentation_of_a_copy_is_the_allocators_own(ids, expected):
+    from ray_tpu.serve.engine.kv_cache import PageAllocator, fragmentation_of
+
+    cases = [ids]
+    if ids == "random":  # the pool of the largest cell, free lists of every density
+        rng = np.random.default_rng(38)
+        cases = [rng.choice(16384, size=n, replace=False).tolist() for n in (2, 3, 100, 5000, 16000, 16384)]
+    for case in cases:
+        want = _fragmentation_by_the_loop(case)
+        assert expected is None or want == pytest.approx(expected)
+        assert fragmentation_of(case) == want  # the same integers divided: equal, not close
+        a = PageAllocator(16384, 4)
+        a._free = sorted(case, reverse=True)
+        assert a.fragmentation() == want
+
+
+def test_the_engine_thread_writes_no_gauge(traced, monkeypatch):
+    """With a connected worker every write of a gauge is a blocking round
+    trip to the head: all ten series a tick come from the publisher thread,
+    none from ``engine-<deployment>``."""
+    import threading
+
+    from ray_tpu._private import worker as worker_mod
+    from ray_tpu.util import metrics
+
+    writes = []
+
+    def store(self, value, tags, mode):
+        kind = (tags or {}).get("kind")
+        writes.append((threading.current_thread().name, self.name + (f":{kind}" if kind else ""), value))
+
+    monkeypatch.setattr(worker_mod, "_require_connected", lambda: None)
+    monkeypatch.setattr(metrics.Metric, "_store", store)
+    eng = traced["eng"]
+    assert _serve(eng) == traced["outs"]
+    deadline = time.monotonic() + 10  # a period is 10 ms: until a tick in which no turn ran
+    while time.monotonic() < deadline and not any(w[1:] == ("ray_tpu_serve_engine_host_share", 0.0) for w in writes[-10:]):
+        time.sleep(0.01)
+    with eng._publish_lock:  # between two ticks
+        monkeypatch.undo()
+    assert writes
+    assert {w[0] for w in writes} == {"gauges-spans"}, {w[0] for w in writes}
+    assert eng._thread.name == "engine-spans" and eng._publisher.name == "gauges-spans"
+    series = {w[1] for w in writes}
+    assert series == {
+        "ray_tpu_serve_engine_slots:active", "ray_tpu_serve_engine_slots:decode", "ray_tpu_serve_engine_slots:prefill",
+        "ray_tpu_serve_engine_slots:total", "ray_tpu_serve_engine_queue_depth", "ray_tpu_serve_engine_kv_pages:used",
+        "ray_tpu_serve_engine_kv_pages:total", "ray_tpu_serve_engine_page_fragmentation", "ray_tpu_serve_engine_host_share",
+        "ray_tpu_serve_engine_tokens_total",
+    }
+    shares = [w[2] for w in writes if w[1] == "ray_tpu_serve_engine_host_share"]
+    assert all(0.0 <= s <= 1.0 for s in shares) and any(s > 0.0 for s in shares) and shares[-1] == 0.0  # idle at the end
+    # the counter's increments add up to every token the engine has generated, the first publish catching up
+    assert sum(w[2] for w in writes if w[1] == "ray_tpu_serve_engine_tokens_total") == eng.stats()["tokens_generated"]
+
+
+def test_the_publisher_ends_with_the_engine_and_threads_do_not_pile_up(traced):
+    import threading
+
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+
+    before = threading.active_count()
+    for _ in range(3):
+        eng = InferenceEngine(traced["llm"], EngineConfig(num_slots=2, page_size=4, max_seq_len=16, prefill_chunk=4), deployment="brief")
+        names = {t.name for t in threading.enumerate()}
+        assert {"engine-brief", "gauges-brief"} <= names
+        eng.shutdown()
+        assert not eng._thread.is_alive() and not eng._publisher.is_alive()
+    assert threading.active_count() == before
+    assert not {"engine-brief", "gauges-brief"} & {t.name for t in threading.enumerate()}
