@@ -12,7 +12,7 @@ from __future__ import annotations
 import importlib
 import random
 import time
-from typing import List, Mapping
+from typing import List, Mapping, Optional
 
 from benchmarks import stats
 
@@ -68,11 +68,16 @@ def _with_probe(base):
     return Probed
 
 
+PROBE_TIMEOUT_S = 60.0
+
+
 class Client:
-    """What a load generator needs of the system: stream or call."""
+    """What a load generator needs of the system: stream or call.  A probe
+    (``method``) that names no timeout waits ``probe_timeout`` seconds."""
 
     def __init__(self, handle):
         self.handle = handle
+        self.probe_timeout = PROBE_TIMEOUT_S
 
     def stream(self, prompt: List[int], budget: int):
         return self.handle.stream_tokens(prompt, max_new_tokens=int(budget), timeout=120.0)
@@ -82,10 +87,10 @@ class Client:
 
         return ray_tpu.get(self.handle.remote({"prompt": prompt, "max_new_tokens": int(budget)}), timeout=300)
 
-    def method(self, name: str, *args, timeout: float = 60.0):
+    def method(self, name: str, *args, timeout: Optional[float] = None):
         import ray_tpu
 
-        return ray_tpu.get(self.handle.method(name).remote(*args), timeout=timeout)
+        return ray_tpu.get(self.handle.method(name).remote(*args), timeout=self.probe_timeout if timeout is None else timeout)
 
 
 def reference_check(cfg: Mapping, seed: int, chips: int) -> dict:
@@ -233,7 +238,90 @@ def deploy_and_warm(cfg: Mapping, seed: int, notes: dict):
 
 
 CANARY_BUDGET = 16
+# ``bench_trace_stop`` writes the profile inside the replica and holds its
+# intake meanwhile: the profiler's cost, not the program's.  EVERY deadline of
+# a traced run that can meet that stall is its untraced value plus this one
+# allowance: a request's drain and, through it, the generator's join of the
+# timeline, and a probe's answer (``Client.probe_timeout``).  The stall itself
+# is ``notes["trace_stop_s"]``: 27-56 s a cell on the v5e, with the Python
+# tracer or without it (PERF.md section 6, PR 43) -- the profiler's own
+# collection of what a 3 s capture holds, so a faster engine lengthens it.
 TRACE_STALL_ALLOWANCE_S = 30.0
+TRACE_STOP_TIMEOUT_S = 300.0  # the stop's own call: one that takes longer than the drain allows shows in the requests
+
+
+class Probes:
+    """The calls a run makes at fixed offsets of its window (``events``), what
+    they brought back, and what that says of the run (``judge``).
+
+    The two ``engine_stats`` snapshots at the window's ends, the canary's two
+    answers under load and (traced) the profiler's start and stop judge the
+    run: one that errs or stays silent is a problem.  ``sample_slots`` (traced,
+    twice a second) feeds a per-layer reader and nothing else: a sample that
+    gets no answer is a sample missing, counted in the notes."""
+
+    def __init__(self, client, canary: List[int], seconds: float, trace_dir: Optional[str] = None, trace_seconds: float = 3.0):
+        self.client, self.canary, self.seconds, self.trace_dir, self.trace_seconds = client, canary, float(seconds), trace_dir, float(trace_seconds)
+        self.capture_start_s = self.seconds / 3.0  # a traced run's capture: from here for ``trace_seconds``, then the stop's stall
+        self.snaps: dict = {}
+        self.answers: dict = {}
+        self.slot_samples: List[float] = []
+        self.samples_missed: List[str] = []
+        self.trace_stop_s: Optional[float] = None
+
+    def snap_start(self):
+        self.snaps["start"] = (time.perf_counter(), self.client.method("engine_stats"))
+
+    def snap_end(self):
+        self.snaps["end"] = (time.perf_counter(), self.client.method("engine_stats"))
+
+    def canary_stream(self):
+        self.answers["loaded_stream"] = [t for frame in self.client.stream(self.canary, CANARY_BUDGET) for t in frame]
+
+    def canary_buffered(self):
+        self.answers["loaded_buffered"] = self.client.call(self.canary, CANARY_BUDGET)
+
+    def sample_slots(self):
+        try:
+            self.slot_samples.append(float(self.client.method("engine_stats")["slots_active"]))
+        except Exception as e:  # noqa: BLE001 -- a sample, not a verdict: whatever kept it away, it is one sample fewer
+            self.samples_missed.append(f"{type(e).__name__}: {e}"[:200])
+
+    def trace_start(self):
+        # no Python tracer: the engine's spans are TraceAnnotations and the dispatch events the runtime's own; it costs the host 0.2-0.6 ms a turn and names gaps by line numbers
+        self.client.method("bench_trace_start", self.trace_dir, 0)
+
+    def trace_stop(self):
+        t = time.perf_counter()
+        self.client.method("bench_trace_stop", timeout=TRACE_STOP_TIMEOUT_S)
+        self.trace_stop_s = time.perf_counter() - t
+
+    def events(self) -> list:
+        s = self.seconds
+        events = [(0.0, self.snap_start), (s, self.snap_end), (s * 0.45, self.canary_stream), (s * 0.55, self.canary_buffered)]
+        if self.trace_dir:
+            events += [(self.capture_start_s, self.trace_start), (self.capture_start_s + self.trace_seconds, self.trace_stop)]
+            events += [(0.25 + 0.5 * k, self.sample_slots) for k in range(int(s * 2))]
+        return events
+
+    def judge(self, timeline_errors: List[str], alone: List[int]):
+        """(problems, notes) of what the probes brought back."""
+        problems, notes = [], {}
+        if timeline_errors:
+            problems.append(f"timeline: {timeline_errors}")
+        for key in ("loaded_stream", "loaded_buffered"):
+            if self.answers.get(key) != alone:
+                problems.append(f"the canary's answer {key} differs from its answer alone: {self.answers.get(key)} vs {alone}")
+        if "start" not in self.snaps or "end" not in self.snaps:
+            problems.append("engine_stats snapshots at the window's ends are missing")
+        if self.trace_dir:
+            notes["trace_stop_s"] = self.trace_stop_s
+            notes["slot_samples_taken"], notes["slot_samples_missed"] = len(self.slot_samples), len(self.samples_missed)
+            if self.samples_missed:
+                notes["slot_samples_missed_first"] = self.samples_missed[0]
+            if self.trace_stop_s is None and not timeline_errors:
+                problems.append("the profiler's stop had not returned when the run ended")
+        return problems, notes
 
 
 def run(ctx) -> dict:
@@ -252,40 +340,14 @@ def run(ctx) -> dict:
 
     dep, client, info, canary, alone, stats_warm = deploy_and_warm(cfg, ctx.seed, notes)
 
-    snaps: dict = {}
-    answers: dict = {}
-    slot_samples: List[float] = []
-
-    def snap_start():
-        snaps["start"] = (time.perf_counter(), client.method("engine_stats"))
-
-    def snap_end():
-        snaps["end"] = (time.perf_counter(), client.method("engine_stats"))
-
-    def canary_stream():
-        answers["loaded_stream"] = [t for frame in client.stream(canary, CANARY_BUDGET) for t in frame]
-
-    def canary_buffered():
-        answers["loaded_buffered"] = client.call(canary, CANARY_BUDGET)
-
-    def sample_slots():
-        slot_samples.append(float(client.method("engine_stats")["slots_active"]))
-
-    events = [(0.0, snap_start), (seconds, snap_end), (seconds * 0.45, canary_stream), (seconds * 0.55, canary_buffered)]
+    probes = Probes(client, canary, seconds, ctx.trace_dir if ctx.trace else None, float(traffic.get("trace_seconds", 3.0)))
     if ctx.trace:
-        trace_len = float(traffic.get("trace_seconds", 3.0))
-        events += [
-            (seconds / 3.0, lambda: client.method("bench_trace_start", ctx.trace_dir, 1)),
-            (seconds / 3.0 + trace_len, lambda: client.method("bench_trace_stop", timeout=300)),
-        ]
-        events += [(0.25 + 0.5 * k, sample_slots) for k in range(int(seconds * 2))]
-
-        # stop_trace writes the file inside the replica and stalls it for
-        # seconds: that is the profiler's cost, not a request's failure
+        client.probe_timeout = PROBE_TIMEOUT_S + TRACE_STALL_ALLOWANCE_S
         traffic = {**traffic, "drain_s": float(traffic.get("drain_s", 10.0)) + TRACE_STALL_ALLOWANCE_S}
 
     loadgen = importlib.import_module(f"benchmarks.loadgen.{traffic['kind']}")
-    res = loadgen.run(client, traffic, ctx.seed, seconds, cfg["vocab_size"], events)
+    ctx.window_opens()
+    res = loadgen.run(client, traffic, ctx.seed, seconds, cfg["vocab_size"], probes.events())
     window_epoch = time.time() - (time.perf_counter() - res["t0"])
 
     stats_end = client.method("engine_stats")
@@ -309,11 +371,12 @@ def run(ctx) -> dict:
         problems.append(f"{len(bad)} of {len(measured)} requests failed, e.g. {[(r['i'], r['error'], r['tokens'], r['budget']) for r in bad[:3]]}")
     if pre_bad:
         problems.append(f"{len(pre_bad)} pre-roll requests failed")
-    if res["timeline_errors"]:
-        problems.append(f"timeline: {res['timeline_errors']}")
-    for key in ("loaded_stream", "loaded_buffered"):
-        if answers.get(key) != alone:
-            problems.append(f"the canary's answer {key} differs from its answer alone: {answers.get(key)} vs {alone}")
+    probe_problems, probe_notes = probes.judge(res["timeline_errors"], alone)
+    problems += probe_problems
+    notes.update(probe_notes)
+    # when the last request came back, from the window's start: what a traced run's drain (drain_s + the allowance) has to hold
+    notes["last_request_done_s"] = max((r["done"] for r in recs if r["done"] is not None), default=None)
+    notes["drain_limit_s"] = seconds + float(traffic["drain_s"]) if "drain_s" in traffic else None
     if len(alone) != CANARY_BUDGET:
         problems.append(f"the canary returned {len(alone)} tokens, not {CANARY_BUDGET}")
     for name, st in (("warm-up", stats_warm), ("end", stats_end)):
@@ -339,11 +402,11 @@ def run(ctx) -> dict:
     e2e["serve_tokens_per_s"] = (sum(r["prompt_len"] + r["tokens"] for r in done_in) / seconds, "tokens/s")
 
     lag = [(r["sent"] - r["due"]) * 1e3 for r in measured if r["due"] is not None and r["sent"] is not None]
-    s0, s1 = snaps.get("start"), snaps.get("end")
+    s0, s1 = probes.snaps.get("start"), probes.snaps.get("end")
     counters = {
         "window_s": seconds,
         "loadgen_lag_ms": lag,
-        "slot_samples": slot_samples,
+        "slot_samples": probes.slot_samples,
         "requests_measured": len(measured),
         "requests_completed_in_window": len(done_in),
         "ttft_samples": len(ttft),
@@ -351,12 +414,12 @@ def run(ctx) -> dict:
         "num_slots": int(eng["num_slots"]),
         "max_seq_len": int(eng["max_seq_len"]),
     }
+    if ctx.trace:
+        counters["capture_start_s"] = probes.capture_start_s  # what was due well before it met neither the profiler nor its stall (``layer_metrics/_ttft.py``)
     if s0 and s1:
         counters["stats_interval_s"] = s1[0] - s0[0]
         counters["iterations"] = s1[1]["iterations"] - s0[1]["iterations"]
         counters["tokens_generated"] = s1[1]["tokens_generated"] - s0[1]["tokens_generated"]
-    else:
-        problems.append("engine_stats snapshots at the window's ends are missing")
     # live context of the decode fleet, for the bytes a decode step must read:
     # mean tokens held by the requests in flight, from what the client saw
     notes.update(info={k: info[k] for k in ("platform", "params_b", "tp")}, stats_end=stats_end, peak_bytes_in_use=device["peak_bytes_in_use"])
@@ -371,5 +434,7 @@ def run(ctx) -> dict:
         "counters": counters,
         "records": recs,
         "notes": notes,
-        "host_thread": r"loop\.py:\d+ _iteration",
+        # idle gaps are named on the thread that holds the engine's turns, by the innermost ``engine/*`` span there (the program's own vocabulary: no line numbers)
+        "host_thread": r"^engine/iteration$",
+        "gap_names": r"^engine/",
     }

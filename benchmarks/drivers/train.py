@@ -86,6 +86,8 @@ def train_loop(config: Mapping) -> None:
     losses = []
     wait_s, steps, trace_call_s = 0.0, 0, 0.0
     compiles_before = len(compiles)
+    if config.get("window_mark"):
+        open(config["window_mark"], "w").close()  # run.py: from here on a failure is never retried
     t_epoch = time.time()
     t0 = time.perf_counter()
     while True:
@@ -229,6 +231,7 @@ def run(ctx) -> dict:
             "seed": ctx.seed,
             "seconds": ctx.seconds,
             "trace_dir": ctx.trace_dir if ctx.trace else None,
+            "window_mark": ctx.window_mark,
         },
         scaling_config=ScalingConfig(num_workers=1, use_tpu=True, tpu_chips_per_worker=chips),
     )

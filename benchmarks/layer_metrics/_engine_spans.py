@@ -53,18 +53,7 @@ def mean_ms(values):
     return 1e-6 * sum(values) / len(values) if values else None
 
 
-def intersect(a, b):
-    """Intervals common to two sorted lists of disjoint ``(start, end)``."""
-    out, i, j = [], 0, 0
-    while i < len(a) and j < len(b):
-        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
-        if hi > lo:
-            out.append((lo, hi))
-        if a[i][1] < b[j][1]:
-            i += 1
-        else:
-            j += 1
-    return out
+intersect = trace_reduce.intersect_intervals  # intervals common to two sorted lists of disjoint (start, end)
 
 
 def total(intervals) -> float:

@@ -13,6 +13,14 @@ The last line of standard output is the result: one JSON object with
 ``correct``, ``attempted``, ``failed``, ``metrics``, ``device`` and, in a
 traced run, ``breakdown``.  Exit code 2 and no result where the host lacks
 the chips, or where the program (``ray_tpu``) is not beside ``benchmarks/``.
+
+``correct: false`` and a non-zero exit are statements about the program under
+test (benchmarks/README.md, "What makes a run incorrect, and what never
+does").  So a run first waits until no other process holds a chip
+(``cluster.wait_for_chips``), and a failure BEFORE its measured window opens is
+tried once more in a fresh cluster (``may_retry``); the first attempt's
+traceback and worker-log tails are kept in ``out/<run>/attempt1.err`` and the
+result line says ``"attempts": 2``.
 """
 
 from __future__ import annotations
@@ -58,6 +66,26 @@ class Context:
     out_dir: str
     trace_dir: str
 
+    @property
+    def window_mark(self) -> str:
+        """A file that exists once the measured window has opened (a training
+        window opens inside the worker, which is given this path)."""
+        return os.path.join(self.out_dir, "window_opened") if self.out_dir else ""
+
+    def window_opens(self) -> None:
+        """Called by a driver as the last thing before its window (pre-roll
+        included): from here on a failure is the run's, and is never retried."""
+        if self.window_mark:
+            open(self.window_mark, "w").close()
+
+
+def may_retry(attempt: int, window_opened: bool) -> bool:
+    """Whether a run that RAISED may be tried again in a fresh cluster: once,
+    and only if its measured window had not opened (cluster start, worker
+    spawn, ``jax.devices()``, the reference-check actor, compile, warm-up).
+    A run that came back with a result, ``correct`` or not, never gets here."""
+    return attempt == 1 and not window_opened
+
 
 def load_json(path: str) -> Dict[str, Any]:
     with open(path) as f:
@@ -87,6 +115,42 @@ def load_cell(workload: str, tiny: bool):
     if tiny:
         config, traffic = merge_tiny(config), merge_tiny(traffic)
     return bench, cell, config, traffic
+
+
+def measure(ctx: Context, driver, cluster, run_id: str):
+    """``driver.run(ctx)`` in a cluster of its own -> (what it returned,
+    attempts, the first attempt's error in one line or None, what each wait
+    for the chips found), or None where it raised: the traceback and the
+    worker-log tails are then on standard error.  Before each attempt: wait
+    until no other process holds a chip (inside ``setup_s``: it is set-up).
+    A first attempt that raised before its window opened (``may_retry``)
+    leaves all it said in ``<out_dir>/attempt1.err`` and is tried once more."""
+    chips = int(ctx.cell["chips"])
+    attempt, first_error, waits = 0, None, []
+    while True:
+        attempt += 1
+        if os.path.exists(ctx.window_mark):
+            os.remove(ctx.window_mark)
+        if not ctx.tiny:
+            waits.append(cluster.wait_for_chips())
+        session = ""
+        try:
+            cluster.start(chips, ctx.tiny)
+            session = cluster.session_dir()
+            raw = driver.run(ctx)
+            cluster.assert_driver_off_jax()
+            return raw, attempt, first_error, waits
+        except BaseException as e:  # noqa: BLE001 -- any failure: say why, print no result, exit non-zero
+            said = traceback.format_exc() + cluster.log_tails(session)
+            print(said, file=sys.stderr, end="", flush=True)
+            if isinstance(e, (KeyboardInterrupt, SystemExit)) or not may_retry(attempt, os.path.exists(ctx.window_mark)):
+                return None
+            first_error = (f"{type(e).__name__}: {e}".splitlines() or [type(e).__name__])[0][:300]
+            with open(os.path.join(ctx.out_dir, "attempt1.err"), "w") as f:
+                f.write(f"attempt 1 of {run_id} failed before its window opened; the wait for the chips found: {json.dumps(waits[-1:])}\n{said}")
+            print(f"run.py: attempt 1 failed before the window opened ({first_error}); trying once more in a fresh cluster", file=sys.stderr, flush=True)
+        finally:
+            cluster.stop()
 
 
 def metrics_of(bench: Dict[str, Any], section: str, cell: str):
@@ -148,23 +212,15 @@ def main(argv=None) -> int:
 
         shutil.rmtree(trace_dir)
     os.makedirs(out_dir, exist_ok=True)
+    if os.path.exists(os.path.join(out_dir, "attempt1.err")):
+        os.remove(os.path.join(out_dir, "attempt1.err"))  # an earlier run's of the same name
     ctx = Context(cell, config, traffic, args.seed, seconds, bool(args.trace), args.tiny, out_dir, trace_dir)
     driver = importlib.import_module(f"benchmarks.drivers.{config['kind']}")
 
-    import ray_tpu
-
-    session = ""
-    try:
-        cluster.start(int(cell["chips"]), args.tiny)
-        session = cluster.session_dir()
-        raw = driver.run(ctx)
-        cluster.assert_driver_off_jax()
-    except BaseException:  # noqa: BLE001 -- any failure: say why, print no result, exit non-zero
-        traceback.print_exc()
-        cluster.dump_logs(session)
+    measured = measure(ctx, driver, cluster, run_id)
+    if measured is None:
         return 1
-    finally:
-        ray_tpu.shutdown()
+    raw, attempt, first_error, waits = measured
 
     if not args.tiny and raw["device"]["platform"] != "tpu":
         print(f"run.py: the cell ran on platform {raw['device']['platform']!r}, not tpu", file=sys.stderr)
@@ -174,7 +230,11 @@ def main(argv=None) -> int:
     e2e = dict(raw["e2e"])
     e2e["setup_s"] = (setup_s, "s")
     device = dict(raw["device"])
-    result: Dict[str, Any] = {"correct": bool(raw["correct"]), "attempted": raw["attempted"], "failed": raw["failed"]}
+    result: Dict[str, Any] = {"correct": bool(raw["correct"]), "attempted": raw["attempted"], "failed": raw["failed"],
+                              "attempts": attempt, "chips_wait_s": sum(w["chips_wait_s"] for w in waits)}
+    if first_error:
+        result["first_error"] = first_error
+    raw["notes"].update(attempts=attempt, first_error=first_error, chips_wait_s=result["chips_wait_s"], chip_holders=[w["chip_holders"] for w in waits])
     detail = {"run": run_id, "seconds": seconds, "cache_dir": cache_dir, "problems": raw["problems"], "notes": raw["notes"],
               "e2e_all": {k: v[0] for k, v in e2e.items()}, "counters": {k: v for k, v in raw["counters"].items() if not isinstance(v, list)}}
 
@@ -183,7 +243,7 @@ def main(argv=None) -> int:
 
         path = trace_reduce.find_xplane(trace_dir)
         planes = trace_reduce.load_xplane(path) if path else []
-        reduced = trace_reduce.reduce_trace(planes, host_thread=raw.get("host_thread"), cpu_rehearsal=args.tiny) if path else None
+        reduced = trace_reduce.reduce_trace(planes, host_thread=raw.get("host_thread"), name_by=raw.get("gap_names"), cpu_rehearsal=args.tiny) if path else None
         if reduced is None:
             print(f"run.py: the traced run left no device trace under {trace_dir}: no result", file=sys.stderr)
             return 1

@@ -161,7 +161,7 @@ def test_the_tiny_traced_rehearsal_of_the_cell():
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     listed = {m["name"] for m in bench["per_layer"] if CELL in m.get("workloads", [CELL])}
-    assert len(listed) == 12 and all(n.endswith(".jamba2-sat") for n in listed)
+    assert len(listed) >= 12 and all(n.endswith(".jamba2-sat") for n in listed)
     assert all(m["moves"] == "serve_tokens_per_s" and m["workloads"] == [CELL] for m in bench["per_layer"] if m["name"] in listed)
     # the device-program and kernel readers find no XLA Modules line and no Pallas call on the CPU and are left out there, as in the older cells
     on_cpu = {n for n in listed if not n.startswith(("decode_program_ms", "prefill_program_ms", "ssm_"))}

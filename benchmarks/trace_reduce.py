@@ -133,22 +133,36 @@ def _gaps(busy: Sequence[Tuple[float, float]], lo: float, hi: float) -> List[Tup
     return gaps
 
 
-def _overlap(s, e, s2, e2) -> float:
-    return max(0.0, min(e, e2) - max(s, s2))
+def intersect_intervals(a: Sequence[Tuple[float, float]], b: Sequence[Tuple[float, float]]) -> List[Tuple[float, float]]:
+    """Intervals common to two sorted lists of disjoint ``(start, end)``: one
+    pass with a pointer into each."""
+    out, i, j = [], 0, 0
+    while i < len(a) and j < len(b):
+        lo, hi = max(a[i][0], b[j][0]), min(a[i][1], b[j][1])
+        if hi > lo:
+            out.append((lo, hi))
+        if a[i][1] < b[j][1]:
+            i += 1
+        else:
+            j += 1
+    return out
 
 
-def _host_spans(planes: Sequence[Plane], min_ns: float = 5_000.0):
+def _host_spans(planes: Sequence[Plane], min_ns: float = 5_000.0, name_by: Optional[str] = None):
     """Host events per thread line, keyed ``<plane>|<line>|<index>``, as arrays
-    ``(names, starts, ends)``.  Events shorter than ``min_ns`` explain no gap
-    worth naming and are dropped (the Python tracer writes very many)."""
+    ``(names, starts, ends, is a bench/ span)``.  Events shorter than
+    ``min_ns`` explain no gap worth naming and are dropped (a Python tracer
+    writes very many); with ``name_by`` (a pattern) only events whose name
+    matches it, and the benchmark's own spans, are kept."""
     import numpy as np
 
+    rx = re.compile(name_by) if name_by else None
     out = {}
     for pname, lines in planes:
         if DEVICE_PLANE.match(pname):
             continue
         for i, (lname, events) in enumerate(lines):
-            evs = [ev for ev in events if ev[2] >= min_ns]
+            evs = [ev for ev in events if ev[2] >= min_ns and (rx is None or rx.search(ev[0]) or ev[0].startswith(BENCH_SPAN))]
             if evs:
                 starts = np.array([ev[1] for ev in evs])
                 # several threads share a line name ("python3"): the index keeps them apart
@@ -197,11 +211,14 @@ def reduce_trace(
     planes: Sequence[Plane],
     *,
     host_thread: Optional[str] = None,
+    name_by: Optional[str] = None,
     top: int = 10,
     cpu_rehearsal: bool = False,
 ) -> Optional[dict]:
     """Busy and idle time, per-operation and per-program durations, and the
-    longest idle gaps named by the host's activity.  Returns None when the
+    longest idle gaps named by the host's activity: by the innermost event on
+    the threads that hold an event matching ``host_thread``, of the events
+    whose name matches ``name_by`` (all, if not given).  Returns None when the
     trace holds no device operation: the caller must then fail, not report."""
     devices = [(n, ls) for n, ls in planes if DEVICE_PLANE.match(n)]
     if cpu_rehearsal and not devices:
@@ -245,7 +262,7 @@ def reduce_trace(
     _, ops0, mods0, whole0 = per_dev[0]
     busy0 = union_intervals((s, s + d) for _, s, d in ops0)
     gaps = _gaps(busy0, lo, hi)
-    host = _host_spans(planes)
+    host = _host_spans(planes, name_by=name_by)
     rx = re.compile(host_thread) if host_thread else None
     marked = {k for k, v in host.items() if rx and any(rx.search(n) for n in v[0])}
     named: Dict[str, float] = {}
@@ -294,7 +311,10 @@ def exposed_seconds(reduced: dict, is_collective) -> Tuple[float, float]:
     coll = union_intervals((s, s + d) for name, s, d in ops if is_collective(name))
     other = union_intervals((s, s + d) for name, s, d in ops if not is_collective(name))
     total = _total(coll)
-    covered = sum(_overlap(s, e, s2, e2) for s, e in coll for s2, e2 in other)
+    # both are sorted unions, so the common part is one pass; it comes out in
+    # the order the double loop over (collective, other) added its overlaps up,
+    # which keeps the sum what it was to the last bit
+    covered = _total(intersect_intervals(coll, other))
     return total * 1e-9, (total - covered) * 1e-9
 
 
