@@ -337,18 +337,26 @@ class LlamaModel:
         so XLA writes it in place."""
         return buf.at[li, wpage, woff].set(vals.astype(buf.dtype), mode="drop")
 
-    def _paged_attend(self, q, kp, vp, li: int, tables, q_pos, q_valid, n_blocks):
+    def _paged_attend(self, q, kp, vp, li: int, tables, q_pos, q_valid, n_blocks, *, value_dim=None, scale=None):
         """Causal attention of q [B, Q, H, D] over layer ``li`` of the pool,
         each row b through its page table cut into blocks, ``tables[b]``
         [n, pages a block] (-1 = no page); a query at logical position
         ``q_pos[b, q]`` sees positions 0..q_pos, ``q_valid`` [B, Q] masks
         idle rows (their result is finite and unused).  Walks blocks
-        0..n_blocks-1 (a traced scalar, at least 1).  Returns [B, Q, H*D]."""
+        0..n_blocks-1 (a traced scalar, at least 1).  Returns [B, Q, H*D].
+
+        What a model whose cache is not K and V heads of one width says
+        (``models/deepseek_v3.py``: one latent row a position): ``vp`` None --
+        a position's values are the first ``value_dim`` of its key row, so
+        ONE gather of a block serves scores and values; ``scale`` in place
+        of D**-0.5.  The result is then [B, Q, H*value_dim]."""
         cfg = self.config
         cd = cfg.compute_dtype
         B, Q, H, D = q.shape
         KV = cfg.n_kv_heads
         G = H // KV
+        Dv = D if value_dim is None else value_dim
+        qk_scale = D**-0.5 if scale is None else scale
         NP, PS = kp.shape[1], kp.shape[2]
         blk = tables.shape[2] * PS
         q = q.reshape(B, Q, KV, G, D)
@@ -359,11 +367,11 @@ class LlamaModel:
             tab = jax.lax.dynamic_index_in_dim(tables, i, axis=1, keepdims=False)  # [B, pages]
             phys = jnp.clip(tab, 0, NP - 1)
             keys = kp[li, phys].reshape(B, blk, KV, D)
-            vals = vp[li, phys].reshape(B, blk, KV, D)
+            vals = keys[..., :Dv] if vp is None else vp[li, phys].reshape(B, blk, KV, D)
             seen = (i * blk + offs)[None, None, :] <= q_pos[:, :, None]  # [B, Q, blk]
             valid = seen & q_valid[:, :, None] & jnp.repeat(tab >= 0, PS, axis=1)[:, None, :]
             s = jnp.einsum("bqkgd,btkd->bkgqt", q, keys, preferred_element_type=jnp.float32)
-            s = jnp.where(valid[:, None, None], s * (D**-0.5), -1e30)
+            s = jnp.where(valid[:, None, None], s * qk_scale, -1e30)
             m_new = jnp.maximum(m, s.max(-1))
             # a block masked for the whole row: m_new == m, so scale is
             # exp(0) = 1 and every p is exp(-1e30 - m) = 0 -- nothing moves
@@ -379,12 +387,12 @@ class LlamaModel:
         init = (
             jnp.full(stat, -1e30, jnp.float32),
             jnp.zeros(stat, jnp.float32),
-            jnp.zeros(stat + (D,), jnp.float32),
+            jnp.zeros(stat + (Dv,), jnp.float32),
         )
         _, l, acc = jax.lax.fori_loop(0, n_blocks, block, init)
         # an idle row kept m = -1e30, so its p were exp(0) = 1: l > 0, finite
-        out = (acc / l[..., None]).astype(cd)  # [B, KV, G, Q, D]
-        return out.transpose(0, 3, 1, 2, 4).reshape(B, Q, H * D)
+        out = (acc / l[..., None]).astype(cd)  # [B, KV, G, Q, Dv]
+        return out.transpose(0, 3, 1, 2, 4).reshape(B, Q, H * Dv)
 
     def _paged_layer(self, x, lp, li, pages, wpage, woff, tables, q_pos, q_valid, n_blocks):
         """One transformer layer over paged KV: write this step's K/V into

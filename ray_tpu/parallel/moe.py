@@ -1,7 +1,9 @@
 """Sparse-expert FFNs.  Two layers live here: the top-1 switch layer with
 all-to-all dispatch over the `ep` axis described next (``moe_ffn``, training,
 ``models/gpt2.py``'s ``moe_experts``), and below it an exact dropless top-k
-layer of gated experts (``dropless_moe_ffn``, ``models/llama.py``: OLMoE).
+layer of gated experts (``dropless_moe_ffn``: ``models/llama.py`` OLMoE,
+``qwen3_next.py``, ``deepseek_v3.py``), its router a softmax or a sigmoid
+with a selection bias.
 
 Expert parallelism: a capability absent from the reference (SURVEY §2.4 "Expert parallel
 (EP/MoE): absent") — built the TPU way: experts shard over the `ep` mesh
@@ -116,6 +118,22 @@ def route(h: jax.Array, router_w: jax.Array, top_k: int):
     return lax.top_k(probs, top_k)
 
 
+def route_sigmoid(h: jax.Array, router_w: jax.Array, top_k: int, bias=None):
+    """``route`` for DeepSeek-V3's family: the score of an expert is the
+    sigmoid of its logit alone, float32 as there.  ``bias`` [X], a selection
+    bias (``e_score_correction_bias``), is added to the scores for the CHOICE
+    alone: the weights returned are the scores themselves, not divided by
+    their sum."""
+    logits = jnp.dot(
+        h.astype(jnp.float32), router_w.astype(jnp.float32), precision=lax.Precision.HIGHEST
+    )
+    scores = jax.nn.sigmoid(logits)
+    if bias is None:
+        return lax.top_k(scores, top_k)
+    _, chosen = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    return jnp.take_along_axis(scores, chosen, axis=-1), chosen
+
+
 def dropless_moe_ffn(
     h: jax.Array,  # [T, E]
     router_w: jax.Array,  # [E, X]
@@ -126,6 +144,9 @@ def dropless_moe_ffn(
     top_k: int,
     renormalize: bool = False,
     expert_offset: int = 0,
+    scoring: str = "softmax",
+    bias=None,
+    scale: float = 1.0,
 ) -> Tuple[jax.Array, jax.Array]:
     """Exact top-k routed SwiGLU experts: every row goes through all
     ``top_k`` of its experts whatever the other rows chose -- no capacity,
@@ -134,12 +155,15 @@ def dropless_moe_ffn(
     The layer is told which experts it HOLDS: the router scores all X
     experts of ``router_w``, and ``w_gate``/``w_up``/``w_down`` are those of
     experts ``expert_offset .. expert_offset + w_gate.shape[0] - 1``, all of
-    them (OLMoE) or one device's share of an expert-parallel deployment
-    (Qwen3-Next: 128 of 512).  The result is the held experts' part of the
-    sum; the shares of all holders add up to the whole layer, and a row none
-    of whose choices is held here gets zero.  ``renormalize`` divides a row's
-    ``top_k`` weights by their sum (``norm_topk_prob``: OLMoE false,
-    Qwen3-Next true).
+    them (OLMoE, Moonlight) or one device's share of an expert-parallel
+    deployment (Qwen3-Next: 128 of 512).  The result is the held experts'
+    part of the sum; the shares of all holders add up to the whole layer, and
+    a row none of whose choices is held here gets zero.  ``scoring`` says
+    which router scores them, ``route`` ("softmax") or ``route_sigmoid``
+    ("sigmoid", with its optional selection ``bias``); ``renormalize``
+    divides a row's ``top_k`` weights by their sum (``norm_topk_prob``: OLMoE
+    false, Qwen3-Next and Moonlight true) and ``scale`` multiplies them after
+    that (``routed_scaling_factor``).  A shared expert is the model's to add.
 
     Computed as a masked contraction over ALL held experts: every one's
     SwiGLU runs on every row, and the router's weight (zero for an expert
@@ -160,9 +184,18 @@ def dropless_moe_ffn(
     cd = h.dtype
     n_experts, held = router_w.shape[-1], w_gate.shape[0]
     with jax.named_scope("router"):
-        weights, chosen = route(h, router_w, top_k)
+        if scoring == "sigmoid":
+            weights, chosen = route_sigmoid(h, router_w, top_k, bias)
+        elif scoring == "softmax" and bias is None:
+            weights, chosen = route(h, router_w, top_k)
+        else:
+            raise ValueError(f"scoring={scoring!r} with bias={bias is not None}: softmax, or sigmoid with an optional selection bias")
         if renormalize:  # over the row's top_k, held here or not
-            weights = weights / weights.sum(-1, keepdims=True)
+            total = weights.sum(-1, keepdims=True)
+            # sigmoid scores can all underflow to zero; a softmax's top_k cannot (the published codes differ here too)
+            weights = weights / (total + 1e-20 if scoring == "sigmoid" else total)
+        if scale != 1.0:
+            weights = weights * scale
         # [T, X]: a row's weight for each expert, zero where not chosen
         dense_w = (jax.nn.one_hot(chosen, n_experts, dtype=jnp.float32) * weights[..., None]).sum(-2)
         if held != n_experts:

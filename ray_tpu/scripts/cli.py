@@ -207,6 +207,7 @@ def _print_engine_gauges(engine: dict) -> None:
             f"queue={gauges.get('queue_depth', 0):.0f} "
             f"frag={gauges.get('page_fragmentation', 0):.2f} "
             f"host={gauges.get('host_share', 0):.2f} "
+            f"cache={gauges.get('cache_bytes_per_position', 0):.0f}B/pos "
             f"tokens={gauges.get('tokens_total', 0):.0f}"
         )
 

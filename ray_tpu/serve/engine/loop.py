@@ -209,6 +209,11 @@ class InferenceEngine:
         # began a sequence and so reset their slot's state
         self._state_bytes = sum(int(a.nbytes) for a, role in zip(self._pages, self._pool_roles) if role == "state")
         self.state_resets = 0
+        # what the cache keeps a position, over all layers: the bytes of the
+        # pool's "pages" members over the positions they hold (a model's K and
+        # V heads, or one latent row)
+        paged = sum(int(getattr(a, "nbytes", 0)) for a, role in zip(self._pages, self._pool_roles) if role == "pages")
+        self._cache_bytes_per_position = paged / (cfg.pool_pages() * cfg.page_size)
         self._tokens_reported = 0
         self._published = (0.0, 0.0)  # turn_s, sync_wait_s as of the last publish
         self.iterations = 0
@@ -243,6 +248,9 @@ class InferenceEngine:
         self._ctx_blocks_per_call = -(-cfg.pages_per_slot // block_pages)
         self.ctx_blocks_walked = 0
         self.ctx_blocks_full = 0
+        # positions held by the rows of each decode step, summed over steps:
+        # what a step's attention has to read of the cache
+        self.ctx_positions_live = 0
         # the gauges' publisher: every write is a blocking round trip to
         # the head, so none of them is made on the engine thread
         self._publish_lock = named_lock("InferenceEngine._publish_lock")
@@ -561,6 +569,7 @@ class InferenceEngine:
                 join_slot, join_token = joined[0].slot, joined[1]
             tables = self.cache.tables.copy()
             self._note_walk(int(positions.max()))
+            self.ctx_positions_live += int(positions.sum()) + len(fleet)  # a row at position p holds p + 1
         with span("engine/dispatch"):
             nxt, self._pages = self._programs["decode"](
                 self.llm.params,
@@ -723,6 +732,8 @@ class InferenceEngine:
         out["rows_discarded"] = float(self.rows_discarded)
         out["ctx_blocks_walked"] = float(self.ctx_blocks_walked)
         out["ctx_blocks_full"] = float(self.ctx_blocks_full)
+        out["ctx_positions_live"] = float(self.ctx_positions_live)
+        out["cache_bytes_per_position"] = float(self._cache_bytes_per_position)
         out.update({f"compile_{k}": v for k, v in self.compile_stats().items()})
         load = self._moe_load
         if load is not None:  # as of the last gauge tick (gauge_period_s)
@@ -853,6 +864,11 @@ class InferenceEngine:
                         "Free-list fragmentation of the KV page pool (0=contiguous)",
                         tag_keys=("deployment",),
                     ),
+                    "cache": Gauge(
+                        "ray_tpu_serve_engine_cache_bytes_per_position",
+                        "Bytes the page pool keeps a cached position, all layers (K and V heads, or one latent row)",
+                        tag_keys=("deployment",),
+                    ),
                     "host": Gauge(
                         "ray_tpu_serve_engine_host_share",
                         "Share of the engine thread's turns not spent waiting for the device (1=host-bound)",
@@ -865,6 +881,8 @@ class InferenceEngine:
                     tag_keys=("deployment",),
                 ),
             )
+            # fixed when the pool was made: written once, not a round trip a period
+            self._gauges[0]["cache"].set(self._cache_bytes_per_position, {"deployment": self.deployment})
         return self._gauges
 
     # ------------------------------------------------------------ teardown

@@ -39,7 +39,7 @@ def topology_devices():
 
 def load_config(name: str, n_layers: int):
     """(the program's config at the file's widths and ``n_layers``, the file's engine geometry)."""
-    from benchmarks.drivers import serve, serve_jamba, serve_moe, serve_qwen3_next
+    from benchmarks.drivers import serve, serve_jamba, serve_mla_moe, serve_moe, serve_qwen3_next
 
     with open(os.path.join(ROOT, "benchmarks", "configs", f"{name}.json")) as f:
         cfg = json.load(f)
@@ -47,7 +47,8 @@ def load_config(name: str, n_layers: int):
         lcfg = serve_jamba.reference_config(cfg)
         assert lcfg.n_layers == n_layers, (lcfg.n_layers, n_layers)
         return lcfg, cfg["engine"]
-    build = {"serve": serve.llama_config, "serve_moe": serve_moe.moe_config, "serve_qwen3_next": serve_qwen3_next.hybrid_config}[cfg["kind"]]
+    build = {"serve": serve.llama_config, "serve_moe": serve_moe.moe_config, "serve_qwen3_next": serve_qwen3_next.hybrid_config,
+             "serve_mla_moe": serve_mla_moe.mla_config}[cfg["kind"]]
     return dataclasses.replace(build(cfg), n_layers=n_layers), cfg["engine"]
 
 
@@ -63,7 +64,7 @@ def compile_programs(lcfg, engine: dict, devices) -> Dict[str, Tuple[str, float]
     llm = ShardedLLM(lcfg, devices=devices[:1], init="abstract")
     slots, page, chunk = int(engine["num_slots"]), int(engine["page_size"]), int(engine["prefill_chunk"])
     per_slot = int(engine["max_seq_len"]) // page
-    programs = llm.engine_programs(num_pages=slots * per_slot, page_size=page, num_slots=slots)
+    programs = llm.engine_programs(num_pages=int(engine.get("num_pages") or slots * per_slot), page_size=page, num_slots=slots)
     repl = jax.sharding.NamedSharding(llm.mesh, jax.sharding.PartitionSpec())
     sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype, sharding=repl)  # noqa: E731
     pool = tuple(sds(a.shape, a.dtype) for a in jax.eval_shape(programs["init"]))

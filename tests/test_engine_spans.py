@@ -413,8 +413,10 @@ def test_the_engine_thread_writes_no_gauge(traced, monkeypatch):
         "ray_tpu_serve_engine_slots:active", "ray_tpu_serve_engine_slots:decode", "ray_tpu_serve_engine_slots:prefill",
         "ray_tpu_serve_engine_slots:total", "ray_tpu_serve_engine_queue_depth", "ray_tpu_serve_engine_kv_pages:used",
         "ray_tpu_serve_engine_kv_pages:total", "ray_tpu_serve_engine_page_fragmentation", "ray_tpu_serve_engine_host_share",
-        "ray_tpu_serve_engine_tokens_total",
+        "ray_tpu_serve_engine_tokens_total", "ray_tpu_serve_engine_cache_bytes_per_position",
     }
+    # fixed when the pool was made: written once, when the gauges are made, not a round trip a period
+    assert [w[2] for w in writes if w[1] == "ray_tpu_serve_engine_cache_bytes_per_position"] == [eng.stats()["cache_bytes_per_position"]]
     shares = [w[2] for w in writes if w[1] == "ray_tpu_serve_engine_host_share"]
     assert all(0.0 <= s <= 1.0 for s in shares) and any(s > 0.0 for s in shares) and shares[-1] == 0.0  # idle at the end
     # the counter's increments add up to every token the engine has generated, the first publish catching up

@@ -122,6 +122,40 @@ def test_v5e_jamba_programs_copy_no_weight_and_hold_no_expanded_state(monkeypatc
         assert not big, (prog, sorted(big))
 
 
+def test_v5e_moonlight_programs_keep_the_cache_latent():
+    """Moonlight-16B-A3B's two programs at the published widths over the
+    reference check's three layers (the dense one, two of experts) and the
+    cell's own pool: no weight-sized copy; the pool in ONE layout, row-major,
+    from the entry to the result (at 576 wide the device's own choice puts the
+    page axis minor-most and both programs copy the whole pool to row-major
+    and back: ``DeepseekV3Config.cache_row_dim``); and no array as large as
+    one walked block's per-head keys but the weights, the pool and the
+    logits: the context's K and V are never formed."""
+    import numpy as np
+
+    devices = _v5e()
+    lcfg, engine = _aot_v5e.load_config("moonlight-16b-a3b-l8", 3)
+    assert (lcfg.latent_dim, lcfg.cache_row_dim, lcfg.n_heads, lcfg.first_k_dense, lcfg.n_experts) == (576, 640, 16, 1, 64)
+    compiled = _aot_v5e.compile_programs(lcfg, engine, devices)
+    model = lcfg.build_model()
+    slots, chunk = int(engine["num_slots"]), int(engine["prefill_chunk"])
+    pool = jax.eval_shape(lambda: model.init_pages(int(engine["num_pages"]), int(engine["page_size"]), slots))
+    assert [tuple(a.shape) for a in pool] == [(3, 18432, 16, 640), (64,)]
+    allowed = {tuple(pool[0].shape)}
+    for w in jax.tree.leaves(jax.eval_shape(model.init, jax.random.PRNGKey(0))):  # a stack, one layer of it, and that layer as a stack of one
+        allowed |= {tuple(w.shape), tuple(w.shape[1:]), (1, *w.shape[1:])}
+    pool_text = "bf16[3,18432,16,640]"
+    for prog, rows in (("decode", slots), ("prefill", chunk)):
+        hlo, _ = compiled[prog]
+        assert _aot_v5e.weight_relayouts(hlo) == [], prog
+        layouts = {hlo[i + len(pool_text) : hlo.index("}", i) + 1] for i in range(len(hlo)) if hlo.startswith(pool_text + "{", i)}
+        assert layouts == {"{3,2,1,0:T(8,128)(2,1)}"}, (prog, layouts)
+        block_keys = slots * 256 * lcfg.n_heads * lcfg.qk_nope_head_dim  # one walked block of the decode step, expanded
+        logits = {(rows, lcfg.padded_vocab), (1, rows, lcfg.padded_vocab)}
+        big = {(dt, dims) for dt, dims in _aot_v5e.array_shapes(hlo) if int(np.prod(dims)) >= block_keys and dims not in allowed | logits}
+        assert not big, (prog, sorted(big))
+
+
 def test_the_relayout_reader_sees_a_copy_where_there_is_one():
     hlo = """HloModule m
 %fused_computation.1 (p: bf16[16,4096,1024]) -> bf16[1024,4096] {
