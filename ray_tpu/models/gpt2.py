@@ -12,8 +12,11 @@ port of any torch modeling code:
   cleanly onto the 128×128 systolic array.
 - sharding is declared, not wired: `param_pspecs()` returns a PartitionSpec
   pytree over the standard mesh axes (tp shards attention heads / mlp
-  hidden / vocab; fsdp shards the stacked layer dim; dp replicates), so the
-  same model runs single-chip or on any Mesh via pjit with no code change.
+  hidden / vocab; fsdp shards each layer's matrices on the dim tp leaves
+  whole, never the stacked dim the scan walks; dp replicates), so the same
+  model runs single-chip or on any Mesh via pjit with no code change.  The
+  one collective the model states itself is fsdp's gather of a layer, inside
+  the checkpointed layer (`backbone`).
 - sequence parallelism: pass `mesh_axis_sp` to route attention through
   ring_attention (sequence sharded over the `sp` axis).
 
@@ -33,7 +36,9 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import PartitionSpec as P
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from ray_tpu.parallel.mesh import prune_pspec
 
 
 def _round_up(x: int, m: int) -> int:
@@ -196,18 +201,31 @@ class GPT2Model:
     def param_pspecs(self, mesh=None) -> Dict[str, Any]:
         """PartitionSpecs over the standard mesh axes.  tp shards the
         contraction-free dim of each matmul (megatron column/row split);
-        fsdp shards the stacked layer dim (ZeRO-3-style param sharding —
-        all-gather per layer inside scan); embeddings shard vocab on tp.
+        embeddings shard vocab on tp.
+
+        fsdp (ZeRO-3-style) shards each layer's matrices on the dim tp
+        leaves whole, never the stacked layer dim: the forward scans over
+        that dim, and a slice along a sharded dim can only be served by
+        gathering the whole stack on every iteration.  Sharded inside the
+        layer, a layer's slice is that layer's shard, and `backbone` gathers
+        it inside the checkpointed layer.  (That dim also keeps the float32
+        shards compact on the TPU: `mlp_out_w` split on its rows pads 1600
+        columns to 1664, 59 MB a chip at GPT-2 XL.)  The per-layer vectors
+        replicate (their Adam moments are sharded all the same: lm_train's
+        ZeRO-1 completion).  A matrix whose dim does not divide by the
+        mesh's fsdp replicates over it: decided from the shapes.
 
         On a pp mesh the stacked layer dim is the *stage* dim: sharded over
         pp (one contiguous slice of layers per stage, consumed by the GPipe
-        shard_map in backbone).  pp composes with dp/fsdp batch sharding
-        AND with tp: the pipeline shard_map is manual over pp/dp/fsdp only,
-        so tp-sharded layer weights keep compiler-managed in-stage
-        collectives (shard_map manual-subset axes).  pp×sp (ring attention
-        inside a manual region) is rejected up front."""
-        if mesh is not None and dict(mesh.shape).get("pp", 1) > 1:
-            shape = dict(mesh.shape)
+        shard_map in backbone), and fsdp shards only the batch.  pp
+        composes with dp/fsdp batch sharding AND with tp: the pipeline
+        shard_map is manual over pp/dp/fsdp only, so tp-sharded layer
+        weights keep compiler-managed in-stage collectives (shard_map
+        manual-subset axes).  pp×sp (ring attention inside a manual region)
+        is rejected up front."""
+        shape = dict(mesh.shape) if mesh is not None else {}
+        pp = shape.get("pp", 1) > 1
+        if pp:
             if shape.get("sp", 1) > 1:
                 raise NotImplementedError(
                     "pp composes with dp/fsdp (batch sharding) and tp; "
@@ -215,46 +233,41 @@ class GPT2Model:
                 )
             if shape.get("tp", 1) > 1 and self.config.pp_schedule == "1f1b":
                 raise NotImplementedError("1f1b composes with dp/fsdp only")
-            specs = self.param_pspecs(None)
+        E = self.config.n_embd
+        # the stacked dim: the stage dim under pp, else whole on every device
+        L = "pp" if pp else None
 
-            def relayer(spec):
-                if not isinstance(spec, P):
-                    return spec
-                parts = list(spec)
-                if parts and parts[0] == "fsdp":
-                    parts[0] = "pp"  # stage dim, not ZeRO dim, under pp
-                return P(*parts)
+        def fsdp(n):
+            """Spec of a dim of size n that tp leaves whole: fsdp, where it
+            divides and the mesh has no pp."""
+            return None if pp or n % shape.get("fsdp", 1) else "fsdp"
 
-            specs["layers"] = {
-                k: relayer(v) for k, v in specs["layers"].items()
-            }
-            return specs
         layers = {
-            "ln1_scale": P("fsdp", None),
-            "ln1_bias": P("fsdp", None),
-            "ln2_scale": P("fsdp", None),
-            "ln2_bias": P("fsdp", None),
-            "qkv_w": P("fsdp", None, "tp"),
-            "qkv_b": P("fsdp", "tp"),
-            "proj_w": P("fsdp", "tp", None),
-            "proj_b": P("fsdp", None),
+            "ln1_scale": P(L, None),
+            "ln1_bias": P(L, None),
+            "ln2_scale": P(L, None),
+            "ln2_bias": P(L, None),
+            "qkv_w": P(L, fsdp(E), "tp"),
+            "qkv_b": P(L, "tp"),
+            "proj_w": P(L, "tp", fsdp(E)),
+            "proj_b": P(L, None),
         }
         if self.config.moe_experts:
-            # experts shard over ep on their expert dim; router replicates
+            # experts shard over ep on their expert dim
             layers.update(
                 {
-                    "router_w": P("fsdp", None, None),
-                    "expert_in": P("fsdp", "ep", None, None),
-                    "expert_out": P("fsdp", "ep", None, None),
+                    "router_w": P(L, fsdp(E), None),
+                    "expert_in": P(L, "ep", fsdp(E), None),
+                    "expert_out": P(L, "ep", fsdp(4 * E), None),
                 }
             )
         else:
             layers.update(
                 {
-                    "mlp_in_w": P("fsdp", None, "tp"),
-                    "mlp_in_b": P("fsdp", "tp"),
-                    "mlp_out_w": P("fsdp", "tp", None),
-                    "mlp_out_b": P("fsdp", None),
+                    "mlp_in_w": P(L, fsdp(E), "tp"),
+                    "mlp_in_b": P(L, "tp"),
+                    "mlp_out_w": P(L, "tp", fsdp(E)),
+                    "mlp_out_b": P(L, None),
                 }
             )
         return {
@@ -420,13 +433,34 @@ class GPT2Model:
         else:
             policy = None
 
+        # the fsdp gather is stated, not left to the partitioner's cost model,
+        # which may as well keep the weights sharded and gather the BATCH
+        # (tensor parallelism over fsdp: the 48-layer step then does not
+        # fit).  `whole`: each sharded matrix's layout once gathered; empty
+        # unless the mesh's fsdp is > 1, so a one-chip program is untouched.
+        whole = {}
+        if mesh is not None:
+            for name, spec in self.param_pspecs(mesh)["layers"].items():
+                gathered = prune_pspec(spec, mesh, drop=("fsdp",))
+                if gathered != prune_pspec(spec, mesh):
+                    whole[name] = NamedSharding(mesh, P(*gathered[1:]))
+
+        def layer(x, layer_params):
+            # cast, then gather: half the bytes on the links.  Inside the
+            # checkpoint: no gathered weight is a residual of every layer,
+            # the backward gathers a layer again as it recomputes it and
+            # reduces that layer's gradient back to its shard.
+            lp = {
+                k: jax.lax.with_sharding_constraint(v.astype(cd), whole[k]) if k in whole else v
+                for k, v in layer_params.items()
+            }
+            return self._layer(x, lp, mesh)
+
         def scan_body(x, layer_params):
             if cfg.remat:
-                y = jax.checkpoint(
-                    lambda x_, lp: self._layer(x_, lp, mesh), policy=policy
-                )(x, layer_params)
+                y = jax.checkpoint(layer, policy=policy)(x, layer_params)
             else:
-                y = self._layer(x, layer_params, mesh)
+                y = layer(x, layer_params)
             return y, None
 
         if mesh is not None and dict(mesh.shape).get("pp", 1) > 1:

@@ -5,12 +5,17 @@ Mesh.  Replaces the reference's torch DDP/FSDP wrap + NCCL allreduce
 (reference: python/ray/train/torch/train_loop_utils.py:56 prepare_model,
 config.py:69 _setup_torch_process_group): here the mesh sharding IS the
 strategy — dp replicates params and psums grads, fsdp shards params and
-optimizer state (ZeRO-style), tp shards within layers — all collectives
-inserted by XLA over ICI.
+optimizer state (ZeRO-style), tp shards within layers — the collectives
+inserted by XLA over ICI, but for fsdp's gather of a layer, which the model
+states inside its layer scan (gpt2.py `backbone`): one layer's matrices in
+the compute dtype a layer, their gradient reduced back to the shard in the
+backward loop; params, moments and the update stay float32 and sharded.
 
 Optimizer-state sharding (ZeRO-1, BASELINE config #4) falls out of the
 same spec tree: mu/nu inherit each param's PartitionSpec, so any param
-sharded over `fsdp` has its Adam moments sharded identically.
+sharded over `fsdp` has its Adam moments sharded identically, and the
+moments of a param that replicates (embeddings, the per-layer vectors) are
+sharded all the same (`_tree_specs_for_opt_state`).
 """
 
 from __future__ import annotations
@@ -23,7 +28,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ray_tpu.models.gpt2 import GPT2Config, GPT2Model
-from ray_tpu.parallel.mesh import data_pspec
+from ray_tpu.parallel.mesh import data_pspec, prune_pspec
 
 
 def _tree_specs_for_opt_state(opt, params, param_specs, mesh=None):
@@ -31,7 +36,7 @@ def _tree_specs_for_opt_state(opt, params, param_specs, mesh=None):
     their param's spec (path-suffix match), scalars replicate.
 
     ZeRO-1 completion: when the mesh carries an fsdp axis, moments whose
-    param is NOT fsdp-sharded (embeddings, final layernorm) still get a
+    param is NOT fsdp-sharded (embeddings, layernorms, biases) still get a
     shard — Adam's elementwise math lets the moments live sharded while
     the param replicates; XLA all-gathers the sharded update before
     apply.  This is exactly the reference FSDP/ZeRO-1 optimizer-state
@@ -95,20 +100,9 @@ def make_train_step(
     param_specs = model.param_pspecs(mesh)
     # drop axes the mesh doesn't carry (e.g. running a tp-annotated model on
     # a pure-dp mesh)
-    present = set(mesh.axis_names)
-
-    def _filter(spec):
-        if not isinstance(spec, P):
-            return spec
-        cleaned = tuple(
-            (a if (a in present and mesh.shape[a] > 1) else None)
-            if not isinstance(a, tuple)
-            else tuple(x for x in a if x in present and mesh.shape[x] > 1) or None
-            for a in spec
-        )
-        return P(*cleaned)
-
-    param_specs = jax.tree.map(_filter, param_specs, is_leaf=lambda x: isinstance(x, P))
+    param_specs = jax.tree.map(
+        lambda spec: prune_pspec(spec, mesh), param_specs, is_leaf=lambda x: isinstance(x, P)
+    )
 
     def shard(spec_tree):
         return jax.tree.map(

@@ -4,12 +4,16 @@ This is the TPU-native answer to the reference's per-strategy plumbing
 (SURVEY §2.4): where the reference wires NCCL process groups per strategy
 (DDP via torch PGs, collective groups via cupy NCCL), here every strategy —
 DP / ZeRO / TP / PP / SP / EP — is an *axis of one jax Mesh*, and XLA
-inserts the collectives (psum over `dp`, all-gather over `fsdp`, ppermute
-over `sp`, all-to-all over `ep`) that ride ICI.
+inserts the collectives (psum over `dp`, reduce-scatter over `fsdp`,
+ppermute over `sp`, all-to-all over `ep`) that ride ICI.  The exception is
+fsdp's all-gather: a model states it where a layer is used (gpt2.py
+`backbone`): left alone, the partitioner's cost model may keep the weights
+sharded and gather the batch instead.
 
 Axis conventions (matching the scaling-book vocabulary):
   dp    — data parallel (gradient psum)
-  fsdp  — ZeRO-style parameter/optimizer sharding (all-gather on use)
+  fsdp  — ZeRO-style parameter/optimizer sharding inside each layer, never
+          on a dim a scan walks (one layer gathered on use); also batch
   tp    — tensor parallel (intra-layer, megatron-style)
   pp    — pipeline stages
   sp    — sequence/context parallel (ring attention)
@@ -108,6 +112,22 @@ def data_pspec(mesh) -> "object":
 
     batch_axes = tuple(a for a in ("dp", "fsdp") if a in mesh.axis_names)
     return P(batch_axes if batch_axes else None)
+
+
+def prune_pspec(spec, mesh, drop: Sequence[str] = ()) -> "object":
+    """`spec` without the axes the mesh lacks or has at size 1 (a
+    tp-annotated model on a pure-dp mesh), and without those in `drop`."""
+    from jax.sharding import PartitionSpec as P
+
+    def prune(entry):
+        axes = tuple(
+            a
+            for a in (entry if isinstance(entry, tuple) else (entry,))
+            if a in mesh.axis_names and mesh.shape[a] > 1 and a not in drop
+        )
+        return axes[0] if len(axes) == 1 else (axes or None)
+
+    return P(*(prune(entry) for entry in spec))
 
 
 def replicated_pspec() -> "object":
