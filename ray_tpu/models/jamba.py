@@ -47,7 +47,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.models.llama import LlamaConfig, LlamaModel, _rms_norm
+from ray_tpu.models.llama import LlamaConfig, LlamaModel, _rms_norm, conv_window_after, conv_window_taps
 from ray_tpu.ops import selective_scan as ssm
 
 
@@ -226,7 +226,7 @@ class JambaModel(LlamaModel):
         cfg = self.config
         cd, f32 = cfg.compute_dtype, jnp.float32
         B, S, _ = x.shape
-        Dn, N, R, K = cfg.d_inner, cfg.d_state, cfg.dt_rank, cfg.d_conv
+        Dn, N, R = cfg.d_inner, cfg.d_state, cfg.dt_rank
 
         h = _rms_norm(x, fp["attn_norm"].astype(f32), cfg.norm_eps).astype(cd)
         uz = h @ mp["w_in"].astype(cd)
@@ -234,18 +234,9 @@ class JambaModel(LlamaModel):
 
         # causal depthwise conv over the last k-1 inputs and the call's own, channels as the scan's tiles
         tiles = ssm.channel_tiles
-        win = conv[mi] if slot is None else lax.dynamic_slice_in_dim(conv[mi], slot, 1, axis=0)  # [B, k-1, R, 128]
-        fresh = q_valid[:, 0] & (q_pos[:, 0] == 0)
-        win = jnp.where(fresh[:, None, None, None], jnp.zeros_like(win), win)
-        seq = jnp.concatenate([win, tiles(u)], axis=1)  # [B, k-1 + S, R, 128]
-        w = tiles(mp["conv_w"].astype(f32))
-        u = jax.nn.silu(sum(seq[:, j : j + S].astype(f32) * w[j] for j in range(K)) + tiles(mp["conv_b"].astype(f32)))
-        # the window after the call: the k-1 inputs that end at each row's last valid one
-        if S == 1:
-            win = jnp.where(q_valid[:, :, None, None], seq[:, 1:], seq[:, :-1])
-        else:
-            win = jax.vmap(lambda s, n: lax.dynamic_slice_in_dim(s, n, K - 1, axis=0))(seq, q_valid.sum(-1))
-        conv = conv.at[mi].set(win) if slot is None else lax.dynamic_update_slice(conv, win[None], (mi, slot, 0, 0, 0))
+        y, seq, fresh = conv_window_taps(conv, mi, slot, u, mp["conv_w"], q_pos, q_valid, tiles)
+        u = jax.nn.silu(y + tiles(mp["conv_b"].astype(f32)))
+        conv = conv_window_after(conv, mi, slot, seq, q_valid)
 
         xdbc = jnp.matmul(u.reshape(B, S, Dn).astype(cd), mp["w_x"].astype(cd), preferred_element_type=f32)
         dt_r = _rms_norm(xdbc[..., :R], mp["dt_norm"].astype(f32), cfg.norm_eps)

@@ -556,3 +556,54 @@ class LlamaModel:
         logits = self._logits(params, x[0])  # [C, V]
         last = jnp.clip(n_valid - 1, 0, C - 1)
         return self._sample_greedy(logits[last]), pages
+
+
+# ------------------------------------- a conv window in the pool (per-slot state)
+
+
+def starts_sequence(q_pos, q_valid):
+    """[B]: the call's rows that begin a sequence (valid, at position 0).  A
+    model with per-slot state starts such a row from zeros, so a reused slot
+    starts clean without the engine's help."""
+    return q_valid[:, 0] & (q_pos[:, 0] == 0)
+
+
+# A causal depthwise convolution whose last k - 1 inputs a slot ride in the
+# pool (``models/jamba.py``: in front of the selective scan; ``models/lfm2.py``:
+# the whole mixer).  ``member`` is the pool's window member [L, slots, k - 1,
+# *channels]; a decode step's rows are the slots (``slot`` None), a prefill
+# chunk is one row, of slot ``slot``.  Two functions, because what a model does
+# between the taps and the write-back (an activation, a bias) is its own.
+
+
+def conv_window_taps(member, li: int, slot, u, w, q_pos, q_valid, tiles=lambda a: a):
+    """The taps over layer ``li``'s window and the call's own inputs u [B, S,
+    channels]: y(t) = sum_j w[j] * in(t - (k-1) + j), w [k, channels], in
+    float32.  A row that begins a sequence (``starts_sequence``) reads a zero
+    window.  ``tiles`` lays channels out as the member has them (Jamba: the
+    scan's tiles of 128).  Returns (y [B, S, *channels] float32, seq [B, k-1 +
+    S, *channels]: the window and then the inputs, for ``conv_window_after``,
+    and the rows that began a sequence [B], for a caller that keeps more
+    state than the window)."""
+    K, S = w.shape[0], u.shape[1]
+    win = member[li] if slot is None else jax.lax.dynamic_slice_in_dim(member[li], slot, 1, axis=0)  # [B, k-1, *channels]
+    fresh = starts_sequence(q_pos, q_valid)
+    win = jnp.where(fresh[(slice(None),) + (None,) * (win.ndim - 1)], jnp.zeros_like(win), win)
+    seq = jnp.concatenate([win, tiles(u)], axis=1)
+    w = tiles(w.astype(jnp.float32))
+    return sum(seq[:, j : j + S].astype(jnp.float32) * w[j] for j in range(K)), seq, fresh
+
+
+def conv_window_after(member, li: int, slot, seq, q_valid):
+    """The member with layer ``li``'s window after the call: the k - 1 inputs
+    that end at each row's last VALID one (``q_valid`` [B, S]; a chunk's
+    padded tail leaves no trace, a row that is not valid leaves the window as
+    it was)."""
+    k1 = member.shape[2]
+    if seq.shape[1] == k1 + 1:
+        win = jnp.where(q_valid[(slice(None), slice(None)) + (None,) * (seq.ndim - 2)], seq[:, 1:], seq[:, :-1])
+    else:
+        win = jax.vmap(lambda s, n: jax.lax.dynamic_slice_in_dim(s, n, k1, axis=0))(seq, q_valid.sum(-1))
+    if slot is None:
+        return member.at[li].set(win)
+    return jax.lax.dynamic_update_slice(member, win[None], (li, slot) + (0,) * (member.ndim - 2))

@@ -18,7 +18,7 @@ hosts n_experts/ep_size experts.
 from __future__ import annotations
 
 import functools
-from typing import Dict, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -147,6 +147,7 @@ def dropless_moe_ffn(
     scoring: str = "softmax",
     bias=None,
     scale: float = 1.0,
+    renorm_eps: Optional[float] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Exact top-k routed SwiGLU experts: every row goes through all
     ``top_k`` of its experts whatever the other rows chose -- no capacity,
@@ -163,7 +164,10 @@ def dropless_moe_ffn(
     ("sigmoid", with its optional selection ``bias``); ``renormalize``
     divides a row's ``top_k`` weights by their sum (``norm_topk_prob``: OLMoE
     false, Qwen3-Next and Moonlight true) and ``scale`` multiplies them after
-    that (``routed_scaling_factor``).  A shared expert is the model's to add.
+    that (``routed_scaling_factor``).  ``renorm_eps`` is what a model's
+    published code adds to that sum (LFM2: 1e-6); left out, it is 1e-20
+    under the sigmoid (DeepSeek-V3's) and nothing under the softmax.  A shared
+    expert is the model's to add.
 
     Computed as a masked contraction over ALL held experts: every one's
     SwiGLU runs on every row, and the router's weight (zero for an expert
@@ -192,8 +196,10 @@ def dropless_moe_ffn(
             raise ValueError(f"scoring={scoring!r} with bias={bias is not None}: softmax, or sigmoid with an optional selection bias")
         if renormalize:  # over the row's top_k, held here or not
             total = weights.sum(-1, keepdims=True)
-            # sigmoid scores can all underflow to zero; a softmax's top_k cannot (the published codes differ here too)
-            weights = weights / (total + 1e-20 if scoring == "sigmoid" else total)
+            if renorm_eps is None:
+                # sigmoid scores can all underflow to zero; a softmax's top_k cannot (the published codes differ here too)
+                renorm_eps = 1e-20 if scoring == "sigmoid" else 0.0
+            weights = weights / (total + renorm_eps if renorm_eps else total)
         if scale != 1.0:
             weights = weights * scale
         # [T, X]: a row's weight for each expert, zero where not chosen

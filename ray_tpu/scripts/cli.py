@@ -208,7 +208,8 @@ def _print_engine_gauges(engine: dict) -> None:
             f"frag={gauges.get('page_fragmentation', 0):.2f} "
             f"host={gauges.get('host_share', 0):.2f} "
             f"cache={gauges.get('cache_bytes_per_position', 0):.0f}B/pos "
-            f"tokens={gauges.get('tokens_total', 0):.0f}"
+            + (f"state={gauges['state_bytes_per_slot']:.0f}B/slot " if "state_bytes_per_slot" in gauges else "")
+            + f"tokens={gauges.get('tokens_total', 0):.0f}"
         )
 
 

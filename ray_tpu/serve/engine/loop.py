@@ -208,6 +208,7 @@ class InferenceEngine:
         # per-slot state beside the pages: its size, and how many chunks
         # began a sequence and so reset their slot's state
         self._state_bytes = sum(int(a.nbytes) for a, role in zip(self._pages, self._pool_roles) if role == "state")
+        self._state_bytes_per_slot = self._state_bytes / cfg.num_slots
         self.state_resets = 0
         # what the cache keeps a position, over all layers: the bytes of the
         # pool's "pages" members over the positions they hold (a model's K and
@@ -745,6 +746,7 @@ class InferenceEngine:
             out["moe_expert_load"] = held.tolist()
         if self._state_bytes:
             out["state_bytes"] = float(self._state_bytes)
+            out["state_bytes_per_slot"] = float(self._state_bytes_per_slot)
             out["state_resets"] = float(self.state_resets)
         return out
 
@@ -869,6 +871,11 @@ class InferenceEngine:
                         "Bytes the page pool keeps a cached position, all layers (K and V heads, or one latent row)",
                         tag_keys=("deployment",),
                     ),
+                    "state": Gauge(
+                        "ray_tpu_serve_engine_state_bytes_per_slot",
+                        "Bytes of per-slot state the pool keeps a slot beside the pages (recurrent state, conv windows)",
+                        tag_keys=("deployment",),
+                    ),
                     "host": Gauge(
                         "ray_tpu_serve_engine_host_share",
                         "Share of the engine thread's turns not spent waiting for the device (1=host-bound)",
@@ -883,6 +890,8 @@ class InferenceEngine:
             )
             # fixed when the pool was made: written once, not a round trip a period
             self._gauges[0]["cache"].set(self._cache_bytes_per_position, {"deployment": self.deployment})
+            if self._state_bytes:  # a model with per-slot state only: absent elsewhere
+                self._gauges[0]["state"].set(self._state_bytes_per_slot, {"deployment": self.deployment})
         return self._gauges
 
     # ------------------------------------------------------------ teardown

@@ -39,7 +39,7 @@ def topology_devices():
 
 def load_config(name: str, n_layers: int):
     """(the program's config at the file's widths and ``n_layers``, the file's engine geometry)."""
-    from benchmarks.drivers import serve, serve_jamba, serve_mla_moe, serve_moe, serve_qwen3_next
+    from benchmarks.drivers import serve, serve_conv_moe, serve_jamba, serve_mla_moe, serve_moe, serve_qwen3_next
 
     with open(os.path.join(ROOT, "benchmarks", "configs", f"{name}.json")) as f:
         cfg = json.load(f)
@@ -48,14 +48,15 @@ def load_config(name: str, n_layers: int):
         assert lcfg.n_layers == n_layers, (lcfg.n_layers, n_layers)
         return lcfg, cfg["engine"]
     build = {"serve": serve.llama_config, "serve_moe": serve_moe.moe_config, "serve_qwen3_next": serve_qwen3_next.hybrid_config,
-             "serve_mla_moe": serve_mla_moe.mla_config}[cfg["kind"]]
+             "serve_mla_moe": serve_mla_moe.mla_config, "serve_conv_moe": serve_conv_moe.conv_config}[cfg["kind"]]
     return dataclasses.replace(build(cfg), n_layers=n_layers), cfg["engine"]
 
 
-def compile_programs(lcfg, engine: dict, devices) -> Dict[str, Tuple[str, float]]:
+def compile_programs(lcfg, engine: dict, devices, with_memory: bool = False) -> Dict[str, Tuple]:
     """{"decode" | "prefill": (optimised HLO text, cost_analysis bytes accessed)}
     of ``ShardedLLM(lcfg).engine_programs`` over ``devices[:1]`` at the
-    engine geometry of the configuration's file."""
+    engine geometry of the configuration's file; ``with_memory``: and the
+    compiler's ``memory_analysis()`` (arguments, temporaries) as a third."""
     import jax
     import jax.numpy as jnp
 
@@ -78,7 +79,7 @@ def compile_programs(lcfg, engine: dict, devices) -> Dict[str, Tuple[str, float]
     out = {}
     for name in PROGRAMS:
         compiled = programs[name].lower(*args[name]).compile()
-        out[name] = (compiled.as_text(), float(compiled.cost_analysis()["bytes accessed"]))
+        out[name] = (compiled.as_text(), float(compiled.cost_analysis()["bytes accessed"])) + ((compiled.memory_analysis(),) if with_memory else ())
     return out
 
 

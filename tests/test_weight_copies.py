@@ -156,6 +156,36 @@ def test_v5e_moonlight_programs_keep_the_cache_latent():
         assert not big, (prog, sorted(big))
 
 
+def test_v5e_lfm2_programs_read_their_weights_and_the_pool_where_they_lie():
+    """LFM2-24B-A2B's two programs at the published widths over the reference
+    check's four layers (both dense conv layers, an attending and a conv layer
+    with experts: all four stacks) and the cell's own pool: no weight-sized
+    copy -- neither ``wq``/``wk``/``wv``, whose per-head norm FOLLOWS the split
+    into heads (``_qkv``'s barrier, restated in ``Lfm2MoeModel._attn``), nor
+    the conv mixer's ``w_in`` and ``w_out`` --; the K/V pages in ONE layout,
+    row-major, from the entry to the result (as [.., 16, 8, 64] the device's
+    own choice puts the page axis minor-most and both programs copy the pool
+    to row-major and back, 4 GB of temporaries: ``Lfm2MoeModel.init_pages``);
+    nothing rematerialised; temporaries as the configuration's ``engine_note``
+    states them."""
+    devices = _v5e()
+    lcfg, engine = _aot_v5e.load_config("lfm2-24b-a2b-l10", 4)
+    assert (lcfg.layer_kinds, lcfg.n_dense_layers, lcfg.n_experts, lcfg.head_dim) == (("conv", "conv", "attn", "conv"), 2, 64, 64)
+    compiled = _aot_v5e.compile_programs(lcfg, engine, devices, with_memory=True)
+    model = lcfg.build_model()
+    pool = jax.eval_shape(lambda: model.init_pages(int(engine["num_pages"]), int(engine["page_size"]), int(engine["num_slots"])))
+    assert [tuple(a.shape) for a in pool] == [(1, 30720, 16, 512), (1, 30720, 16, 512), (64,), (3, 96, 2, 2048)]
+    pool_text = "bf16[1,30720,16,512]"
+    for prog in _aot_v5e.PROGRAMS:
+        hlo, _, memory = compiled[prog]
+        assert _aot_v5e.weight_relayouts(hlo) == [], prog
+        layouts = {hlo[i + len(pool_text) : hlo.index("}", i) + 1] for i in range(len(hlo)) if hlo.startswith(pool_text + "{", i)}
+        assert layouts == {"{3,2,1,0:T(8,128)(2,1)}"}, (prog, layouts)
+        assert ".remat" not in hlo, prog
+        assert memory.temp_size_in_bytes < 32 << 20, (prog, memory.temp_size_in_bytes)  # 10 MB and 9 MB at all ten layers
+        assert memory.alias_size_in_bytes >= sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in pool) - 4096, prog  # the pool is updated in place
+
+
 def test_the_relayout_reader_sees_a_copy_where_there_is_one():
     hlo = """HloModule m
 %fused_computation.1 (p: bf16[16,4096,1024]) -> bf16[1024,4096] {
