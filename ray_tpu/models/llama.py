@@ -486,9 +486,10 @@ class LlamaModel:
     def pool_roles(self) -> Tuple[str, ...]:
         """What each member of ``init_pages``' pool is to the engine:
         "pages" (indexed by physical page on axis 1: ``defrag`` moves
-        them), "counter" (the routing counter, at most one) or "state"
+        them), "counter" (the routing counter, at most one), "state"
         (per-SLOT tensors, ``num_slots`` wide, that no page move touches;
-        this model has none)."""
+        this model has none) or "expert_reads" (``models/qwen3_next.py``: a
+        scalar count of the expert weight reads its decode steps made)."""
         return ("pages", "pages") + (("counter",) if self.config.n_experts else ())
 
     def held_experts(self) -> slice:

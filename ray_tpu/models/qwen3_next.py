@@ -292,9 +292,13 @@ class Qwen3NextModel(LlamaModel):
         layers only [L_full, NP, PS, KV, D], the routing counter over the
         router's experts [n_routed_experts] int32, and per slot the Gated
         DeltaNet layers' recurrent state [L_lin, slots, Hv, Dk, Dv] float32
-        and conv window [L_lin, slots, k - 1, conv_dim].  Admission stays
-        one number, pages: a slot's state is there whether it is used or
-        not."""
+        and conv window [L_lin, slots, k - 1, conv_dim], and last the count
+        of (layer, expert) weight reads that the routed layer's touched form
+        made (int32, wrapping; ``parallel/moe.py dropless_moe_ffn``: a decode
+        step of few slots reads the held experts its live rows chose, and a
+        program that takes the masked form leaves the count as it was).
+        Admission stays one number, pages: a slot's state is there whether
+        it is used or not."""
         cfg = self.config
         if num_slots <= 0:
             raise ValueError("a model with per-slot state must be told the number of slots")
@@ -307,14 +311,15 @@ class Qwen3NextModel(LlamaModel):
             jnp.zeros((cfg.n_routed_experts,), jnp.int32),
             jnp.zeros((Ll, num_slots, cfg.lin_value_heads, cfg.lin_key_dim, cfg.lin_value_dim), jnp.float32),
             jnp.zeros((Ll, num_slots, cfg.conv_kernel - 1, cfg.conv_dim), cfg.compute_dtype),
+            jnp.zeros((), jnp.int32),
         )
 
     def pool_pspecs(self) -> Tuple:
         # nothing of the mixers is split, so neither is what they keep
-        return (P(), P(), P(), P(), P())
+        return (P(), P(), P(), P(), P(), P())
 
     def pool_roles(self) -> Tuple[str, ...]:
-        return ("pages", "pages", "counter", "state", "state")
+        return ("pages", "pages", "counter", "state", "state", "expert_reads")
 
     def held_experts(self) -> slice:
         cfg = self.config
@@ -411,7 +416,11 @@ class Qwen3NextModel(LlamaModel):
     def _ffn(self, x, mp):
         """The block every layer ends in: x [B, S, E] -> (x + the held
         experts' part of the routed sum + the gated shared expert, chosen
-        [B, S, K] over the router's experts)."""
+        [B, S, K] over the router's experts).  ``mp`` is the layer's slice
+        of ``params["moe"]`` but for the three expert stacks, which are whole
+        with the layer's index under "layer" (the routed layer takes one
+        expert of them where it reads), and under "valid" which of x's rows
+        are live [B, S]: a decode step's idle slots touch no expert."""
         from ray_tpu.parallel.moe import dropless_moe_ffn
 
         cfg = self.config
@@ -421,7 +430,7 @@ class Qwen3NextModel(LlamaModel):
         with jax.named_scope("moe_ffn"):
             y, chosen = dropless_moe_ffn(
                 h, mp["router"], mp["w_gate"], mp["w_up"], mp["w_down"], top_k=cfg.n_experts_per_tok,
-                renormalize=cfg.norm_topk_prob, expert_offset=cfg.expert_offset,
+                renormalize=cfg.norm_topk_prob, expert_offset=cfg.expert_offset, valid=mp["valid"].reshape(B * S), layer=mp["layer"],
             )
         with jax.named_scope("shared_expert"):
             shared = (jax.nn.silu(h @ mp["ws_gate"].astype(cd)) * (h @ mp["ws_up"].astype(cd))) @ mp["ws_down"].astype(cd)
@@ -430,12 +439,18 @@ class Qwen3NextModel(LlamaModel):
         return x + y.reshape(B, S, E), chosen.reshape(B, S, -1)
 
     def _paged_forward(self, params, x, pages, wpage, woff, tables, q_pos, q_valid, slot=None):
+        from ray_tpu.parallel.moe import reads_touched_experts_only
+
         cfg = self.config
-        kp, vp, load, state, conv = pages
+        kp, vp, load, state, conv, reads = pages
         tables, n_blocks = self._walk_blocks(tables, kp.shape[2], q_pos, q_valid)
+        # the experts' stacks go to the routed layer whole: a layer's slice handed to its loop would be copied first
+        experts = {name: params["moe"][name] for name in ("w_gate", "w_up", "w_down")}
+        rest = {name: p for name, p in params["moe"].items() if name not in experts}
+        touched_only = reads_touched_experts_only(x.shape[0] * x.shape[1], cfg.n_experts_per_tok, cfg.n_routed_experts)
         n_full = n_lin = 0
         for i, kind in enumerate(cfg.layer_kinds):
-            mp = jax.tree.map(lambda p: p[i], params["moe"])
+            mp = jax.tree.map(lambda p: p[i], rest)
             if kind == "full":
                 fp = jax.tree.map(lambda p: p[n_full], params["full"])
                 with jax.named_scope("gated_attn"):
@@ -446,8 +461,10 @@ class Qwen3NextModel(LlamaModel):
                 with jax.named_scope("gdn"):
                     x, state, conv = self._gdn(x, mp, lp, n_lin, state, conv, slot, q_pos, q_valid)
                 n_lin += 1
-            x, chosen = self._ffn(x, mp)
-            hits = jax.nn.one_hot(chosen, cfg.n_routed_experts, dtype=jnp.int32) * q_valid[..., None, None]
-            load = load + hits.sum((0, 1, 2))
+            x, chosen = self._ffn(x, {**mp, **experts, "layer": i, "valid": q_valid})
+            hits = (jax.nn.one_hot(chosen, cfg.n_routed_experts, dtype=jnp.int32) * q_valid[..., None, None]).sum((0, 1, 2))
+            load = load + hits
+            if touched_only:  # the routed layer visited the held experts a live row chose, and read no other
+                reads = reads + (hits[self.held_experts()] > 0).sum()
         x = _zrms_norm(x, params["final_norm"], cfg.norm_eps)
-        return x.astype(cfg.compute_dtype), (kp, vp, load, state, conv)
+        return x.astype(cfg.compute_dtype), (kp, vp, load, state, conv, reads)

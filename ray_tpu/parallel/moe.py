@@ -134,10 +134,27 @@ def route_sigmoid(h: jax.Array, router_w: jax.Array, top_k: int, bias=None):
     return jnp.take_along_axis(scores, chosen, axis=-1), chosen
 
 
+def reads_touched_experts_only(rows: int, top_k: int, n_experts: int) -> bool:
+    """The one rule by which ``dropless_moe_ffn`` picks its form, from what a
+    call's shapes say: ``rows * top_k`` assignments over a router of
+    ``n_experts`` touch about ``1 - exp(-rows * top_k / n_experts)`` of the
+    held experts, whichever share is held.  Swept on a v5e at Qwen3-Next's
+    widths (128 of 512 held, top 10, every row live; PERF.md section 6, PR
+    48) the masked contraction costs 1.09 ms a layer whatever the rows, 8.5 us
+    an expert, and a visit of the touched form 13.9 us (its three matmuls, in
+    two fusions, move 6 MB at two thirds of the bandwidth): the forms meet
+    where 0.61 of the held experts are touched, at ``rows * top_k`` about
+    ``n_experts``.  The rule takes the touched form up to half of that, where
+    it still costs 0.55 of the masked form (24 rows: 0.58 against 1.09 ms),
+    and beyond it leaves the layer to the contraction whose cost no routing
+    moves."""
+    return 2 * rows * top_k <= n_experts
+
+
 def dropless_moe_ffn(
     h: jax.Array,  # [T, E]
     router_w: jax.Array,  # [E, X]
-    w_gate: jax.Array,  # [X, E, H]
+    w_gate: jax.Array,  # [X, E, H]; with ``layer``, a model's whole stack [L, X, E, H]
     w_up: jax.Array,  # [X, E, H]
     w_down: jax.Array,  # [X, H, E]
     *,
@@ -148,6 +165,8 @@ def dropless_moe_ffn(
     bias=None,
     scale: float = 1.0,
     renorm_eps: Optional[float] = None,
+    valid=None,
+    layer: Optional[int] = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """Exact top-k routed SwiGLU experts: every row goes through all
     ``top_k`` of its experts whatever the other rows chose -- no capacity,
@@ -169,11 +188,22 @@ def dropless_moe_ffn(
     under the sigmoid (DeepSeek-V3's) and nothing under the softmax.  A shared
     expert is the model's to add.
 
-    Computed as a masked contraction over ALL held experts: every one's
-    SwiGLU runs on every row, and the router's weight (zero for an expert
-    the row did not choose) multiplies the activation before ONE
+    One layer in two forms, which share the router block to its last line and
+    part at ``dense_w`` [T, held], a row's weight for each held expert and zero
+    where it did not choose it.  ``reads_touched_experts_only`` picks by the
+    call's shapes; the engine's calls, and their choices over the router's
+    experts:
+
+        Qwen3-Next decode   16 rows x 10 of 512   0.31   the touched experts
+        Qwen3-Next chunk   256 rows x 10 of 512   5      masked
+        OLMoE decode        32 rows x  8 of  64   4      masked
+        Moonlight decode    64 rows x  6 of  64   6      masked
+        LFM2 decode         96 rows x  4 of  64   6      masked
+
+    The masked contraction over ALL held experts: every one's SwiGLU runs on
+    every row, and the router's weight multiplies the activation before ONE
     down-projection contracts over experts and expert width together.  At
-    the engine's shapes (32 decode rows, 256 chunk rows, 64 experts, top 8)
+    OLMoE's shapes (32 decode rows, 256 chunk rows, 64 experts, top 8)
     every expert is chosen by some row, so every expert's weights are read
     from HBM whichever way it is computed; masking costs X/K times the
     routed FLOPs and needs no sort, no gather and no data-dependent shape.
@@ -184,9 +214,25 @@ def dropless_moe_ffn(
     ms of bytes: AT the roofline, not hidden under it (the chunk program
     takes 12.7 ms, 67% of its byte roofline), so from that row count on a
     grouped matmul over the routed rows alone would be the faster layer.
+
+    The touched form (``_touched_experts_ffn``), for a decode step of a few
+    rows over a wide router: 16 rows' 160 choices fall on ~19 of the 128
+    experts held, and the masked form would stream all 128 (805 MB a layer
+    for 118 MB used).  It visits the touched experts alone, in ascending
+    order, each visit reading ONE expert's three matrices.  ``valid`` [T]
+    bool says which rows are live (a decode step's idle slots are not): a
+    row that is not touches nothing and gets zero; the masked form, which
+    reads every expert anyway, takes no notice of it.  It needs the stacks
+    where they lie: ``layer`` says that ``w_gate``/``w_up``/``w_down`` are a
+    model's WHOLE stacks [L, X, E, H] and this call is layer ``layer`` of
+    them.  A layer's slice handed to the loop is a value the TPU compiler
+    materialises in front of it -- all three stacks copied, every call, which
+    costs more than the masked form reads -- so a call with sliced stacks
+    keeps the masked form at every shape (OLMoE's, Moonlight's and LFM2's
+    models slice; their few-slot deployments lose nothing they had).
     Returns (y [T, E] in h's dtype, chosen [T, K])."""
     cd = h.dtype
-    n_experts, held = router_w.shape[-1], w_gate.shape[0]
+    n_experts, held = router_w.shape[-1], w_gate.shape[-3]
     with jax.named_scope("router"):
         if scoring == "sigmoid":
             weights, chosen = route_sigmoid(h, router_w, top_k, bias)
@@ -206,8 +252,43 @@ def dropless_moe_ffn(
         dense_w = (jax.nn.one_hot(chosen, n_experts, dtype=jnp.float32) * weights[..., None]).sum(-2)
         if held != n_experts:
             dense_w = dense_w[:, expert_offset : expert_offset + held]
+    if layer is not None and reads_touched_experts_only(h.shape[0], top_k, n_experts):
+        if valid is not None:
+            dense_w = dense_w * valid[:, None]
+        return _touched_experts_ffn(h, dense_w, w_gate, w_up, w_down, layer), chosen
+    if layer is not None:
+        w_gate, w_up, w_down = w_gate[layer], w_up[layer], w_down[layer]
     gate = jnp.einsum("te,xeh->xth", h, w_gate.astype(cd))
     up = jnp.einsum("te,xeh->xth", h, w_up.astype(cd))
     act = (jax.nn.silu(gate) * up).astype(jnp.float32) * dense_w.T[:, :, None]
     y = jnp.einsum("xth,xhe->te", act.astype(cd), w_down.astype(cd), preferred_element_type=jnp.float32)
     return y.astype(cd), chosen
+
+
+def _touched_experts_ffn(h, dense_w, w_gate, w_up, w_down, layer: int):
+    """``dropless_moe_ffn`` from ``dense_w`` [T, held] on, reading the
+    weights of the held experts some row has a weight for and no others.
+    The list of those experts has a static length (``held``) and a dynamic
+    count: a loop of that many visits takes them in ascending order, and a
+    visit cuts ONE expert out of each stack [L, held, ., .] at ``(layer,
+    expert)`` (the compiler reads the cut in place, as its matmul's operand),
+    runs all T rows through its SwiGLU, scales by that expert's column of
+    ``dense_w`` and adds into a float32 [T, E].  No shape depends on the
+    data.  A row's non-zero terms arrive in ascending expert order whoever
+    else is in the call, and another row's expert adds an exact zero to it:
+    alone or among others, a row's result is the same to the bit."""
+    cd = h.dtype
+    touched = (dense_w != 0).any(0)  # [held]
+    order = jnp.argsort(~touched, stable=True)  # the touched experts first, ascending
+
+    def visit(i, y):
+        x = order[i]
+
+        def one(w):
+            return lax.dynamic_slice(w, (layer, x, 0, 0), (1, 1) + w.shape[-2:]).reshape(w.shape[-2:]).astype(cd)
+
+        act = (jax.nn.silu(h @ one(w_gate)) * (h @ one(w_up))).astype(jnp.float32) * lax.dynamic_slice_in_dim(dense_w, x, 1, axis=1)
+        return y + jnp.dot(act.astype(cd), one(w_down), preferred_element_type=jnp.float32)
+
+    y = lax.fori_loop(0, touched.sum(), visit, jnp.zeros(h.shape, jnp.float32))
+    return y.astype(cd)

@@ -198,13 +198,16 @@ class InferenceEngine:
         self._gauges = None
         self._last_tick = 0.0
         # what each member of the pool is, by the model's word
-        # (``LlamaModel.pool_roles``): "pages", "counter" or "state"
+        # (``LlamaModel.pool_roles``): "pages", "counter", "state" or "expert_reads"
         self._pool_roles = tuple(llm.model.pool_roles())
         # an expert model's routing counter (a wrapping int32 per expert on
         # the device): its last reading, and the replica's running totals.
-        # None for a model without one
+        # None for a model without one.  The same pair for the count of
+        # expert weight reads, at a model whose pool carries one
         self._moe_seen = None
         self._moe_load = None
+        self._reads_seen = 0
+        self._expert_reads = 0 if "expert_reads" in self._pool_roles else None
         # per-slot state beside the pages: its size, and how many chunks
         # began a sequence and so reset their slot's state
         self._state_bytes = sum(int(a.nbytes) for a, role in zip(self._pages, self._pool_roles) if role == "state")
@@ -552,6 +555,13 @@ class InferenceEngine:
         self._sync_ns += time.perf_counter_ns() - t0
         return out
 
+    def _read_member(self, role: str) -> Optional[np.ndarray]:
+        """The newest pool's member called ``role`` (``pool_roles``), read to
+        the host, or None at a model whose pool has none."""
+        if role not in self._pool_roles:
+            return None
+        return self._await(self._pages[self._pool_roles.index(role)])
+
     def _decode_step(self, fleet: List[EngineRequest], joined) -> None:
         """Dispatch one decode step from the frontier on the device.  Every
         row of ``fleet`` either ran in the step before (its input is that
@@ -744,6 +754,8 @@ class InferenceEngine:
             out["moe_assignments"] = out["moe_assignments_held"] = float(held.sum())
             out["moe_assignments_seen"] = float(load.sum())
             out["moe_expert_load"] = held.tolist()
+        if self._expert_reads is not None:  # as of the same tick
+            out["moe_expert_reads"] = float(self._expert_reads)
         if self._state_bytes:
             out["state_bytes"] = float(self._state_bytes)
             out["state_bytes_per_slot"] = float(self._state_bytes_per_slot)
@@ -775,14 +787,17 @@ class InferenceEngine:
         if now - self._last_tick < self.cfg.gauge_period_s:
             return
         self._last_tick = now
-        seen = None
-        if "counter" in self._pool_roles:
-            try:
-                seen = self._await(self._pages[self._pool_roles.index("counter")]).astype(np.uint32)
-            except Exception:  # noqa: BLE001 -- a dead loop's pool may be gone; the totals stay as they were
-                pass
+        seen = reads = None
+        try:
+            seen, reads = self._read_member("counter"), self._read_member("expert_reads")  # one step's result: the second read waits for nothing
+        except Exception:  # noqa: BLE001 -- a dead loop's pool may be gone; the totals stay as they were
+            pass
         with span("engine/gauges"):
+            if reads is not None:  # a wrapping int32 too
+                self._expert_reads += (int(reads) - self._reads_seen) % 2**32
+                self._reads_seen = int(reads)
             if seen is not None:
+                seen = seen.astype(np.uint32)
                 # the device counts in wrapping int32: the difference between
                 # two readings is exact as long as fewer than 2**32
                 # assignments go to one expert between ticks

@@ -332,5 +332,5 @@ def test_a_dense_model_with_per_slot_state_reports_state_bytes_and_no_moe_keys()
     st, roles = _engine_stats(LlamaConfig.tiny(compute_dtype=jnp.float32, n_experts=4, n_experts_per_tok=2, qk_norm=True))
     assert roles == ("pages", "pages", "counter") and MOE <= set(st) and not STATE & set(st) and len(st["moe_expert_load"]) == 4
     st, roles = _engine_stats(qwen_tiny(jnp.float32))
-    assert roles == ("pages", "pages", "counter", "state", "state") and (MOE | STATE) <= set(st) and len(st["moe_expert_load"]) == 4
+    assert roles == ("pages", "pages", "counter", "state", "state", "expert_reads") and (MOE | STATE | {"moe_expert_reads"}) <= set(st) and len(st["moe_expert_load"]) == 4
     assert st["state_bytes"] == 2 * 3 * (4 * 16 * 16 * 4 + 3 * 128 * 4)

@@ -319,3 +319,69 @@ def test_engine_stats_carry_the_state_and_held_share_counters(served):
     assert st["moe_assignments_held"] == sum(st["moe_expert_load"]) == st["moe_assignments"]
     assert st["moe_assignments_seen"] % (cfg.n_layers * cfg.n_experts_per_tok) == 0  # whole rows
     assert 0.1 < st["moe_assignments_held"] / st["moe_assignments_seen"] < 0.5
+
+
+# ------------------------------------ the decode step reads the touched experts only
+
+
+def _stats_after_a_tick(eng):
+    import time
+
+    eng._wake.set()
+    time.sleep(0.3)  # an idle tick reads the pool's counters
+    return eng.stats()
+
+
+def test_engine_stats_count_the_expert_reads_of_the_decode_steps():
+    """Two slots x top 3 of 16: the decode step takes the routed layer's
+    touched form, and ``moe_expert_reads`` grows by the distinct (layer, held
+    expert) pairs that the reference's routing gives for the rows a step
+    decodes -- one request alone, so one row a step; the chunks (64 rows: the
+    masked form) and the idle slot add nothing."""
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+
+    cfg = tiny(jnp.float32)
+    assert moe.reads_touched_experts_only(2, cfg.n_experts_per_tok, cfg.n_routed_experts) and not moe.reads_touched_experts_only(CHUNK, cfg.n_experts_per_tok, cfg.n_routed_experts)
+    llm = ShardedLLM(cfg, tp=1, init=crafted(cfg))
+    eng = InferenceEngine(llm, EngineConfig(num_slots=2, page_size=PAGE, max_seq_len=192, prefill_chunk=CHUNK, max_new_tokens=8, gauge_period_s=0.0), deployment="r")
+    try:
+        assert eng.stats()["moe_expert_reads"] == 0.0
+        want_reads = want_steps = 0
+        for prompt, n_new in ((PROMPTS[1], 6), (PROMPTS[0], 4)):
+            out = eng.submit(list(map(int, prompt)), n_new).sink.result(timeout=300)
+            seq = np.zeros(192, np.int32)
+            seq[: len(prompt) + n_new] = list(prompt) + out
+            chosen = np.asarray(jax.jit(lambda p, t: ref_mod.forward(p, t, **driver.reference_kwargs(cfg)).chosen)(llm.params, jnp.asarray(seq)))  # [L, S, K]
+            fed = chosen[:, len(prompt) : len(prompt) + n_new - 1]  # a decode step feeds every generated token but the last
+            held = (fed >= cfg.expert_offset) & (fed < cfg.expert_offset + cfg.n_experts)
+            want_reads += sum(len(set(row[keep])) for layer, kept in zip(fed, held) for row, keep in zip(layer, kept))
+            want_steps += n_new - 1
+        st = _stats_after_a_tick(eng)
+        assert st["decode_steps"] == want_steps and st["rows_discarded"] == 0
+        assert st["moe_expert_reads"] == want_reads > 0
+        assert st["moe_expert_reads"] / (st["decode_steps"] * cfg.n_layers * cfg.n_experts) < 0.5  # one row: at most 3 of the 4 held a layer
+        assert eng.compile_stats() == {"prefill": 1, "decode": 1}
+    finally:
+        eng.shutdown()
+
+
+def test_an_engine_whose_decode_step_takes_the_masked_form_reports_no_expert_reads(served):
+    """OLMoE's shape of call (slots x top_k over the router's experts): its
+    pool and programs carry no such count.  And the 3-slot Qwen3-Next engine
+    above (2 * 3 * 3 > 16: masked) carries one that stays at zero."""
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.serve.engine import EngineConfig, InferenceEngine
+
+    ocfg = LlamaConfig.tiny(compute_dtype=jnp.float32, n_experts=4, n_experts_per_tok=2)
+    assert not moe.reads_touched_experts_only(3, ocfg.n_experts_per_tok, ocfg.n_experts)
+    eng = InferenceEngine(ShardedLLM(ocfg, tp=1), EngineConfig(num_slots=3, page_size=PAGE, max_seq_len=64, prefill_chunk=16, max_new_tokens=4, gauge_period_s=0.0), deployment="o")
+    try:
+        eng.submit([5, 7, 9], 4).sink.result(timeout=300)
+        st = _stats_after_a_tick(eng)
+        assert st["moe_assignments"] > 0 and "moe_expert_reads" not in st
+    finally:
+        eng.shutdown()
+    _, _, hybrid = served
+    hybrid.submit([3, 4, 5], 3).sink.result(timeout=300)
+    st = _stats_after_a_tick(hybrid)
+    assert st["decode_steps"] > 0 and st["moe_expert_reads"] == 0.0

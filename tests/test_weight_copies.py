@@ -186,6 +186,34 @@ def test_v5e_lfm2_programs_read_their_weights_and_the_pool_where_they_lie():
         assert memory.alias_size_in_bytes >= sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in pool) - 4096, prog  # the pool is updated in place
 
 
+def test_v5e_qwen3_next_decode_step_reads_one_expert_a_visit():
+    """The cell's own decode program (8 layers, 16 slots, 128 of 512 experts
+    held): the routed layer's touched form (``parallel/moe.py``).  Nothing in
+    it reads, converts or copies a layer's whole expert stack -- no array
+    with a [128, 2048, 512] in it but the parameters themselves, which the
+    eight loops carry through untouched -- and all that is cut out of a stack
+    is ONE expert, three times a layer, inside the loop that visits the
+    touched experts.  The chunk program (256 rows) keeps the masked
+    contraction over a layer's whole stacks."""
+    import re
+
+    devices = _v5e()
+    lcfg, engine = _aot_v5e.load_config("qwen3-next-80b-a3b-l8-ep4", 8)
+    held, E, H = lcfg.n_experts, lcfg.dim, lcfg.hidden_dim
+    assert (int(engine["num_slots"]), held, lcfg.n_routed_experts, lcfg.n_experts_per_tok, E, H) == (16, 128, 512, 10, 2048, 512)
+    compiled = _aot_v5e.compile_programs(lcfg, engine, devices)
+    a_layers_stack = lambda shapes: {dims for _, dims in shapes if tuple(d for d in dims if d != 1) in ((held, E, H), (held, H, E))}  # noqa: E731
+    one_expert = re.compile(r"= bf16\[1,1,(?:%d,%d|%d,%d)\]\S* dynamic-slice\(" % (E, H, H, E))
+    decode, chunk = compiled["decode"][0], compiled["prefill"][0]
+    assert a_layers_stack(_aot_v5e.array_shapes(decode)) == set()
+    assert len(one_expert.findall(decode)) == 3 * lcfg.n_layers
+    assert decode.count(" while(") == lcfg.n_layers + lcfg.layer_kinds.count("full")  # a visit loop a layer, a walk a full layer
+    assert sum(b for _, _, b in _aot_v5e.weight_relayouts(decode)) <= 2 * KNOWN_COPIES["qwen3-next-80b-a3b-l8-ep4", "decode"]  # two full layers' wq
+    assert a_layers_stack(_aot_v5e.array_shapes(chunk)) and not one_expert.findall(chunk)
+    assert chunk.count(" while(") == lcfg.n_layers  # six scans, two walks
+    assert _aot_v5e.weight_relayouts(chunk) == []
+
+
 def test_the_relayout_reader_sees_a_copy_where_there_is_one():
     hlo = """HloModule m
 %fused_computation.1 (p: bf16[16,4096,1024]) -> bf16[1024,4096] {
